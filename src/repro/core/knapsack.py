@@ -7,9 +7,7 @@ the partitions. The paper's guarantee — *"the load on any two processes
 differs by at most the maximum weight of any point"* — is property-tested
 in ``tests/test_knapsack.py``.
 
-Everything here is fixed-shape, jit-able jnp; the Pallas kernel
-``repro.kernels.knapsack_scan`` implements the blocked prefix-scan +
-boundary pick for the hot path.
+Everything here is fixed-shape, jit-able jnp.
 """
 from __future__ import annotations
 

@@ -15,7 +15,6 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from repro import compat as _compat
 import numpy as np
 
 
@@ -299,7 +298,7 @@ def _exchange_fn(mesh: jax.sharding.Mesh, axis: str, capacity: int, fill_value):
         rval = jax.lax.all_to_all(val, axis, split_axis=0, concat_axis=0)
         return rbuf.reshape((-1,) + x.shape[1:]), rval.reshape(-1)
 
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),

@@ -21,8 +21,6 @@ from typing import Literal, NamedTuple
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat as _compat
 from jax.sharding import PartitionSpec as P
 
 from repro.core import kdtree as _kdtree
@@ -143,6 +141,11 @@ class PartitionerConfig:
     use_tree: bool = False        # order via kd-tree buckets (paper's full path)
     use_pallas: bool = False      # use the Pallas key-gen kernels
 
+    def __post_init__(self):
+        if self.use_pallas and self.words != 1:
+            raise ValueError("the Pallas key-gen kernels emit single-word keys: "
+                             f"use_pallas=True needs words=1, got words={self.words}")
+
 
 def _keys_for(points: jax.Array, cfg: PartitionerConfig) -> jax.Array:
     if cfg.use_pallas:
@@ -159,9 +162,9 @@ def _point_order(points: jax.Array, cfg: PartitionerConfig) -> tuple[jax.Array, 
     """Point-path curve order: (perm, keys). The ONE key-gen + sort
     prelude shared by the flat and hierarchical partitions (so the
     (1, D)-is-bit-identical invariant cannot drift)."""
-    if cfg.use_pallas and cfg.words == 1:
-        # Pallas key-gen kernels (single-word keys); same curve order as
-        # the jnp path — asserted by test_pallas_path_matches_jnp
+    if cfg.use_pallas:
+        # Pallas key-gen kernels; same keys as the jnp path — asserted by
+        # test_pallas_path_matches_jnp
         keys = _keys_for(points, cfg)
         return _sfc.argsort_keys(keys), keys
     return _sfc.sfc_order(
@@ -583,7 +586,7 @@ def _reslice_fn(mesh: jax.sharding.Mesh, axis: str, num_parts: int):
         me = jax.lax.axis_index(axis)
         return _global_curve_slice(wts, val, axis, me, nshards, num_parts)
 
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),
@@ -686,7 +689,7 @@ def _partition_fn(
         part = _global_curve_slice(recv_w, valid, axis, me, nshards, num_parts)
         return recv_k, jnp.where(valid, recv_w, -1.0), part
 
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),
@@ -839,7 +842,7 @@ def _hier_bucket_partition_fn(
         return bucket_part[tree.leaf_id], tree.leaf_id.astype(jnp.int32), node_keys
 
     spec = P(axes)
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(spec, spec),
@@ -865,7 +868,7 @@ def _hier_bucket_reslice_fn(mesh: jax.sharding.Mesh, plan: HierarchyPlan):
         return bucket_part[leaf_id]
 
     spec = P(axes)
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -9,11 +9,12 @@
 
 Both run against a shared :class:`repro.core.curve_index.CurveIndex`
 (built cold here, or refreshed incrementally from a ``Repartitioner``'s
-cached keys) and both route the key search through the Pallas
-``bucket_search`` kernel when compiled kernels are enabled
-(``REPRO_PALLAS_COMPILE=1`` / ``kernels.ops.set_interpret(False)``),
-falling back to ``jnp.searchsorted`` in interpret mode where the pure-jnp
-path is the faster one.
+cached keys). Every key search is a directory hop — the last bucket
+whose first key is <= the query key — followed by a count inside that
+one bucket. ``use_pallas`` picks the hop's implementation: the Pallas
+``bucket_search`` / ``fused_locate`` kernels or ``jnp.searchsorted``.
+Both give identical answers; the default is the kernels on a TPU and
+``jnp.searchsorted`` elsewhere (interpreted kernels are slow).
 """
 from __future__ import annotations
 
@@ -32,11 +33,10 @@ QueryIndex = _ci.CurveIndex
 
 
 def _pallas_default() -> bool:
-    """Pallas fast path by default only when kernels compile natively
-    (on CPU/interpret mode jnp.searchsorted wins)."""
+    """Kernels by default only where they compile (see module note)."""
     from repro.kernels import ops as _kops
 
-    return not _kops.INTERPRET
+    return not _kops.interpret()
 
 
 def build_index(
@@ -56,57 +56,52 @@ def build_index(
     return _ci.build(points, ids, bucket_size=bucket_size, bits=bits, curve="morton")
 
 
-def _searchsorted_u32(
-    sorted_keys: jax.Array, qk: jax.Array, side: str, use_pallas: bool
-) -> jax.Array:
-    """searchsorted over sorted uint32 keys, routed through the Pallas
-    ``bucket_search`` kernel (last-boundary<=key probe) when enabled.
+def _directory_hop(bucket_keys: jax.Array, qk: jax.Array, use_pallas: bool) -> jax.Array:
+    """Last bucket whose first key is <= each query key (clamped to 0)."""
+    if use_pallas:
+        from repro.kernels import ops as _kops
 
-    Exact for integer keys: right(q) = last_le(q)+1 (0 when q < keys[0]);
-    left(q) = right(q-1) for q > 0, else 0.
-    """
-    from repro.kernels import bucket_search as _bsk
-    from repro.kernels import ops as _kops
+        return _kops.bucket_search(qk, bucket_keys)
+    b = jnp.searchsorted(bucket_keys, qk, side="right").astype(jnp.int32) - 1
+    return jnp.clip(b, 0, bucket_keys.shape[0] - 1)
 
-    if not use_pallas or sorted_keys.shape[0] > _bsk.DIR_MAX:
-        return jnp.searchsorted(sorted_keys, qk, side=side).astype(jnp.int32)
-    if side == "right":
-        last_le = _kops.bucket_search(qk, sorted_keys)
-        return jnp.where(sorted_keys[0] <= qk, last_le + 1, 0).astype(jnp.int32)
-    qm = qk - jnp.uint32(1)
-    last_lt = _kops.bucket_search(qm, sorted_keys)
-    cnt = jnp.where(sorted_keys[0] <= qm, last_lt + 1, 0)
-    return jnp.where(qk > jnp.uint32(0), cnt, 0).astype(jnp.int32)
+
+def _rank(index: CurveIndex, qk: jax.Array, strict: bool, use_pallas: bool) -> jax.Array:
+    """Number of live stored keys < qk (``strict``) or <= qk.
+
+    Exact for integer keys: the hop finds bucket b, the last whose first
+    key is <= the probe. Every key before b's start is <= that first key
+    and every key from the next bucket on is > the probe, so the rank is
+    b's start plus a count inside b's rows (at most ``max_bucket_len``)."""
+    probe = qk - jnp.uint32(1) if strict else qk
+    b = _directory_hop(index.bucket_keys, probe, use_pallas)
+    start = index.bucket_starts[b]
+    end = index.bucket_starts[b + 1]
+    pos = start[:, None] + jnp.arange(index.max_bucket_len, dtype=jnp.int32)[None, :]
+    keys = index.keys[jnp.clip(pos, 0, index.capacity - 1)]
+    rank = start + jnp.sum((pos < end[:, None]) & (keys <= probe[:, None]), axis=1)
+    if strict:   # qk == 0: nothing is smaller (the probe wrapped around)
+        rank = jnp.where(qk > jnp.uint32(0), rank, 0)
+    return rank.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def _locate_bucket(index: CurveIndex, queries: jax.Array, use_pallas: bool) -> jax.Array:
-    from repro.kernels import bucket_search as _bsk
-
-    if (
-        use_pallas
-        and index.curve == "morton"
-        and index.tree is None  # tree-backed keys come from a tree walk,
-        #                         not from query coordinates
-        and index.num_buckets <= _bsk.DIR_MAX
-    ):
+    if use_pallas and index.curve == "morton" and index.tree is None:
+        # point-keyed Morton index: interleave + hop fused in one kernel
+        # (tree-backed keys come from a tree walk, not from coordinates)
         from repro.kernels import ops as _kops
 
-        # fused key-gen + directory search in one kernel dispatch (beyond
-        # DIR_MAX the directory doesn't fit VMEM: degrade to the exact
-        # jnp path below rather than assert)
         return _kops.fused_locate(
             queries, index.bucket_keys, index.frame_lo, index.frame_hi, index.bits
         )
-    qk = _ci.query_keys(index, queries)
-    b = _searchsorted_u32(index.bucket_keys, qk, "right", use_pallas) - 1
-    return jnp.clip(b, 0, index.num_buckets - 1)
+    return _directory_hop(index.bucket_keys, _ci.query_keys(index, queries), use_pallas)
 
 
 def locate_bucket(
     index: CurveIndex, queries: jax.Array, *, use_pallas: bool | None = None
 ) -> jax.Array:
-    """Bucket id per query via binary search on the sorted directory."""
+    """Bucket id per query: the directory hop (see module note)."""
     if use_pallas is None:
         use_pallas = _pallas_default()
     return _locate_bucket(index, queries, use_pallas)
@@ -130,8 +125,8 @@ def _point_location(
     # [lo_i, hi_i) — unlike a single-bucket scan, this cannot silently
     # miss when duplicates spill a bucket (runs spanning bucket or even
     # partition boundaries are covered).
-    lo_i = _searchsorted_u32(index.keys, qk, "left", use_pallas)
-    hi_i = _searchsorted_u32(index.keys, qk, "right", use_pallas)
+    lo_i = _rank(index, qk, True, use_pallas)
+    hi_i = _rank(index, qk, False, use_pallas)
     run = hi_i - lo_i
     n = index.capacity
     offs = jnp.arange(bucket_cap, dtype=jnp.int32)

@@ -19,7 +19,6 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from repro import compat as _compat
 import numpy as np
 
 
@@ -201,7 +200,7 @@ def distributed_spmv(
 
     # shard_map must run under jit: eager execution dispatches every
     # traced op as its own SPMD program (see partitioner._reslice_fn)
-    fn = jax.jit(_compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P()),

@@ -29,7 +29,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat as _compat
 from repro.configs.base import ModelConfig, ShardingRules
 
 _CTX = threading.local()
@@ -627,7 +626,7 @@ def _query_serve_fn(
         return got[:, :k], jax.lax.bitcast_convert_type(got[:, k:], jnp.int32), pos_of
 
     out_specs = (P(axis), P(axis)) if mode == "pl" else (P(axis), P(axis), P(axis))
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
@@ -717,7 +716,7 @@ def _query_serve_fn_2d(
 
     spec = P(axes)
     out_specs = (spec, spec) if mode == "pl" else (spec, spec, spec)
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec),

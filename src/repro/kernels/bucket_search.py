@@ -1,14 +1,25 @@
-"""Pallas TPU kernel: batched binary search of query keys over sorted
-bucket boundary keys — the point-location inner loop (paper §V-A).
+"""Pallas TPU kernel: batched search of query keys over sorted bucket
+boundary keys — the point-location directory hop (paper §V-A).
 
-Each grid cell stages a block of query keys plus the *entire* boundary
-directory into VMEM (the directory is n/BUCKETSIZE entries — 250M points
-at BUCKETSIZE=32 is 7.8M boundaries, so production use tiles a two-level
-directory; this kernel handles directories up to DIR_MAX that fit VMEM,
-which covers every in-memory case in the paper's experiments).
+For each query key the kernel returns the index of the last boundary key
+<= it, as ``count(boundary <= key) - 1`` clamped to the directory. The
+count is a compare-and-count sweep: the grid walks (query block,
+directory tile) pairs. Queries sit lane-dense, 128 per row; each 8x128
+chunk of the directory is transposed so that every directory key
+becomes a column broadcast over the lanes, compared against a row of
+queries and summed into a (128, 128) VMEM accumulator per query row;
+the last directory tile reduces the sublanes. Mosaic has no vector
+gather beyond one vreg, so a branch-free binary search (a
+data-dependent probe per lane) does not lower; the sweep does
+O(directory) VPU work per query instead of O(log directory), with no
+limit on the directory size.
 
-The search is branch-free: log2(B) rounds of midpoint probes with
-vectorized gathers, identical control flow for every lane.
+``fused_locate`` adds the Morton interleave of the query cells in the
+same kernel, so the query keys never round-trip through HBM. The
+quantization of the coordinates into cells stays in XLA
+(`sfc.cells_in_frame`): it divides by the frame span, and keeping that
+one division on one compiler is what keeps query keys bit-identical to
+the stored keys on every backend.
 """
 from __future__ import annotations
 
@@ -17,121 +28,108 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BLOCK_Q = 2048
-DIR_MAX = 1 << 20  # 1M boundaries * 4B = 4 MiB of VMEM
+from repro.kernels.morton import LANES, interleave
 
-
-def _search_kernel(q_ref, dir_ref, out_ref, *, steps: int, nb: int):
-    q = q_ref[...]          # (BLOCK_Q,) uint32 query keys
-    d = dir_ref[...]        # (NB,) uint32 sorted boundary keys
-    lo = jnp.zeros_like(q, dtype=jnp.int32)
-    step = jnp.int32(1 << (steps - 1))
-    for _ in range(steps):
-        mid = lo + step
-        mid_c = jnp.minimum(mid, nb - 1)
-        probe = d[mid_c]
-        go = (probe <= q) & (mid <= nb - 1)
-        lo = jnp.where(go, mid, lo)
-        step = step // 2
-    out_ref[...] = lo
+BLOCK_QR = 8      # query rows of 128 per grid step (1024 queries)
+DIR_ROWS = 64     # directory tile: DIR_ROWS x 128 keys per grid step
 
 
-def _fused_kernel(
-    pts_ref, lo_ref, span_ref, dir_ref, out_ref, *, bits: int, d: int, steps: int, nb: int
-):
-    """Fused key-gen + search: quantize a query block against the frame,
-    Morton-interleave, and binary-search the directory — one VMEM stage,
-    no intermediate key round-trip to HBM."""
-    pts = pts_ref[...]        # (BLOCK_Q, d) float32 query coordinates
-    flo = lo_ref[...]         # (1, d) frame lo
-    span = span_ref[...]      # (1, d) frame span (hi - lo, degenerate -> 1)
-    # op-for-op identical to curve_index.keys_in_frame (divide, clip to
-    # 1-1e-7, then scale by the exact power of two): a reciprocal-multiply
-    # here would disagree with the jnp path by 1 ulp on ~1e-5 of queries,
-    # i.e. route them to a different bucket than their stored key
-    unit = jnp.clip((pts - flo) / span, 0.0, jnp.float32(1.0 - 1e-7))
-    cells = (unit * jnp.float32(2**bits)).astype(jnp.uint32)
-    key = jnp.zeros((cells.shape[0],), dtype=jnp.uint32)
-    offset = 32 - bits * d    # left-align payload (same layout as sfc/morton)
-    for k in range(bits):
-        src_bit = bits - 1 - k
-        for i in range(d):
-            bit_in_word = 31 - (offset + k * d + i)
-            comp = (cells[:, i] >> jnp.uint32(src_bit)) & jnp.uint32(1)
-            key = key | (comp << jnp.uint32(bit_in_word))
-    dirk = dir_ref[...]       # (NB,) uint32 sorted boundary keys
-    lo = jnp.zeros_like(key, dtype=jnp.int32)
-    step = jnp.int32(1 << (steps - 1))
-    for _ in range(steps):
-        mid = lo + step
-        mid_c = jnp.minimum(mid, nb - 1)
-        probe = dirk[mid_c]
-        go = (probe <= key) & (mid <= nb - 1)
-        lo = jnp.where(go, mid, lo)
-        step = step // 2
-    out_ref[...] = lo
+def _count_step(qkey_fn, q_ref, dir_ref, out_ref, qk_ref, acc_ref):
+    j = pl.program_id(1)
+    br = qk_ref.shape[0]
+
+    @pl.when(j == 0)
+    def _():
+        qk_ref[...] = qkey_fn(q_ref)                   # (br, 128) uint32
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def chunk(c, carry):
+        blk = dir_ref[pl.ds(pl.multiple_of(c * 8, 8), 8), :]
+        cols = blk.T                                   # (128, 8): key per sublane
+        for s in range(8):
+            key = jnp.broadcast_to(cols[:, s:s + 1], (LANES, LANES))
+            for r in range(br):
+                hit = (key <= qk_ref[r:r + 1, :]).astype(jnp.int32)
+                acc_ref[r] = acc_ref[r] + hit
+        return carry
+
+    jax.lax.fori_loop(0, dir_ref.shape[0] // 8, chunk, 0)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        for r in range(br):
+            out_ref[r:r + 1, :] = jnp.sum(acc_ref[r], axis=0, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def fused_locate(
-    queries: jax.Array,
-    boundary_keys: jax.Array,
-    frame_lo: jax.Array,
-    frame_hi: jax.Array,
-    bits: int,
-    *,
-    interpret: bool = True,
-) -> jax.Array:
-    """Morton key-gen + directory search fused into one kernel.
+def _slab(cols: jax.Array) -> jax.Array:
+    """(w, q) per-query columns -> (w, rows, 128) lane-dense slab, rows
+    padded to a multiple of the query block."""
+    w, q = cols.shape
+    rows = pl.cdiv(q, LANES)
+    br = min(BLOCK_QR, rows)
+    rows_pad = pl.cdiv(rows, br) * br
+    return jnp.pad(cols, ((0, 0), (0, rows_pad * LANES - q))).reshape(w, rows_pad, LANES)
 
-    Returns, per query point, the index of the last boundary key <= its
-    Morton key (clamped to 0) — i.e. its directory bucket.
-    """
-    q, d = queries.shape
-    assert bits * d <= 32, "single-word fused kernel: bits*d must fit 32 bits"
+
+def _last_le(qkey_fn, cols: jax.Array, boundary_keys: jax.Array, interpret: bool) -> jax.Array:
+    """Index of the last boundary key <= each query's key (clamped to
+    the directory). ``cols`` (w, q) is the per-query input and
+    ``qkey_fn(ref)`` turns one (w, br, 128) block of it into (br, 128)
+    uint32 keys."""
+    q = cols.shape[1]
+    slab = _slab(cols)
+    w, q_rows, _ = slab.shape
     nb = boundary_keys.shape[0]
-    assert nb <= DIR_MAX, "two-level directory required beyond DIR_MAX"
-    steps = max(1, (nb - 1).bit_length())
-    span = jnp.where(frame_hi > frame_lo, frame_hi - frame_lo, 1.0)
-    span = span.astype(jnp.float32)[None, :]
-    flo = frame_lo.astype(jnp.float32)[None, :]
-    q_pad = pl.cdiv(q, BLOCK_Q) * BLOCK_Q
-    qp = jnp.zeros((q_pad, d), jnp.float32).at[:q].set(queries)
-    out = pl.pallas_call(
-        functools.partial(_fused_kernel, bits=bits, d=d, steps=steps, nb=nb),
-        grid=(q_pad // BLOCK_Q,),
+    rows = pl.cdiv(nb, LANES)
+    tile = min(DIR_ROWS, pl.cdiv(rows, 8) * 8)
+    rows_pad = pl.cdiv(rows, tile) * tile
+    # pad with the largest key: only a query equal to it counts the pads,
+    # and its answer (the last boundary) is the clamp below
+    dirk = jnp.pad(
+        boundary_keys, (0, rows_pad * LANES - nb), constant_values=jnp.uint32(0xFFFFFFFF)
+    ).reshape(rows_pad, LANES)
+    br = min(BLOCK_QR, q_rows)
+    count = pl.pallas_call(
+        functools.partial(_count_step, qkey_fn),
+        grid=(q_rows // br, rows_pad // tile),
         in_specs=[
-            pl.BlockSpec((BLOCK_Q, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((nb,), lambda i: (0,)),
+            pl.BlockSpec((w, br, LANES), lambda i, j: (0, i, 0)),
+            pl.BlockSpec((tile, LANES), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((BLOCK_Q,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((q_pad,), jnp.int32),
+        out_specs=pl.BlockSpec((br, LANES), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((q_rows, LANES), jnp.int32),
+        scratch_shapes=[
+            pltpu.VMEM((br, LANES), jnp.uint32),
+            pltpu.VMEM((br, LANES, LANES), jnp.int32),
+        ],
         interpret=interpret,
-    )(qp, flo, span, boundary_keys)
-    return out[:q]
+    )(slab, dirk)
+    return jnp.clip(count.reshape(-1)[:q] - 1, 0, nb - 1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bucket_search(qkeys: jax.Array, boundary_keys: jax.Array, *, interpret: bool = True) -> jax.Array:
-    """For each query key, index of the last boundary <= key (uint32)."""
-    q = qkeys.shape[0]
-    nb = boundary_keys.shape[0]
-    assert nb <= DIR_MAX, "two-level directory required beyond DIR_MAX"
-    steps = max(1, (nb - 1).bit_length())
-    q_pad = pl.cdiv(q, BLOCK_Q) * BLOCK_Q
-    qk = jnp.zeros((q_pad,), jnp.uint32).at[:q].set(qkeys)
-    out = pl.pallas_call(
-        functools.partial(_search_kernel, steps=steps, nb=nb),
-        grid=(q_pad // BLOCK_Q,),
-        in_specs=[
-            pl.BlockSpec((BLOCK_Q,), lambda i: (i,)),
-            pl.BlockSpec((nb,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_Q,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((q_pad,), jnp.int32),
-        interpret=interpret,
-    )(qk, boundary_keys)
-    return out[:q]
+    """For each query key, index of the last boundary <= key (uint32),
+    clamped to 0 for keys below the first boundary."""
+    return _last_le(lambda ref: ref[0], qkeys[None, :], boundary_keys, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "interpret"))
+def fused_locate(
+    cells: jax.Array, boundary_keys: jax.Array, bits: int, *, interpret: bool = True
+) -> jax.Array:
+    """Morton interleave + directory search in one kernel.
+
+    ``cells`` (q, d) uint32 are the query coordinates quantized against
+    the index frame. Returns, per query, the index of the last boundary
+    key <= its Morton key (clamped to 0) — i.e. its directory bucket.
+    """
+    d = cells.shape[1]
+    assert bits * d <= 32, "single-word fused kernel: bits*d must fit 32 bits"
+
+    def qkey(ref):
+        return interleave([ref[i] for i in range(d)], bits)
+
+    return _last_le(qkey, cells.T, boundary_keys, interpret)

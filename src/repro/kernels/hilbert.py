@@ -1,9 +1,10 @@
 """Pallas TPU kernel: Hilbert-like SFC key generation (Skilling transform).
 
-Same VPU-bound structure as the Morton kernel plus the Gray-code
-transpose (paper's Hilbert-like look-ahead — a static O(bits * d) chain of
-shifts/xors/selects per block, still branch-free and fully vectorized).
-The kernel fuses transform + interleave so cells are read from VMEM once.
+Same VPU-bound structure and lane-dense (d, rows, 128) layout as the
+Morton kernel, plus the Gray-code transpose (paper's Hilbert-like
+look-ahead — a static O(bits * d) chain of shifts/xors/selects per
+block, branch-free and fully vectorized). The kernel fuses transform +
+interleave so cells are read from VMEM once.
 """
 from __future__ import annotations
 
@@ -11,14 +12,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-BLOCK_N = 2048
+from repro.kernels.morton import interleave, lane_dense_keys
 
 
 def _hilbert_kernel(cells_ref, out_ref, *, bits: int, d: int):
-    cells = cells_ref[...]  # (BLOCK_N, d) uint32
-    X = [cells[:, i] for i in range(d)]
+    X = [cells_ref[i] for i in range(d)]   # d x (BLOCK_ROWS, 128) uint32
 
     # Skilling inverse-undo (static loops -> straight-line vector code)
     Q = 1 << (bits - 1)
@@ -48,33 +47,10 @@ def _hilbert_kernel(cells_ref, out_ref, *, bits: int, d: int):
     for i in range(d):
         X[i] = X[i] ^ t
 
-    # interleave (same layout as the Morton kernel)
-    key = jnp.zeros_like(X[0])
-    total = bits * d
-    offset = 32 - total
-    for k in range(bits):
-        src_bit = bits - 1 - k
-        for i in range(d):
-            g = k * d + i
-            bit_in_word = 31 - (offset + g)
-            comp = (X[i] >> jnp.uint32(src_bit)) & jnp.uint32(1)
-            key = key | (comp << jnp.uint32(bit_in_word))
-    out_ref[...] = key
+    out_ref[...] = interleave(X, bits)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def hilbert_from_cells(cells: jax.Array, bits: int, *, interpret: bool = True) -> jax.Array:
     """(n, d) uint32 cells -> (n,) uint32 Hilbert-like keys via Pallas."""
-    n, d = cells.shape
-    assert bits * d <= 32
-    n_pad = pl.cdiv(n, BLOCK_N) * BLOCK_N
-    cells_p = jnp.zeros((n_pad, d), dtype=jnp.uint32).at[:n].set(cells)
-    out = pl.pallas_call(
-        functools.partial(_hilbert_kernel, bits=bits, d=d),
-        grid=(n_pad // BLOCK_N,),
-        in_specs=[pl.BlockSpec((BLOCK_N, d), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((BLOCK_N,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.uint32),
-        interpret=interpret,
-    )(cells_p)
-    return out[:n]
+    return lane_dense_keys(_hilbert_kernel, cells, bits, interpret)

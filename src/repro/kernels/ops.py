@@ -1,13 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only (the
-harness contract: kernels target TPU, validate in interpret mode). On a
-real TPU runtime set ``repro.kernels.ops.INTERPRET = False`` (or the
-REPRO_PALLAS_COMPILE=1 env) to compile the kernels natively.
+Every wrapper compiles its kernel for the TPU when the default backend is
+a TPU and runs it in Pallas interpret mode anywhere else (CPU tests).
+The choice is made per call from ``jax.default_backend()``, never at
+import, so importing this module does not initialize a backend.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -15,12 +13,14 @@ import jax.numpy as jnp
 from repro.core import sfc as _sfc
 from repro.kernels import bucket_search as _bs
 from repro.kernels import hilbert as _hil
-from repro.kernels import knapsack_scan as _ks
 from repro.kernels import morton as _mor
 from repro.kernels import pair_force as _pf
 from repro.kernels import stencil_update as _su
 
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
+
+def interpret() -> bool:
+    """Interpret the kernels unless they run on a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -42,8 +42,7 @@ _KEY_CACHE_STATS = {"hits": 0, "misses": 0}
 def invalidate_key_cache(token=None) -> int:
     """Drop cached keys. ``token=None`` clears everything; otherwise only
     entries generated under that token. Returns the number of entries
-    dropped. Called automatically by ``set_interpret`` (a backend switch
-    may change key bit layouts in interpret-vs-compiled edge cases)."""
+    dropped."""
     if token is None:
         n = len(_KEY_CACHE)
         _KEY_CACHE.clear()
@@ -56,13 +55,6 @@ def invalidate_key_cache(token=None) -> int:
 
 def key_cache_stats() -> dict:
     return dict(_KEY_CACHE_STATS, entries=len(_KEY_CACHE))
-
-
-def set_interpret(flag: bool) -> None:
-    """Toggle Pallas interpret mode; invalidates the key cache."""
-    global INTERPRET
-    INTERPRET = bool(flag)
-    invalidate_key_cache()
 
 
 def cached_sfc_key(
@@ -96,7 +88,7 @@ def cached_sfc_key(
         cells = _sfc.cells_in_frame(points, lo, hi, b)
         if use_pallas:
             fn = _mor.morton_from_cells if curve == "morton" else _hil.hilbert_from_cells
-            keys = fn(cells, b, interpret=INTERPRET)
+            keys = fn(cells, b, interpret=interpret())
         else:
             fn = (
                 _sfc.morton_key_from_cells
@@ -119,7 +111,7 @@ def morton_key(points: jax.Array, bits: int | None = None, *, stats: str = "geom
     if bits is None:
         bits = _sfc.max_bits_per_dim(d)
     cells = _sfc.quantize(points, bits, stats)
-    return _mor.morton_from_cells(cells, bits, interpret=INTERPRET)
+    return _mor.morton_from_cells(cells, bits, interpret=interpret())
 
 
 def hilbert_key(points: jax.Array, bits: int | None = None, *, stats: str = "geometric") -> jax.Array:
@@ -127,15 +119,11 @@ def hilbert_key(points: jax.Array, bits: int | None = None, *, stats: str = "geo
     if bits is None:
         bits = _sfc.max_bits_per_dim(d)
     cells = _sfc.quantize(points, bits, stats)
-    return _hil.hilbert_from_cells(cells, bits, interpret=INTERPRET)
-
-
-def knapsack_parts(weights: jax.Array, num_parts: int) -> jax.Array:
-    return _ks.knapsack_parts(weights, num_parts, interpret=INTERPRET)
+    return _hil.hilbert_from_cells(cells, bits, interpret=interpret())
 
 
 def bucket_search(qkeys: jax.Array, boundary_keys: jax.Array) -> jax.Array:
-    return _bs.bucket_search(qkeys, boundary_keys, interpret=INTERPRET)
+    return _bs.bucket_search(qkeys, boundary_keys, interpret=interpret())
 
 
 def fused_locate(
@@ -145,13 +133,12 @@ def fused_locate(
     frame_hi: jax.Array,
     bits: int,
 ) -> jax.Array:
-    """Fused Morton key-gen + directory binary search (one kernel
-    dispatch): per query point, the index of the last boundary key <= its
-    key. The query-serving hot loop — point location and kNN bucket
-    lookup both ride on it when compiled kernels are enabled."""
-    return _bs.fused_locate(
-        queries, boundary_keys, frame_lo, frame_hi, bits, interpret=INTERPRET
-    )
+    """Fused Morton interleave + directory search (one kernel dispatch
+    after the frame quantization): per query point, the index of the
+    last boundary key <= its key. kNN's bucket lookup rides on it when
+    kernels are on."""
+    cells = _sfc.cells_in_frame(queries, frame_lo, frame_hi, bits)
+    return _bs.fused_locate(cells, boundary_keys, bits, interpret=interpret())
 
 
 def stencil_update(
@@ -166,13 +153,13 @@ def stencil_update(
     """Fused stencil row update (gather + mask + coeff*(v-u) + K-reduce).
 
     The mesh stencil executors' inner loop. ``use_pallas`` dispatches the
-    Pallas kernel (REPRO_PALLAS_COMPILE-respecting via ``INTERPRET``);
-    the default jnp fallback is bit-equal by construction — both
-    evaluate `kernels.stencil_update.stencil_update_ref`'s expression.
+    Pallas kernel; the default jnp path is bit-equal by construction —
+    both evaluate `kernels.stencil_update.stencil_update_ref`'s
+    expression.
     """
     if use_pallas:
         return _su.fused_stencil_update(
-            vals_all, u_rows, nbr, valid, coeff, interpret=INTERPRET
+            vals_all, u_rows, nbr, valid, coeff, interpret=interpret()
         )
     return _su.stencil_update_ref(vals_all, u_rows, nbr, valid, coeff)
 
@@ -189,15 +176,14 @@ def pair_accel(
 ) -> jax.Array:
     """Fused pairwise short-range acceleration (gather + cutoff weight +
     K-reduce) — the particle executors' inner loop. ``use_pallas``
-    dispatches the Pallas kernel (REPRO_PALLAS_COMPILE-respecting via
-    ``INTERPRET``); the default jnp fallback is bit-equal by
+    dispatches the Pallas kernel; the default jnp path is bit-equal by
     construction — both evaluate `kernels.pair_force.pair_accel_ref`'s
     expression.
     """
     if use_pallas:
         return _pf.fused_pair_accel(
             pos_all, mass_all, x_rows, nbr, valid,
-            jnp.asarray(rc2, jnp.float32), interpret=INTERPRET,
+            jnp.asarray(rc2, jnp.float32), interpret=interpret(),
         )
     return _pf.pair_accel_ref(
         pos_all, mass_all, x_rows, nbr, valid, jnp.asarray(rc2, jnp.float32)

@@ -1,14 +1,14 @@
 """Pallas TPU kernel: fused pairwise short-range acceleration (paper
 §V-C particle hot loop).
 
-One pass over (rows, K) interaction tiles fuses the neighbor
-position/mass gather, the cutoff weight ``max(rc2 - |r|^2, 0) * m_j``
-and the K-reduction into per-row accelerations — the unfused jnp path
-materializes the (n, K, d) displacement and contribution intermediates
-in HBM between separate ops; here each grid block stages the FULL
-owned+ghost position matrix and mass vector into VMEM once ((V, d) +
-(V,) float32 — the same in-VMEM-directory regime as `stencil_update`)
-and streams the (BLOCK_R, K) index/mask tiles past it.
+One pass over (K, rows) interaction tiles fuses the cutoff weight
+``max(rc2 - |r|^2, 0) * m_j``, the displacement products and the
+K-reduction into per-row accelerations — the unfused jnp path
+materializes the (n, K, d) contribution intermediate in HBM between
+separate ops. The neighbor position/mass gather runs in XLA ahead of
+the kernel (Mosaic lowers no vector gather beyond one vreg), laid out
+(d, K, rows) so rows fill the 128 lanes and both add chains are
+sublane-row adds.
 
 The force law is the bounded short-range attraction
 
@@ -37,9 +37,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BLOCK_R = 1024
-VALS_MAX = 1 << 20  # owned+ghost rows whose (V, d) positions fit VMEM
+from repro.kernels.stencil_update import row_tiles
 
 
 def pair_accel_ref(
@@ -57,42 +57,41 @@ def pair_accel_ref(
     ``nbr``/``valid`` (R, K) the row-local interaction table, ``rc2``
     the squared cutoff radius. Returns the (R, d) accelerations.
     """
-    pj = pos_all[nbr]                       # (R, K, d)
-    mj = mass_all[nbr]                      # (R, K)
-    diff = pj - x_rows[:, None, :]
-    # fixed-order dimension accumulation (see module docstring)
-    d2 = diff[..., 0] * diff[..., 0]
-    for a in range(1, diff.shape[-1]):
-        d2 = d2 + diff[..., a] * diff[..., a]
-    w = jnp.where(valid, jnp.maximum(rc2 - d2, jnp.float32(0.0)) * mj,
-                  jnp.float32(0.0))
-    contrib = w[..., None] * diff           # (R, K, d)
-    # fixed-order K accumulation (NOT jnp.sum)
-    acc = contrib[:, 0, :]
-    for k in range(1, contrib.shape[1]):
-        acc = acc + contrib[:, k, :]
+    # one row gather per table column (see `stencil_update_ref`: XLA:TPU
+    # compiles the (R, K)-index gather slower and through an (R, K, d)
+    # temporary), fixed-order dimension and K accumulation (NOT jnp.sum)
+    acc = None
+    for k in range(nbr.shape[1]):
+        diff = pos_all[nbr[:, k]] - x_rows             # (R, d)
+        d2 = diff[:, 0] * diff[:, 0]
+        for a in range(1, diff.shape[1]):
+            d2 = d2 + diff[:, a] * diff[:, a]
+        w = jnp.where(valid[:, k],
+                      jnp.maximum(rc2 - d2, jnp.float32(0.0)) * mass_all[nbr[:, k]],
+                      jnp.float32(0.0))
+        contrib = w[:, None] * diff
+        acc = contrib if acc is None else acc + contrib
     return acc
 
 
-def _accel_kernel(rc2_ref, pos_ref, mass_ref, x_ref, nbr_ref, valid_ref, out_ref):
-    # same jnp expression as pair_accel_ref, on one (BLOCK_R, K) tile
-    pos_all = pos_ref[...]
-    mass_all = mass_ref[...]
-    x = x_ref[...]
-    rc2 = rc2_ref[0]
-    pj = pos_all[nbr_ref[...]]
-    mj = mass_all[nbr_ref[...]]
-    diff = pj - x[:, None, :]
-    d2 = diff[..., 0] * diff[..., 0]
-    for a in range(1, diff.shape[-1]):
-        d2 = d2 + diff[..., a] * diff[..., a]
-    w = jnp.where(valid_ref[...], jnp.maximum(rc2 - d2, jnp.float32(0.0)) * mj,
+def _accel_kernel(rc2_ref, pj_ref, mj_ref, x_ref, valid_ref, out_ref):
+    # same expression as pair_accel_ref on one (K, BLOCK_R) tile:
+    # pj (d, K, BLOCK_R), mj/valid (K, BLOCK_R), x/out (d, 1, BLOCK_R)
+    d = pj_ref.shape[0]
+    rc2 = rc2_ref[0, 0]
+    diff = [pj_ref[a] - x_ref[a] for a in range(d)]
+    d2 = diff[0] * diff[0]
+    for a in range(1, d):
+        d2 = d2 + diff[a] * diff[a]
+    w = jnp.where(valid_ref[...] != 0,
+                  jnp.maximum(rc2 - d2, jnp.float32(0.0)) * mj_ref[...],
                   jnp.float32(0.0))
-    contrib = w[..., None] * diff
-    acc = contrib[:, 0, :]
-    for k in range(1, contrib.shape[1]):
-        acc = acc + contrib[:, k, :]
-    out_ref[...] = acc
+    for a in range(d):
+        contrib = w * diff[a]
+        acc = contrib[0:1]
+        for k in range(1, contrib.shape[0]):
+            acc = acc + contrib[k:k + 1]
+        out_ref[a] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -106,37 +105,35 @@ def fused_pair_accel(
     *,
     interpret: bool = True,
 ) -> jax.Array:
-    """Fused gather + cutoff weight + contribution + K-reduce, one kernel
-    dispatch. Pad rows (``valid`` all False) come out exactly zero —
-    exactly what the unfused path computes for them."""
+    """Fused cutoff weight + contribution + K-reduce, one kernel dispatch
+    after the XLA gather. Pad rows (``valid`` all False) come out exactly
+    zero — exactly what the unfused path computes for them."""
     R, K = nbr.shape
-    V, d = pos_all.shape
-    assert V <= VALS_MAX, "owned+ghost positions must fit VMEM (tile beyond)"
-    r_pad = pl.cdiv(R, BLOCK_R) * BLOCK_R
-
-    def pad(a, fill):
-        return jnp.full((r_pad,) + a.shape[1:], fill, a.dtype).at[:R].set(a)
-
+    d = pos_all.shape[1]
+    block, r_pad = row_tiles(R)
+    nbr_t = jnp.pad(nbr.T, ((0, 0), (0, r_pad - R)))          # (K, r_pad)
+    x_t = jnp.pad(x_rows.T, ((0, 0), (0, r_pad - R)))[:, None, :]
+    valid_t = jnp.pad(valid.T.astype(jnp.int32), ((0, 0), (0, r_pad - R)))
+    tile = pl.BlockSpec((K, block), lambda i: (0, i))
+    per_dim = lambda rows: pl.BlockSpec((d, rows, block), lambda i: (0, 0, i))
     out = pl.pallas_call(
         _accel_kernel,
-        grid=(r_pad // BLOCK_R,),
+        grid=(r_pad // block,),
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((V, d), lambda i: (0, 0)),
-            pl.BlockSpec((V,), lambda i: (0,)),
-            pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_R, K), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_R, K), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            per_dim(K),
+            tile,
+            per_dim(1),
+            tile,
         ],
-        out_specs=pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r_pad, d), jnp.float32),
+        out_specs=per_dim(1),
+        out_shape=jax.ShapeDtypeStruct((d, 1, r_pad), jnp.float32),
         interpret=interpret,
     )(
-        jnp.asarray(rc2, jnp.float32).reshape(1),
-        pos_all,
-        mass_all,
-        pad(x_rows, 0.0),
-        pad(nbr, 0),
-        pad(valid, False),
+        jnp.asarray(rc2, jnp.float32).reshape(1, 1),
+        jnp.stack([pos_all[:, a][nbr_t] for a in range(d)]),
+        mass_all[nbr_t],
+        x_t,
+        valid_t,
     )
-    return out[:R]
+    return out[:, 0, :R].T

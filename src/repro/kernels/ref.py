@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for every Pallas kernel (allclose-tested in
+"""Pure-jnp oracles for every Pallas kernel (tested in
 tests/test_kernels.py across shape/dtype sweeps)."""
 from __future__ import annotations
 
@@ -14,12 +14,6 @@ def morton_from_cells(cells: jax.Array, bits: int) -> jax.Array:
 
 def hilbert_from_cells(cells: jax.Array, bits: int) -> jax.Array:
     return _sfc.hilbert_key_from_cells(cells, bits)
-
-
-def knapsack_parts(weights: jax.Array, num_parts: int) -> jax.Array:
-    from repro.core import knapsack as _knap
-
-    return _knap.slice_weighted_curve(weights, num_parts)
 
 
 def bucket_search(qkeys: jax.Array, boundary_keys: jax.Array) -> jax.Array:

@@ -1,13 +1,12 @@
 """Pallas TPU kernel: fused stencil row update (paper §I mesh hot loop).
 
-One pass over (rows, K) tiles fuses the neighbor-value gather, the
-validity mask, the ``coeff * (u_nbr - u)`` contribution and the
-K-reduction — the unfused jnp path materializes the (n, K) ``vals`` and
-``contrib`` intermediates in HBM between four separate ops; here each
-grid block stages the FULL owned+ghost value vector into VMEM once
-(cap + gcap float32 — a few KB to low MB for every mesh in the paper's
-experiments, same in-VMEM-directory regime as `bucket_search`) and
-streams the (BLOCK_R, K) index/mask/coefficient tiles past it.
+One pass over (K, rows) tiles fuses the validity mask, the
+``coeff * (u_nbr - u)`` contribution and the K-reduction — the unfused
+jnp path materializes the (n, K) ``contrib`` intermediate in HBM
+between separate ops. The neighbor-value gather ``vals_all[nbr]`` runs
+in XLA ahead of the kernel (Mosaic lowers no vector gather beyond one
+vreg); the gathered tables are laid out (K, rows) so rows fill the 128
+lanes and the K-chain is a sequence of sublane-row adds.
 
 Bit-equality contract: :func:`stencil_update_ref` is THE definition of
 the update — ``u_r + sum_k where(valid, coeff * (vals_all[nbr] - u_r),
@@ -32,8 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK_R = 1024
-VALS_MAX = 1 << 20  # 1M owned+ghost values * 4B = 4 MiB of VMEM
+BLOCK_R = 512  # rows per grid step (lanes)
 
 
 def stencil_update_ref(
@@ -49,27 +47,35 @@ def stencil_update_ref(
     value of each row being updated, ``nbr``/``valid``/``coeff`` (R, K)
     the row-local stencil tables. Returns the (R,) updated centers.
     """
-    vals = vals_all[nbr]
-    contrib = jnp.where(valid, coeff * (vals - u_rows[:, None]), jnp.float32(0.0))
-    # fixed-order K accumulation (see module docstring: NOT jnp.sum)
-    acc = contrib[:, 0]
-    for k in range(1, contrib.shape[1]):
-        acc = acc + contrib[:, k]
+    # one (R,) gather per neighbor column: XLA:TPU compiles a scalar
+    # gather with (R, K) indices ~60x slower (90 s at 1.4M rows), with
+    # the same elementwise arithmetic either way. Fixed-order K
+    # accumulation (see module docstring: NOT jnp.sum).
+    acc = None
+    for k in range(nbr.shape[1]):
+        contrib = jnp.where(
+            valid[:, k], coeff[:, k] * (vals_all[nbr[:, k]] - u_rows), jnp.float32(0.0)
+        )
+        acc = contrib if acc is None else acc + contrib
     return u_rows + acc
 
 
-def _update_kernel(vals_ref, u_ref, nbr_ref, valid_ref, coeff_ref, out_ref):
-    # same jnp expression as stencil_update_ref, on one (BLOCK_R, K) tile
-    vals_all = vals_ref[...]
-    u = u_ref[...]
-    vals = vals_all[nbr_ref[...]]
+def _update_kernel(vals_ref, u_ref, valid_ref, coeff_ref, out_ref):
+    # same expression as stencil_update_ref on one (K, BLOCK_R) tile
+    u = u_ref[...]                                   # (1, BLOCK_R)
     contrib = jnp.where(
-        valid_ref[...], coeff_ref[...] * (vals - u[:, None]), jnp.float32(0.0)
+        valid_ref[...] != 0, coeff_ref[...] * (vals_ref[...] - u), jnp.float32(0.0)
     )
-    acc = contrib[:, 0]
-    for k in range(1, contrib.shape[1]):
-        acc = acc + contrib[:, k]
+    acc = contrib[0:1]
+    for k in range(1, contrib.shape[0]):
+        acc = acc + contrib[k:k + 1]
     out_ref[...] = u + acc
+
+
+def row_tiles(R: int) -> tuple[int, int]:
+    """(block, padded rows) for a lane-major row axis of length R."""
+    block = min(BLOCK_R, pl.cdiv(R, 128) * 128)
+    return block, pl.cdiv(R, block) * block
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -82,37 +88,31 @@ def fused_stencil_update(
     *,
     interpret: bool = True,
 ) -> jax.Array:
-    """Fused gather + mask + contribution + K-reduce, one kernel dispatch.
+    """Fused mask + contribution + K-reduce, one kernel dispatch after
+    the XLA gather.
 
     Pad rows (``valid`` all False) pass their center value through
     unchanged up to ``+0.0`` — exactly what the unfused path computes.
     """
     R, K = nbr.shape
-    V = vals_all.shape[0]
-    assert V <= VALS_MAX, "owned+ghost vector must fit VMEM (tile vals_all beyond)"
-    r_pad = pl.cdiv(R, BLOCK_R) * BLOCK_R
+    block, r_pad = row_tiles(R)
 
-    def pad(a, fill):
-        return jnp.full((r_pad,) + a.shape[1:], fill, a.dtype).at[:R].set(a)
+    def cols(a):   # (R, K) -> (K, r_pad), rows on lanes
+        return jnp.pad(a.T, ((0, 0), (0, r_pad - R)))
 
+    tile = pl.BlockSpec((K, block), lambda i: (0, i))
+    row = pl.BlockSpec((1, block), lambda i: (0, i))
     out = pl.pallas_call(
         _update_kernel,
-        grid=(r_pad // BLOCK_R,),
-        in_specs=[
-            pl.BlockSpec((V,), lambda i: (0,)),
-            pl.BlockSpec((BLOCK_R,), lambda i: (i,)),
-            pl.BlockSpec((BLOCK_R, K), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_R, K), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_R, K), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_R,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((r_pad,), jnp.float32),
+        grid=(r_pad // block,),
+        in_specs=[tile, row, tile, tile],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((1, r_pad), jnp.float32),
         interpret=interpret,
     )(
-        vals_all,
-        pad(u_rows, 0.0),
-        pad(nbr, 0),
-        pad(valid, False),
-        pad(coeff, 0.0),
+        vals_all[cols(nbr)],
+        jnp.pad(u_rows, (0, r_pad - R))[None, :],
+        cols(valid.astype(jnp.int32)),
+        cols(coeff),
     )
-    return out[:R]
+    return out[0, :R]

@@ -8,16 +8,11 @@ data parallelism (see DESIGN.md §6).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
 
-    def _axis_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-
-except ImportError:  # jax 0.4.x: every axis is implicitly Auto
-    def _axis_kw(n: int) -> dict:
-        return {}
+def _axis_kw(n: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

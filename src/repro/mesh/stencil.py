@@ -46,7 +46,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat as _compat
 from repro.kernels import ops as _ops
 from repro.mesh.halo import GID_SENTINEL, HaloPlan, MovePlan
 
@@ -136,7 +135,7 @@ def _stencil_fn(
 
     spec = P(axes)
     in_specs = (P(),) + (spec,) * (7 + len(stage_meta))
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False,
     ))
 
@@ -163,7 +162,7 @@ def _stencil_fn_presplit(
 
     spec = P(axes)
     in_specs = (spec,) * (5 + len(stage_meta))
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False,
     ))
 
@@ -252,7 +251,7 @@ def _phase_fns(mesh: jax.sharding.Mesh, axes: tuple, stage_meta: tuple):
         vals_all = jnp.concatenate([u, ghosts])
         return _rows_update(u, u, vals_all, nbr, valid, coeff, rows, False)
 
-    wrap = lambda f, n: jax.jit(_compat.shard_map(
+    wrap = lambda f, n: jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(spec,) * n, out_specs=spec, check_vma=False,
     ))
     return (
@@ -333,7 +332,7 @@ def _move_fn(
 
     spec = P(axes)
     in_specs = (spec,) * (3 + len(stage_meta))
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False,
     ))
 

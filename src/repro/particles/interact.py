@@ -37,7 +37,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat as _compat
 from repro.core import curve_index as _ci
 from repro.kernels import ops as _ops
 from repro.mesh import halo as _halo
@@ -336,7 +335,7 @@ def _leapfrog_fn(
 
     spec = P(axes)
     in_specs = (P(), P(), P()) + (spec,) * (9 + len(stage_meta))
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=(spec, spec),
         check_vma=False,
     ))
@@ -378,7 +377,7 @@ def _exchange_fn(mesh: jax.sharding.Mesh, axes: tuple, stage_meta: tuple):
 
     spec = P(axes)
     in_specs = (spec,) * (2 + len(stage_meta))
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False,
     ))
 
@@ -433,7 +432,7 @@ def _move_cols_fn(
 
     spec = P(axes)
     in_specs = (spec,) * (3 + len(stage_meta))
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False,
     ))
 

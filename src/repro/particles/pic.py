@@ -40,7 +40,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat as _compat
 from repro.kernels import ops as _ops
 from repro.mesh import amr as _amr
 from repro.mesh import halo as _halo
@@ -228,7 +227,7 @@ def _pic_fn(
 
     spec = P(axes)
     in_specs = (P(), P(), P()) + (spec,) * (8 + len(stage_meta))
-    return jax.jit(_compat.shard_map(
+    return jax.jit(jax.shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False,
     ))
 

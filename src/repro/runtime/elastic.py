@@ -76,12 +76,9 @@ def mesh_from_devices(
     a grown pool) — `launch.mesh.make_mesh` always takes the default
     device order, which a shrunken pool no longer matches."""
     arr = np.asarray(devices, dtype=object).reshape(shape)
-    try:  # jax >= 0.5: explicit-sharding axis types
-        from jax.sharding import AxisType
-
-        return jax.sharding.Mesh(arr, axes, axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        return jax.sharding.Mesh(arr, axes)
+    return jax.sharding.Mesh(
+        arr, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 @dataclass(frozen=True)

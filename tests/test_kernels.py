@@ -1,5 +1,6 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret
-mode (the harness kernel-validation contract)."""
+"""Pallas kernels vs their pure-jnp definitions: shape sweeps in
+interpret mode, bit-equal. (Compilation for the TPU is checked in
+tests/test_tpu_compile.py.)"""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -7,7 +8,6 @@ import pytest
 from repro.core import sfc
 from repro.kernels import bucket_search as bsk
 from repro.kernels import hilbert as hk
-from repro.kernels import knapsack_scan as kk
 from repro.kernels import morton as mk
 from repro.kernels import ref
 
@@ -54,29 +54,9 @@ def test_hilbert_kernel_sweep(n, d, bits, rng):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
 
 
-@pytest.mark.parametrize("n", [64, 4096, pytest.param(5000, marks=pytest.mark.slow), pytest.param(16384, marks=pytest.mark.slow)])
-@pytest.mark.parametrize("p", [2, 16, 63])
-def test_knapsack_kernel_sweep(n, p, rng):
-    w = jnp.asarray((rng.random(n) + 0.05).astype(np.float32))
-    out = kk.knapsack_parts(w, p)
-    expect = ref.knapsack_parts(w, p)
-    out_h, exp_h = np.asarray(out), np.asarray(expect)
-    if np.array_equal(out_h, exp_h):
-        return
-    # the blocked Pallas scan and the jnp cumsum associate float32 adds
-    # differently; an element whose center of mass lands (numerically) on
-    # a part boundary may legally flip one part. Anything else is a bug.
-    mism = np.nonzero(out_h != exp_h)[0]
-    assert np.abs(out_h[mism] - exp_h[mism]).max() <= 1, (n, p, mism[:8])
-    w64 = np.asarray(w, np.float64)
-    prefix = np.cumsum(w64) - w64
-    ideal = w64.sum() / p
-    frac = (prefix[mism] + 0.5 * w64[mism]) / ideal
-    dist = np.abs(frac - np.round(frac))
-    assert dist.max() < 1e-3, (n, p, dist.max())
-
-
-@pytest.mark.parametrize("q,b", [(100, 17), (4096, 128), (2048, 1024), (100, 1)])
+@pytest.mark.parametrize(
+    "q,b", [(100, 17), (4096, 128), (2048, 1024), (100, 1), (1500, 9000)]
+)
 def test_bucket_search_kernel_sweep(q, b, rng):
     bk = jnp.sort(jnp.asarray(rng.integers(0, 2**31, b).astype(np.uint32)))
     qk = jnp.asarray(rng.integers(0, 2**31, q).astype(np.uint32))
@@ -86,11 +66,28 @@ def test_bucket_search_kernel_sweep(q, b, rng):
 
 
 def test_bucket_search_exact_boundaries():
-    bk = jnp.asarray([10, 20, 30], jnp.uint32)
-    qk = jnp.asarray([5, 10, 15, 20, 29, 30, 31], jnp.uint32)
+    """Equal keys, duplicate boundaries, the extremes of the key range
+    (0xFFFFFFFF is also the directory's pad key) and the sign bit."""
+    bk = jnp.asarray([10, 20, 20, 30, 2**31, 0xFFFFFFFF], jnp.uint32)
+    qk = jnp.asarray(
+        [0, 5, 10, 15, 20, 29, 30, 31, 2**31 - 1, 2**31, 2**32 - 2, 2**32 - 1],
+        jnp.uint32,
+    )
     out = np.asarray(bsk.bucket_search(qk, bk))
     expect = np.asarray(ref.bucket_search(qk, bk))
     np.testing.assert_array_equal(out, expect)
+
+
+@pytest.mark.parametrize("q,d,b", [(100, 3, 17), (3000, 3, 700), (555, 2, 64)])
+def test_fused_locate_matches_definition(q, d, b, rng):
+    """Fused interleave + search == Morton keys of the same cells
+    searched by the jnp definition."""
+    bits = sfc.max_bits_per_dim(d)
+    cells = sfc.quantize(jnp.asarray(rng.random((q, d)), jnp.float32), bits)
+    bk = jnp.sort(jnp.asarray(rng.integers(0, 2**32, b, dtype=np.uint64).astype(np.uint32)))
+    out = bsk.fused_locate(cells, bk, bits)
+    expect = ref.bucket_search(ref.morton_from_cells(cells, bits), bk)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
 
 
 # ---------------------------------------------------------------------------

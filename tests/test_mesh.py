@@ -28,6 +28,7 @@ def _run(code: str, devices: int = 8) -> str:
         " --xla_backend_optimization_level=0"
     )
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # fake host devices; never a chip the parent holds
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, env=env, timeout=560,

@@ -62,6 +62,34 @@ def test_pallas_key_search_matches_jnp(rng):
     np.testing.assert_array_equal(np.asarray(r_ref.ids), np.asarray(r_pal.ids))
 
 
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_rank_is_searchsorted_over_live_keys(use_pallas, rng):
+    """The directory hop + in-bucket count equals searchsorted over the
+    live keys: key runs spanning several buckets, keys below the first
+    and above the last, key 0, and a sentinel tail of dead slots."""
+    from repro.core import curve_index as ci
+
+    n_valid, cap = 600, 700
+    live = np.sort(np.concatenate([
+        rng.integers(1, 2**32 - 1, n_valid - 100, dtype=np.uint64),
+        np.full(100, 2**31, np.uint64),       # one run across ~6 buckets
+    ])).astype(np.uint32)
+    keys = np.concatenate([live, np.full(cap - n_valid, 0xFFFFFFFF, np.uint32)])
+    idx = ci.from_sorted(
+        jnp.zeros((cap, 3), jnp.float32), jnp.arange(cap, dtype=jnp.int32),
+        jnp.asarray(keys), n_valid=n_valid, frame_lo=jnp.zeros(3),
+        frame_hi=jnp.ones(3), bits=10, bucket_size=16,
+    )
+    qk = np.concatenate([
+        live[rng.integers(0, n_valid, 200)],
+        rng.integers(0, 2**32, 200, dtype=np.uint64).astype(np.uint32),
+        np.asarray([0, 1, 2**31 - 1, 2**31, 2**31 + 1, 2**32 - 1], np.uint32),
+    ])
+    for strict, side in ((True, "left"), (False, "right")):
+        got = queries._rank(idx, jnp.asarray(qk), strict, use_pallas)
+        np.testing.assert_array_equal(np.asarray(got), np.searchsorted(live, qk, side=side))
+
+
 @pytest.mark.parametrize("k", [pytest.param(1, marks=pytest.mark.slow), 3, pytest.param(5, marks=pytest.mark.slow)])
 def test_knn_recall(k, rng):
     pts = jnp.asarray(rng.random((4096, 3)), jnp.float32)

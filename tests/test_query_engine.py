@@ -104,6 +104,7 @@ def test_distributed_routing_subprocess():
         " --xla_backend_optimization_level=0"
     )
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # fake host devices; never a chip the parent holds
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import queries
@@ -221,6 +222,7 @@ def test_tree_backed_and_skew_replication_subprocess():
         " --xla_backend_optimization_level=0"
     )
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # fake host devices; never a chip the parent holds
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import queries
@@ -311,6 +313,7 @@ def test_lane_subset_replication_subprocess():
         " --xla_backend_optimization_level=0"
     )
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # fake host devices; never a chip the parent holds
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import queries

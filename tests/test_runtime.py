@@ -161,6 +161,7 @@ def test_elastic_reshard_mid_serve_subprocess():
         " --xla_backend_optimization_level=0"
     )
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # fake host devices; never a chip the parent holds
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import queries
