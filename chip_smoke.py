@@ -10,23 +10,25 @@ One chip runs four phases:
 
 * partition — 2^24 clustered 3-D points with random weights: the point
   path (jnp and Pallas Hilbert keys, which must agree bit for bit) into
-  64 parts, and the kd-tree bucket path over the first 2^22 of them,
-  each within the README's load spread bound; then a tree-mode
-  ``Repartitioner`` through 5 weight-drift steps and one insert/delete
-  batch, checking weight conservation and the bound after every step.
+  64 parts, its knapsack checked against a float64 host knapsack on the
+  same curve order; the Pallas Morton keys against ``sfc.morton_key``;
+  the kd-tree bucket path over the first 2^22 points within the
+  README's bucket spread bound; then a tree-mode ``Repartitioner``
+  through 5 weight-drift steps and one insert/delete batch, checking
+  weight conservation and the bound after every step.
 * mesh — the AMR heat-stencil simulation (2-D, 2^20 base cells) on a
-  (1, 1) mesh against its single-device reference, bit-equal; plus the
-  Pallas stencil kernel against its jnp definition on the final mesh.
+  (1, 1) mesh with the Pallas stencil row update, bit-equal to its
+  single-device jnp reference.
 * particles — the N-body short-range loop (32,768 atoms, ~40
   neighbours) with the Pallas force kernel on a (1, 1) mesh against the
   jnp reference, bit-equal.
 * queries — a point index over the partition phase's points; point
-  location and kNN (k=8) for 2^20 queries, half of them hits, with the
-  Pallas search kernels against the same calls without them.
+  location and kNN (k=8) for 2^20 queries, half of them hits, a sample
+  of 4096 checked against plain numpy implementations of both.
 
 ``--chips 4`` runs only what exists across chips: the mesh simulation on
-the (2, 2) mesh against its reference, the distributed bucket
-repartitioner against the single-device hierarchical partition, and the
+the (2, 2) mesh (base level 9, 8 events) against its reference, the
+distributed bucket repartitioner against the single-device hierarchical partition, and the
 two-level distributed query engine against local answers, then prints
 every device's peak memory.
 
@@ -125,6 +127,10 @@ def _spread(loads) -> float:
     return float(loads.max() - loads.min())
 
 
+def _rel_err(got: np.ndarray, want: np.ndarray) -> float:
+    return float((np.abs(got - want) / np.maximum(want, 1e-30)).max())
+
+
 def _kernel_impl(use_pallas: bool) -> str:
     from repro.kernels import ops as kops
 
@@ -157,10 +163,53 @@ def clustered_points(seed: int, n: int, d: int = 3, clusters: int = 32):
 # one-chip phases
 # ---------------------------------------------------------------------------
 
+def knapsack_reference(w_sorted, parts: int) -> tuple[np.ndarray, np.ndarray]:
+    """The greedy midpoint-rule knapsack of ``knapsack.slice_weighted_curve``
+    in float64 on the host: (part per element, exclusive prefix)."""
+    w = np.asarray(w_sorted, np.float64)
+    prefix = np.cumsum(w) - w
+    ideal = (prefix[-1] + w[-1]) / parts
+    part = np.clip(np.floor((prefix + 0.5 * w) / ideal), 0, parts - 1).astype(np.int32)
+    return part, prefix
+
+
+def cut_shift(part_sorted, ref_part, prefix, w_sorted, parts: int) -> float:
+    """Largest weight between a part's first element on the device and
+    in the reference: how far float32 rounding moved any cut."""
+    ext = np.append(prefix, prefix[-1] + float(np.asarray(w_sorted[-1], np.float64)))
+    p = np.arange(parts)
+    got = np.searchsorted(np.asarray(part_sorted), p, side="left")
+    want = np.searchsorted(ref_part, p, side="left")
+    return float(np.abs(ext[got] - ext[want]).max())
+
+
+def _check_knapsack(ph: "Phase", w_sorted, part_sorted, loads, parts: int) -> None:
+    """README: the knapsack's load spread is <= 2 x the largest element
+    weight — in exact arithmetic, so it is checked on a float64 host
+    knapsack over the same curve-sorted weights. The device knapsack
+    works on a float32 prefix; its cuts must match the reference's to
+    within one element plus 4 ulp of the total weight (DESIGN.md, "Load
+    balance in float32")."""
+    w_max = float(jnp.max(w_sorted))
+    ref_part, prefix = knapsack_reference(w_sorted, parts)
+    ref_loads = np.bincount(ref_part, weights=np.asarray(w_sorted, np.float64), minlength=parts)
+    total = float(np.float32(prefix[-1]))
+    tol = w_max + 4 * float(np.spacing(np.float32(total)))
+    shift = cut_shift(part_sorted, ref_part, prefix, w_sorted, parts)
+    ph.line(spread_device=_spread(loads), spread_reference=_spread(ref_loads),
+            w_max=w_max, max_cut_shift=shift)
+    ph.at_most("reference spread <= 2 max element weight", _spread(ref_loads), 2 * w_max)
+    ph.at_most("device cuts within w_max + 4 ulp(total) of reference", shift, tol)
+    ph._record("device parts non-decreasing along the curve",
+               bool((np.diff(np.asarray(part_sorted)) >= 0).all()))
+
+
 def phase_partition(seed: int, n: int = 1 << 24, tree_n: int = 1 << 22,
                     parts: int = 64, drift_steps: int = 5, delta: int = 1 << 16):
     from repro.core import partitioner as pt
+    from repro.core import sfc
     from repro.core.repartition import Repartitioner
+    from repro.kernels import ops as kops
 
     ph = Phase("partition")
     pts, w = ph.run("generate", lambda: clustered_points(seed, n))
@@ -173,18 +222,11 @@ def phase_partition(seed: int, n: int = 1 << 24, tree_n: int = 1 << 22,
     ph.line(hilbert_keys=_kernel_impl(True))
     ph.equal("pallas hilbert keys == sfc.hilbert_key", res_p.keys, res_j.keys)
     ph.equal("pallas parts == xla parts", res_p.part, res_j.part)
-    # README: spread <= 2 x max element weight, in exact arithmetic. The
-    # knapsack places boundaries on a float32 prefix sum, which moves
-    # each boundary by up to 2 (E + 2 ulp(total)), E the prefix's
-    # rounding error measured here (DESIGN.md, "Load balance in float32")
-    w_max = float(jnp.max(w))
-    w_sorted = w[res_j.perm]
-    exact = np.cumsum(np.asarray(w_sorted, np.float64))
-    prefix_err = float(np.abs(np.asarray(jnp.cumsum(w_sorted), np.float64) - exact).max())
-    shift = 2 * (prefix_err + 2 * float(np.spacing(np.float32(exact[-1]))))
-    ph.line(prefix_err=prefix_err, boundary_shift=shift)
-    ph.at_most("point spread <= 2 max element weight + 4 boundary shift",
-               _spread(res_j.loads), 2 * w_max + 4 * shift)
+    mk_p = ph.run("morton_keys_pallas", lambda: jax.jit(kops.morton_key)(pts))
+    mk_x = ph.run("morton_keys_xla", lambda: jax.jit(sfc.morton_key)(pts))
+    ph.line(morton_keys=_kernel_impl(True))
+    ph.equal("pallas morton keys == sfc.morton_key", mk_p, mk_x)
+    _check_knapsack(ph, w[res_j.perm], res_j.part[res_j.perm], res_j.loads, parts)
     ph.close("point loads conserve weight", float(np.sum(np.asarray(res_j.loads, np.float64))),
              w_total, 1e-4)
 
@@ -234,41 +276,32 @@ def phase_partition(seed: int, n: int = 1 << 24, tree_n: int = 1 << 22,
     return pts
 
 
-def _mesh_config(base_level: int):
+def _mesh_config(base_level: int, events: int):
     from repro.mesh.simulate import SimConfig
 
     return SimConfig(d=2, base_level=base_level, max_level=base_level + 1,
-                     events=12, amr_every=4, substeps=8)
+                     events=events, amr_every=4, substeps=8)
 
 
-def phase_mesh(jax_mesh, hplan, base_level: int = 10, name: str = "mesh"):
-    from repro.kernels import ops as kops
+def phase_mesh(jax_mesh, hplan, base_level: int = 10, events: int = 12, name: str = "mesh"):
     from repro.mesh import simulate as sim
 
     ph = Phase(name)
-    cfg = _mesh_config(base_level)
+    cfg = _mesh_config(base_level, events)
     events = ph.run("trajectory_host", lambda: sim.build_trajectory(cfg))
     u0 = sim.initial_field(events[0].mesh, cfg)
     ph.line(d=cfg.d, base_level=cfg.base_level, max_level=cfg.max_level,
             cells_first=events[0].mesh.n, cells_last=events[-1].mesh.n,
-            events=cfg.events, substeps=cfg.substeps, mesh=dict(jax_mesh.shape))
-    ref = ph.run("reference", lambda: sim.run_reference(events, u0, cfg.substeps))
-    got, st = ph.run("distributed_incremental", lambda: sim.run_distributed(
-        events, u0, cfg.substeps, jax_mesh, hplan, driver="incremental", cfg=cfg))
+            events=cfg.events, substeps=cfg.substeps, mesh=dict(jax_mesh.shape),
+            stencil_row_update=_kernel_impl(True))
+    ref = ph.run("reference_xla", lambda: sim.run_reference(events, u0, cfg.substeps))
+    got, st = ph.run("distributed_incremental_pallas", lambda: sim.run_distributed(
+        events, u0, cfg.substeps, jax_mesh, hplan, driver="incremental", cfg=cfg,
+        use_pallas=True))
     ph.line(stencil_s=round(st.stencil_s, 3), plan_build_s=round(st.plan_build_s, 3),
             engine_s=round(st.engine_s, 3), repartition_events=st.repartition_events,
             amr_events=st.amr_events)
     ph.equal("distributed field == reference", got, ref)
-
-    if jax_mesh.size == 1:
-        ev = events[-1]
-        upd = jax.jit(kops.stencil_update, static_argnames=("use_pallas",))
-        args = (jnp.asarray(got), jnp.asarray(got), jnp.asarray(ev.nbr),
-                jnp.asarray(ev.nbr >= 0), jnp.asarray(ev.coeff, jnp.float32))
-        ph.line(stencil_kernel=_kernel_impl(True), rows=ev.nbr.shape[0], K=ev.nbr.shape[1])
-        a = ph.run("stencil_xla", lambda: upd(*args, use_pallas=False))
-        b = ph.run("stencil_pallas", lambda: upd(*args, use_pallas=True))
-        ph.equal("pallas stencil sweep == jnp definition", b, a)
     ph.done()
 
 
@@ -300,25 +333,82 @@ def _query_batch(seed: int, pts, nq: int):
     return jnp.concatenate([hits, rand])
 
 
-def phase_queries(seed: int, pts, nq: int = 1 << 20, k: int = 8):
+class _HostIndex:
+    """The index's arrays on the host, for the plain numpy references."""
+
+    def __init__(self, idx):
+        self.keys = np.asarray(idx.keys)
+        self.points = np.asarray(idx.points)
+        self.ids = np.asarray(idx.ids)
+        self.bucket_keys = np.asarray(idx.bucket_keys)
+        self.starts = np.asarray(idx.bucket_starts).astype(np.int64)
+        self.n_valid = int(self.starts[-1])
+        self.max_bucket_len = idx.max_bucket_len
+
+
+def host_point_location(h: _HostIndex, q: np.ndarray, qk: np.ndarray, cap: int):
+    """Exact point location in numpy: scan the query key's run of the
+    sorted live keys for an equal coordinate (first match wins)."""
+    lo = np.searchsorted(h.keys[: h.n_valid], qk, side="left")
+    hi = np.searchsorted(h.keys[: h.n_valid], qk, side="right")
+    pos = lo[:, None] + np.arange(cap)[None, :]
+    cand = np.minimum(pos, h.keys.shape[0] - 1)
+    hit = (h.points[cand] == q[:, None, :]).all(-1) & (pos < hi[:, None])
+    found = hit.any(1)
+    gid = h.ids[cand[np.arange(q.shape[0]), hit.argmax(1)]]
+    return found, np.where(found, gid, -1).astype(np.int32), found | (hi - lo <= cap)
+
+
+def host_knn_distances(h: _HostIndex, q: np.ndarray, qk: np.ndarray, k: int,
+                       cutoff: int = 1, max_window: int = 1024) -> np.ndarray:
+    """k smallest float64 distances over the query's bucket +- cutoff
+    along the curve (the window ``queries.knn`` searches), ascending."""
+    nb = h.bucket_keys.shape[0]
+    b = np.clip(np.searchsorted(h.bucket_keys, qk, side="right") - 1, 0, nb - 1)
+    start = h.starts[np.clip(b - cutoff, 0, nb - 1)]
+    end = h.starts[np.clip(b + cutoff, 0, nb - 1) + 1]
+    win = max(k, min(h.keys.shape[0], h.max_bucket_len * (2 * cutoff + 1), max_window))
+    pos = start[:, None] + np.arange(win)[None, :]
+    cand = np.minimum(pos, h.keys.shape[0] - 1)
+    d2 = ((h.points[cand].astype(np.float64) - q[:, None, :]) ** 2).sum(-1)
+    d2 = np.where(pos < end[:, None], d2, np.inf)
+    return np.sqrt(np.sort(d2, axis=1)[:, :k])
+
+
+def phase_queries(seed: int, pts, nq: int = 1 << 20, k: int = 8, sample: int = 4096):
+    """Point location and kNN over the whole batch; a sample of queries
+    from both halves is checked against the numpy references."""
+    from repro.core import curve_index as ci
     from repro.core import queries
 
     ph = Phase("queries")
     idx = ph.run("build_index", lambda: queries.build_index(pts))
     q = _query_batch(seed, pts, nq)
     ph.line(points=pts.shape[0], buckets=idx.num_buckets, queries=nq, k=k,
-            search_kernels=_kernel_impl(True))
-    pl_k = ph.run("point_location_pallas", lambda: queries.point_location(idx, q, use_pallas=True))
-    pl_x = ph.run("point_location_xla", lambda: queries.point_location(idx, q, use_pallas=False))
-    for f in ("found", "ids", "ok"):
-        ph.equal(f"point_location {f} pallas == xla", getattr(pl_k, f), getattr(pl_x, f))
-    found = np.asarray(pl_k.found)
-    ph._record("every hit query found", bool(found[: nq // 2].all()),
-               found=int(found.sum()))
-    kn_k = ph.run("knn_pallas", lambda: queries.knn(idx, q, k=k, use_pallas=True))
-    kn_x = ph.run("knn_xla", lambda: queries.knn(idx, q, k=k, use_pallas=False))
-    ph.equal("knn distances pallas == xla", kn_k[0], kn_x[0])
-    ph.equal("knn ids pallas == xla", kn_k[1], kn_x[1])
+            key_search="xla-searchsorted", reference_sample=sample)
+    pl = ph.run("point_location", lambda: queries.point_location(idx, q))
+    dist, ids = ph.run("knn", lambda: queries.knn(idx, q, k=k))
+    found = np.asarray(pl.found)
+    ph._record("every hit query found", bool(found[: nq // 2].all()), found=int(found.sum()))
+
+    half = sample // 2
+    sel = np.concatenate([np.arange(half), nq // 2 + np.arange(half)])
+    h = _HostIndex(idx)
+    q_s = np.asarray(q)[sel]
+    qk_s = np.asarray(ci.query_keys(idx, jnp.asarray(q_s)))
+    want = host_point_location(h, q_s, qk_s, 64)
+    for f, w in zip(("found", "ids", "ok"), want):
+        ph.equal(f"point_location {f} == numpy reference", np.asarray(getattr(pl, f))[sel], w)
+    d_got = np.asarray(dist)[sel].astype(np.float64)
+    d_want = host_knn_distances(h, q_s, qk_s, k)
+    # float32 distances: a few ulp from float64 (a wrong neighbour is off
+    # by far more)
+    ph.at_most("knn distances vs numpy float64 reference, relative",
+               _rel_err(d_got, d_want), 1e-5)
+    # every returned id is a stored point at the reported distance
+    p_ids = np.asarray(pts)[np.asarray(ids)[sel]].astype(np.float64)
+    d_ids = np.sqrt(((p_ids - q_s[:, None, :]) ** 2).sum(-1))
+    ph.at_most("knn ids are at their distances, relative", _rel_err(d_got, d_ids), 1e-5)
     ph.done()
 
 
@@ -327,7 +417,7 @@ def phase_queries(seed: int, pts, nq: int = 1 << 20, k: int = 8):
 # ---------------------------------------------------------------------------
 
 def phase_four_chips(seed: int, n: int = 1 << 21, nq: int = 1 << 18,
-                     base_level: int = 9):
+                     base_level: int = 9, events: int = 8):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core import partitioner as pt
@@ -338,7 +428,7 @@ def phase_four_chips(seed: int, n: int = 1 << 21, nq: int = 1 << 18,
 
     jm = shd.make_node_device_mesh(2, 2)
     plan = pt.HierarchyPlan(2, 2)
-    phase_mesh(jm, plan, base_level=base_level, name="mesh_2x2")
+    phase_mesh(jm, plan, base_level=base_level, events=events, name="mesh_2x2")
 
     ph = Phase("bucket_repartitioner_2x2")
     pts, w = clustered_points(seed, n)

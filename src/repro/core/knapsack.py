@@ -33,7 +33,10 @@ def slice_weighted_curve(weights: jax.Array, num_parts: int) -> jax.Array:
     ideal = jnp.where(ideal > 0, ideal, 1.0)
     # midpoint rule: assign by the element's center of mass on the segment
     part = jnp.floor((prefix + 0.5 * w) / ideal).astype(jnp.int32)
-    return jnp.clip(part, 0, num_parts - 1)
+    # once an ulp of the float32 prefix exceeds half an element weight
+    # (2^24 elements of weight ~1), the rounded centers are no longer
+    # monotone: the running max keeps every part one contiguous slice
+    return jax.lax.cummax(jnp.clip(part, 0, num_parts - 1))
 
 
 @functools.partial(jax.jit, static_argnames=("num_parts",))
@@ -96,7 +99,11 @@ def device_slice_within_nodes(
     ideal = node_tot[node] / devices_per_node
     ideal = jnp.where(ideal > 0, ideal, 1.0)
     dev = jnp.floor((local_prefix + 0.5 * w) / ideal).astype(jnp.int32)
-    return jnp.clip(dev, 0, devices_per_node - 1)
+    dev = jnp.clip(dev, 0, devices_per_node - 1)
+    # contiguous within each node, as in slice_weighted_curve (node is
+    # non-decreasing, so the running max never crosses a node boundary)
+    base = node * devices_per_node
+    return jax.lax.cummax(base + dev) - base
 
 
 @functools.partial(jax.jit, static_argnames=("num_nodes", "devices_per_node"))

@@ -9,12 +9,9 @@
 
 Both run against a shared :class:`repro.core.curve_index.CurveIndex`
 (built cold here, or refreshed incrementally from a ``Repartitioner``'s
-cached keys). Every key search is a directory hop — the last bucket
-whose first key is <= the query key — followed by a count inside that
-one bucket. ``use_pallas`` picks the hop's implementation: the Pallas
-``bucket_search`` / ``fused_locate`` kernels or ``jnp.searchsorted``.
-Both give identical answers; the default is the kernels on a TPU and
-``jnp.searchsorted`` elsewhere (interpreted kernels are slow).
+cached keys). Every key search is ``jnp.searchsorted`` — over the
+bucket directory to locate a query's bucket, over the live sorted keys
+to bound its key-equal run.
 """
 from __future__ import annotations
 
@@ -30,13 +27,6 @@ from repro.core import curve_index as _ci
 # partitioner; ``QueryIndex`` remains as a compatibility alias.
 CurveIndex = _ci.CurveIndex
 QueryIndex = _ci.CurveIndex
-
-
-def _pallas_default() -> bool:
-    """Kernels by default only where they compile (see module note)."""
-    from repro.kernels import ops as _kops
-
-    return not _kops.interpret()
 
 
 def build_index(
@@ -56,55 +46,24 @@ def build_index(
     return _ci.build(points, ids, bucket_size=bucket_size, bits=bits, curve="morton")
 
 
-def _directory_hop(bucket_keys: jax.Array, qk: jax.Array, use_pallas: bool) -> jax.Array:
-    """Last bucket whose first key is <= each query key (clamped to 0)."""
-    if use_pallas:
-        from repro.kernels import ops as _kops
-
-        return _kops.bucket_search(qk, bucket_keys)
-    b = jnp.searchsorted(bucket_keys, qk, side="right").astype(jnp.int32) - 1
-    return jnp.clip(b, 0, bucket_keys.shape[0] - 1)
+def _rank(index: CurveIndex, qk: jax.Array, side: str) -> jax.Array:
+    """``searchsorted`` over the live keys: clamped to ``n_valid`` so a
+    query never counts the sentinel tail of dead slots."""
+    r = jnp.searchsorted(index.keys, qk, side=side).astype(jnp.int32)
+    return jnp.minimum(r, index.valid_count())
 
 
-def _rank(index: CurveIndex, qk: jax.Array, strict: bool, use_pallas: bool) -> jax.Array:
-    """Number of live stored keys < qk (``strict``) or <= qk.
-
-    Exact for integer keys: the hop finds bucket b, the last whose first
-    key is <= the probe. Every key before b's start is <= that first key
-    and every key from the next bucket on is > the probe, so the rank is
-    b's start plus a count inside b's rows (at most ``max_bucket_len``)."""
-    probe = qk - jnp.uint32(1) if strict else qk
-    b = _directory_hop(index.bucket_keys, probe, use_pallas)
-    start = index.bucket_starts[b]
-    end = index.bucket_starts[b + 1]
-    pos = start[:, None] + jnp.arange(index.max_bucket_len, dtype=jnp.int32)[None, :]
-    keys = index.keys[jnp.clip(pos, 0, index.capacity - 1)]
-    rank = start + jnp.sum((pos < end[:, None]) & (keys <= probe[:, None]), axis=1)
-    if strict:   # qk == 0: nothing is smaller (the probe wrapped around)
-        rank = jnp.where(qk > jnp.uint32(0), rank, 0)
-    return rank.astype(jnp.int32)
+@jax.jit
+def _locate_bucket(index: CurveIndex, queries: jax.Array) -> jax.Array:
+    qk = _ci.query_keys(index, queries)
+    b = jnp.searchsorted(index.bucket_keys, qk, side="right").astype(jnp.int32) - 1
+    return jnp.clip(b, 0, index.num_buckets - 1)
 
 
-@functools.partial(jax.jit, static_argnames=("use_pallas",))
-def _locate_bucket(index: CurveIndex, queries: jax.Array, use_pallas: bool) -> jax.Array:
-    if use_pallas and index.curve == "morton" and index.tree is None:
-        # point-keyed Morton index: interleave + hop fused in one kernel
-        # (tree-backed keys come from a tree walk, not from coordinates)
-        from repro.kernels import ops as _kops
-
-        return _kops.fused_locate(
-            queries, index.bucket_keys, index.frame_lo, index.frame_hi, index.bits
-        )
-    return _directory_hop(index.bucket_keys, _ci.query_keys(index, queries), use_pallas)
-
-
-def locate_bucket(
-    index: CurveIndex, queries: jax.Array, *, use_pallas: bool | None = None
-) -> jax.Array:
-    """Bucket id per query: the directory hop (see module note)."""
-    if use_pallas is None:
-        use_pallas = _pallas_default()
-    return _locate_bucket(index, queries, use_pallas)
+def locate_bucket(index: CurveIndex, queries: jax.Array) -> jax.Array:
+    """Bucket id per query: the last bucket whose first key is <= the
+    query's key (clamped to 0)."""
+    return _locate_bucket(index, queries)
 
 
 class PointLocation(NamedTuple):
@@ -115,18 +74,16 @@ class PointLocation(NamedTuple):
     #                   certified (raise bucket_cap to resolve)
 
 
-@functools.partial(jax.jit, static_argnames=("bucket_cap", "use_pallas"))
-def _point_location(
-    index: CurveIndex, queries: jax.Array, bucket_cap: int, use_pallas: bool
-) -> PointLocation:
+@functools.partial(jax.jit, static_argnames=("bucket_cap",))
+def _point_location(index: CurveIndex, queries: jax.Array, bucket_cap: int) -> PointLocation:
     qk = _ci.query_keys(index, queries)
     # Exact extent of the key-equal run in the sorted key array. Equal
     # coordinates imply equal keys, so every possible match lies in
     # [lo_i, hi_i) — unlike a single-bucket scan, this cannot silently
     # miss when duplicates spill a bucket (runs spanning bucket or even
     # partition boundaries are covered).
-    lo_i = _rank(index, qk, True, use_pallas)
-    hi_i = _rank(index, qk, False, use_pallas)
+    lo_i = _rank(index, qk, "left")
+    hi_i = _rank(index, qk, "right")
     run = hi_i - lo_i
     n = index.capacity
     offs = jnp.arange(bucket_cap, dtype=jnp.int32)
@@ -146,7 +103,6 @@ def point_location(
     queries: jax.Array,
     *,
     bucket_cap: int = 64,
-    use_pallas: bool | None = None,
 ) -> PointLocation:
     """Exact point location: (found, id or -1, ok).
 
@@ -155,25 +111,22 @@ def point_location(
     distributions) — the scan window was exhausted, so the miss is not a
     certificate of absence.
     """
-    if use_pallas is None:
-        use_pallas = _pallas_default()
-    return _point_location(index, queries, bucket_cap, use_pallas)
+    return _point_location(index, queries, bucket_cap)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("k", "cutoff_buckets", "use_pallas", "max_window")
+    jax.jit, static_argnames=("k", "cutoff_buckets", "max_window")
 )
 def _knn(
     index: CurveIndex,
     queries: jax.Array,
     k: int,
     cutoff_buckets: int,
-    use_pallas: bool,
     max_window: int,
 ) -> tuple[jax.Array, jax.Array]:
     nb = index.num_buckets
     n = index.capacity
-    b = _locate_bucket(index, queries, use_pallas)
+    b = _locate_bucket(index, queries)
     b0 = jnp.clip(b - cutoff_buckets, 0, nb - 1)
     b1 = jnp.clip(b + cutoff_buckets, 0, nb - 1)
     start = index.bucket_starts[b0]
@@ -202,7 +155,6 @@ def knn(
     *,
     k: int = 3,
     cutoff_buckets: int = 1,
-    use_pallas: bool | None = None,
     max_window: int = 1024,
 ) -> tuple[jax.Array, jax.Array]:
     """Approximate k-NN: search the query's bucket ± cutoff_buckets along
@@ -214,9 +166,7 @@ def knn(
 
     Returns (distances (q, k), global ids (q, k)).
     """
-    if use_pallas is None:
-        use_pallas = _pallas_default()
-    return _knn(index, queries, k, cutoff_buckets, use_pallas, max_window)
+    return _knn(index, queries, k, cutoff_buckets, max_window)
 
 
 def knn_bruteforce(points: jax.Array, queries: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:

@@ -11,7 +11,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import sfc as _sfc
-from repro.kernels import bucket_search as _bs
 from repro.kernels import hilbert as _hil
 from repro.kernels import morton as _mor
 from repro.kernels import pair_force as _pf
@@ -120,25 +119,6 @@ def hilbert_key(points: jax.Array, bits: int | None = None, *, stats: str = "geo
         bits = _sfc.max_bits_per_dim(d)
     cells = _sfc.quantize(points, bits, stats)
     return _hil.hilbert_from_cells(cells, bits, interpret=interpret())
-
-
-def bucket_search(qkeys: jax.Array, boundary_keys: jax.Array) -> jax.Array:
-    return _bs.bucket_search(qkeys, boundary_keys, interpret=interpret())
-
-
-def fused_locate(
-    queries: jax.Array,
-    boundary_keys: jax.Array,
-    frame_lo: jax.Array,
-    frame_hi: jax.Array,
-    bits: int,
-) -> jax.Array:
-    """Fused Morton interleave + directory search (one kernel dispatch
-    after the frame quantization): per query point, the index of the
-    last boundary key <= its key. kNN's bucket lookup rides on it when
-    kernels are on."""
-    cells = _sfc.cells_in_frame(queries, frame_lo, frame_hi, bits)
-    return _bs.fused_locate(cells, boundary_keys, bits, interpret=interpret())
 
 
 def stencil_update(

@@ -3,7 +3,6 @@ tests/test_kernels.py across shape/dtype sweeps)."""
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.core import sfc as _sfc
 
@@ -15,7 +14,3 @@ def morton_from_cells(cells: jax.Array, bits: int) -> jax.Array:
 def hilbert_from_cells(cells: jax.Array, bits: int) -> jax.Array:
     return _sfc.hilbert_key_from_cells(cells, bits)
 
-
-def bucket_search(qkeys: jax.Array, boundary_keys: jax.Array) -> jax.Array:
-    idx = jnp.searchsorted(boundary_keys, qkeys, side="right") - 1
-    return jnp.clip(idx, 0, boundary_keys.shape[0] - 1).astype(jnp.int32)

@@ -159,6 +159,7 @@ def run_distributed(
     driver: str = "incremental",
     cfg: SimConfig = SimConfig(),
     phase_probes: bool = False,
+    use_pallas: bool = False,
 ) -> tuple[np.ndarray, SimStats]:
     """Integrate the trajectory on a device mesh under one driver.
 
@@ -168,6 +169,8 @@ def run_distributed(
     ``phase_probes`` additionally attributes sweep walltime to its
     exchange/interior/boundary phases via the single-phase probe
     executors (extra per-event probe calls — reporting, not the gate).
+    ``use_pallas`` runs the sweeps' row update through the Pallas stencil
+    kernel (bit-equal to the jnp definition, so to ``run_reference``).
     """
     import jax
     import jax.numpy as jnp
@@ -303,7 +306,7 @@ def run_distributed(
             st.stencil_boundary_s += substeps * ph["boundary"]
         t0 = time.perf_counter()
         u_dev = jax.block_until_ready(
-            _st.stencil_steps(jax_mesh, plan, u_dev, args, substeps)
+            _st.stencil_steps(jax_mesh, plan, u_dev, args, substeps, use_pallas=use_pallas)
         )
         st.stencil_s += time.perf_counter() - t0
 

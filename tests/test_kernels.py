@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from repro.core import sfc
-from repro.kernels import bucket_search as bsk
 from repro.kernels import hilbert as hk
 from repro.kernels import morton as mk
 from repro.kernels import ref
@@ -51,42 +50,6 @@ def test_hilbert_kernel_sweep(n, d, bits, rng):
     cells = sfc.quantize(pts, bits)
     out = hk.hilbert_from_cells(cells, bits)
     expect = ref.hilbert_from_cells(cells, bits)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
-
-
-@pytest.mark.parametrize(
-    "q,b", [(100, 17), (4096, 128), (2048, 1024), (100, 1), (1500, 9000)]
-)
-def test_bucket_search_kernel_sweep(q, b, rng):
-    bk = jnp.sort(jnp.asarray(rng.integers(0, 2**31, b).astype(np.uint32)))
-    qk = jnp.asarray(rng.integers(0, 2**31, q).astype(np.uint32))
-    out = bsk.bucket_search(qk, bk)
-    expect = ref.bucket_search(qk, bk)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
-
-
-def test_bucket_search_exact_boundaries():
-    """Equal keys, duplicate boundaries, the extremes of the key range
-    (0xFFFFFFFF is also the directory's pad key) and the sign bit."""
-    bk = jnp.asarray([10, 20, 20, 30, 2**31, 0xFFFFFFFF], jnp.uint32)
-    qk = jnp.asarray(
-        [0, 5, 10, 15, 20, 29, 30, 31, 2**31 - 1, 2**31, 2**32 - 2, 2**32 - 1],
-        jnp.uint32,
-    )
-    out = np.asarray(bsk.bucket_search(qk, bk))
-    expect = np.asarray(ref.bucket_search(qk, bk))
-    np.testing.assert_array_equal(out, expect)
-
-
-@pytest.mark.parametrize("q,d,b", [(100, 3, 17), (3000, 3, 700), (555, 2, 64)])
-def test_fused_locate_matches_definition(q, d, b, rng):
-    """Fused interleave + search == Morton keys of the same cells
-    searched by the jnp definition."""
-    bits = sfc.max_bits_per_dim(d)
-    cells = sfc.quantize(jnp.asarray(rng.random((q, d)), jnp.float32), bits)
-    bk = jnp.sort(jnp.asarray(rng.integers(0, 2**32, b, dtype=np.uint64).astype(np.uint32)))
-    out = bsk.fused_locate(cells, bk, bits)
-    expect = ref.bucket_search(ref.morton_from_cells(cells, bits), bk)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
 
 

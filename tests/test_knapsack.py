@@ -2,6 +2,7 @@
 property test (§III-C)."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
 from repro.core import knapsack
@@ -41,6 +42,23 @@ def test_boundaries_consistent():
     for p in range(7):
         seg = part[bounds[p] : bounds[p + 1]]
         assert (seg == p).all() or seg.size == 0
+
+
+@pytest.mark.parametrize("levels", ["flat", "two_level"])
+def test_parts_contiguous_when_float32_centers_are_not(levels):
+    """A heavy head puts the float32 prefix at ~3e7, where one ulp (2)
+    exceeds half of every later weight: the rounded element centers go
+    non-monotone, and the parts must still be contiguous slices."""
+    rng = np.random.default_rng(0)
+    w = rng.uniform(0.5, 1.5, 65536).astype(np.float32)
+    w[0] = 3e7
+    if levels == "flat":
+        part = np.asarray(knapsack.slice_weighted_curve(jnp.asarray(w), 4096))
+    else:
+        node, dev, part = map(np.asarray, knapsack.two_level_slice(jnp.asarray(w), 2, 2048))
+        np.testing.assert_array_equal(part, node * 2048 + dev)
+        assert (dev >= 0).all() and (dev < 2048).all()
+    assert (np.diff(part) >= 0).all()
 
 
 def test_greedy_bins_balances():

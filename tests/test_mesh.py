@@ -438,6 +438,34 @@ def test_stencil_executor_not_keyed_on_steps():
     assert _st._stencil_fn_presplit.cache_info().misses == 3
 
 
+@pytest.mark.parametrize("driver", ["incremental", "rebuild"])
+def test_simulation_with_pallas_stencil_bit_equal(driver, monkeypatch):
+    """The simulation driver's ``use_pallas`` runs every sweep through
+    the Pallas row update (traced into the executor), and the field
+    stays bit-equal to the single-device reference across AMR events."""
+    from repro.core import partitioner as pt
+    from repro.distributed import sharding as shd
+    from repro.kernels import ops
+    from repro.mesh import simulate
+    from repro.mesh import stencil as _st
+
+    traced = []
+    kernel = ops._su.fused_stencil_update
+    monkeypatch.setattr(ops._su, "fused_stencil_update",
+                        lambda *a, **k: traced.append(1) or kernel(*a, **k))
+    _st._stencil_fn.cache_clear()
+    cfg = simulate.SimConfig(events=6, amr_every=3, substeps=2, base_level=3, max_level=5)
+    events = simulate.build_trajectory(cfg)
+    u0 = simulate.initial_field(events[0].mesh, cfg)
+    ref = simulate.run_reference(events, u0, cfg.substeps)
+    got, st = simulate.run_distributed(
+        events, u0, cfg.substeps, shd.make_node_device_mesh(1, 1), pt.HierarchyPlan(1, 1),
+        driver=driver, cfg=cfg, use_pallas=True)
+    _st._stencil_fn.cache_clear()
+    assert traced and st.amr_events == 1
+    np.testing.assert_array_equal(got, ref)
+
+
 def test_distributed_overlap_variants_bit_equal():
     """8-device mesh: the overlapped executor (jnp and Pallas row
     update) and the pre-split baseline all produce the reference bits

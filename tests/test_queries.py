@@ -47,26 +47,49 @@ def test_point_location_duplicate_heavy(rng):
     np.testing.assert_array_equal(np.asarray(pts)[np.asarray(gid2)], np.asarray(q))
 
 
-def test_pallas_key_search_matches_jnp(rng):
-    """The bucket_search-kernel path (fused key-gen + directory search,
-    full-key run search) must agree with the jnp.searchsorted fallback."""
+@pytest.mark.parametrize("bucket_size", [1, 16, 300])
+def test_locate_bucket_is_last_bucket_at_or_below(bucket_size, rng):
+    """The bucket of a query is the last directory bucket whose first key
+    is <= the query's key, clamped to bucket 0 below the first."""
+    from repro.core import curve_index as ci
+
     pts = jnp.asarray(rng.random((1024, 3)), jnp.float32)
-    idx = queries.build_index(pts, bucket_size=16)
+    idx = queries.build_index(pts, bucket_size=bucket_size)
     q = jnp.concatenate([pts[:64], jnp.asarray(rng.random((64, 3)), jnp.float32)])
-    b_ref = queries.locate_bucket(idx, q, use_pallas=False)
-    b_pal = queries.locate_bucket(idx, q, use_pallas=True)
-    np.testing.assert_array_equal(np.asarray(b_ref), np.asarray(b_pal))
-    r_ref = queries.point_location(idx, q, use_pallas=False)
-    r_pal = queries.point_location(idx, q, use_pallas=True)
-    np.testing.assert_array_equal(np.asarray(r_ref.found), np.asarray(r_pal.found))
-    np.testing.assert_array_equal(np.asarray(r_ref.ids), np.asarray(r_pal.ids))
+    qk = np.asarray(ci.query_keys(idx, q))
+    want = np.searchsorted(np.asarray(idx.bucket_keys), qk, side="right") - 1
+    got = np.asarray(queries.locate_bucket(idx, q))
+    np.testing.assert_array_equal(got, np.clip(want, 0, idx.num_buckets - 1))
+    # a stored point's bucket holds its sorted position
+    starts = np.asarray(idx.bucket_starts)
+    hit = np.asarray(queries.point_location(idx, q[:64]).found)
+    assert hit.all()
+    lo = np.searchsorted(np.asarray(idx.keys)[: starts[-1]], qk[:64], side="left")
+    assert ((starts[got[:64]] <= lo) & (lo < starts[got[:64] + 1])).all()
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_rank_is_searchsorted_over_live_keys(use_pallas, rng):
-    """The directory hop + in-bucket count equals searchsorted over the
-    live keys: key runs spanning several buckets, keys below the first
-    and above the last, key 0, and a sentinel tail of dead slots."""
+def test_point_location_tree_index_large_leaves(rng):
+    """Tree-backed index whose leaves hold hundreds of points: every
+    stored point is found, every point outside the box is a certified
+    miss (the key-run search has no per-bucket window)."""
+    from repro.core import partitioner
+
+    pts = jnp.asarray(rng.random((4096, 3)), jnp.float32)
+    cfg = partitioner.PartitionerConfig(curve="morton", use_tree=True, bucket_size=512)
+    _, idx = partitioner.partition_with_index(pts, None, 4, cfg)
+    assert idx.tree is not None and idx.max_bucket_len >= 256
+    q = jnp.concatenate([pts[::16], jnp.asarray(rng.random((64, 3)) + 2.0, jnp.float32)])
+    found, gid, ok = queries.point_location(idx, q, bucket_cap=idx.max_bucket_len)
+    found, gid, ok = np.asarray(found), np.asarray(gid), np.asarray(ok)
+    assert found[:256].all() and not found[256:].any() and ok.all()
+    np.testing.assert_array_equal(np.asarray(pts)[gid[:256]], np.asarray(q[:256]))
+
+
+@pytest.mark.parametrize("side", ["left", "right"])
+def test_rank_is_searchsorted_over_live_keys(side, rng):
+    """A key run's bounds come from searchsorted over the live keys only:
+    key runs spanning several buckets, keys below the first and above
+    the last, key 0, and a sentinel tail of dead slots."""
     from repro.core import curve_index as ci
 
     n_valid, cap = 600, 700
@@ -85,9 +108,8 @@ def test_rank_is_searchsorted_over_live_keys(use_pallas, rng):
         rng.integers(0, 2**32, 200, dtype=np.uint64).astype(np.uint32),
         np.asarray([0, 1, 2**31 - 1, 2**31, 2**31 + 1, 2**32 - 1], np.uint32),
     ])
-    for strict, side in ((True, "left"), (False, "right")):
-        got = queries._rank(idx, jnp.asarray(qk), strict, use_pallas)
-        np.testing.assert_array_equal(np.asarray(got), np.searchsorted(live, qk, side=side))
+    got = queries._rank(idx, jnp.asarray(qk), side)
+    np.testing.assert_array_equal(np.asarray(got), np.searchsorted(live, qk, side=side))
 
 
 @pytest.mark.parametrize("k", [pytest.param(1, marks=pytest.mark.slow), 3, pytest.param(5, marks=pytest.mark.slow)])

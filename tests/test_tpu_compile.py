@@ -16,11 +16,9 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import bucket_search, hilbert, morton, pair_force, stencil_update
+from repro.kernels import hilbert, morton, pair_force, stencil_update
 
 N_POINTS = 1 << 24      # partition phase: keys of every point
-N_QUERIES = 1 << 20     # query phase
-N_BUCKETS = 1 << 19     # bucket directory of 2^24 points at bucket_size 32
 MESH_ROWS = 1_397_674   # mesh phase: cells after the last refinement
 PARTICLES, K_PAIR = 32768, 112   # particle phase: atoms, table width
 
@@ -31,10 +29,6 @@ CASES = {
                [((N_POINTS, 3), u32)]),
     "hilbert": (lambda c: hilbert.hilbert_from_cells(c, 10, interpret=False),
                 [((N_POINTS, 3), u32)]),
-    "bucket_search": (lambda q, d: bucket_search.bucket_search(q, d, interpret=False),
-                      [((N_QUERIES,), u32), ((N_BUCKETS,), u32)]),
-    "fused_locate": (lambda c, d: bucket_search.fused_locate(c, d, 10, interpret=False),
-                     [((N_QUERIES, 3), u32), ((N_BUCKETS,), u32)]),
     "stencil_update": (
         lambda v, u, n, m, c: stencil_update.fused_stencil_update(v, u, n, m, c, interpret=False),
         [((MESH_ROWS,), f32), ((MESH_ROWS,), f32), ((MESH_ROWS, 8), i32),
