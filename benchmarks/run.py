@@ -6,10 +6,6 @@ artifacts metric by metric (old, new, delta, percent) — the perf
 trajectory check for a PR: run the smoke suite, then compare its fresh
 artifact against the committed one. NEW defaults to ``BENCH_<name>.json``
 in the current directory, with ``<name>`` taken from OLD's payload.
-
-The roofline analysis (deliverable g) is a separate entrypoint —
-``python -m benchmarks.roofline`` — because it needs the 512-fake-device
-environment, which must not leak into these CPU benchmarks.
 """
 from __future__ import annotations
 

@@ -29,6 +29,13 @@ refreshes the frame.
 Every step emits a ``migration.MigrationPlan`` so the application can
 move payloads with the bounded-message exchange. Storage-slot ids are
 the stable element identity across steps.
+
+The engine's phases are host spans named ``repartition.<phase>``
+(``jax.profiler.TraceAnnotation``), so they appear in any profiler trace
+on the device's clock: ``step``, ``timeop``, ``slice``, ``plan``,
+``rebuild``, ``update_weights``, ``insert``, ``delete``, and ``sync``
+around every blocking device->host read, each of which also counts in
+``RepartitionStats.host_syncs`` / ``host_pull_bytes``.
 """
 from __future__ import annotations
 
@@ -41,6 +48,7 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import curve_index as _ci
 from repro.core import dynamic as _dyn
@@ -58,6 +66,18 @@ KEY_SENTINEL = _ci.KEY_SENTINEL  # inactive-slot key: sorts to the tail
 # same-shaped point stores and private counters both starting at 0 would
 # silently read each other's (stale) keys.
 _TOKEN_SOURCE = itertools.count(1)
+_INT32_MAX = 2**31 - 1
+
+
+def _pull_to_host(x: jax.Array, stats: "RepartitionStats | None" = None) -> np.ndarray:
+    """The one blocking device->host read of this module: waits for ``x``
+    inside a ``repartition.sync`` span and counts it in ``stats``."""
+    with TraceAnnotation("repartition.sync"):
+        out = np.asarray(x)
+    if stats is not None:
+        stats.host_syncs += 1
+        stats.host_pull_bytes += out.nbytes
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("num_parts",))
@@ -141,6 +161,12 @@ def _hier_intra_slice_kernel(
     return part, loads, node_loads
 
 
+@jax.jit
+def _add_applied(total, counts):
+    """Running device count of applied summary deltas (no host read)."""
+    return total + jnp.sum(counts)
+
+
 @functools.partial(jax.jit, static_argnames=("num_parts",))
 def _send_counts_kernel(old_part, new_part, num_parts):
     """(P, P) migration count matrix, reduced on device (elements active
@@ -171,15 +197,26 @@ class RepartitionStep:
 
 @dataclass
 class RepartitionStats:
+    """Cumulative counters of one engine, for operators and tests.
+
+    ``host_syncs`` / ``host_pull_bytes`` are the engine's device->host
+    traffic: each blocking read of a device array (``np.asarray``,
+    ``int``/``float`` of a ``jax.Array``) counts once, with the bytes it
+    returned. Each such read is also a ``repartition.sync`` span in any
+    ``jax.profiler`` trace, nested in the ``repartition.<phase>`` span
+    that made it. A plain tree-mode ``step()`` makes 6 (the imbalance
+    fallback's part/weights/active and bucket count, the part loads, the
+    (P, P) migration counts); ``delete`` none, ``insert`` one.
+    """
+
     rebuilds: int = 0
     incremental_steps: int = 0
     # storage slots run through key generation; rebuilds are
     # capacity-shaped (fixed-shape kernels), inserts count the delta batch
     keygen_points: int = 0
-    # tree mode: buckets run through (O(B)) key generation at rebuilds,
-    # and summary entries refreshed by delta scatters between rebuilds
+    # tree mode: buckets run through (O(B)) key generation at rebuilds
+    # (``summary_refreshes`` below counts the delta-scatter refreshes)
     keygen_buckets: int = 0
-    summary_refreshes: int = 0
     # hierarchical engines: how often each re-slice level fired (an
     # intra-node step never moves an element across nodes; an inter-node
     # step re-slices both levels)
@@ -188,7 +225,41 @@ class RepartitionStats:
     # elastic part-count changes (device loss / growth): re-slices of the
     # CACHED curve onto a new part count — never a rebuild
     resizes: int = 0
+    host_syncs: int = 0
+    host_pull_bytes: int = 0
     history: list = field(default_factory=list)
+    # summary refreshes: a host count, plus the deletes' applied entries
+    # summed on the device (int32, at most ``_unread_bound``) until read
+    _refreshes: int = field(default=0, repr=False)
+    _unread: jax.Array | None = field(default=None, repr=False)
+    _unread_bound: int = field(default=0, repr=False)
+
+    @property
+    def summary_refreshes(self) -> int:
+        """Tree mode: summary entries refreshed by insert/delete delta
+        scatters (applied entries only, masked no-ops excluded). Reading
+        it reads the device sum once."""
+        self._fold_refreshes()
+        return self._refreshes
+
+    def _add_refreshes(self, counts: jax.Array | None, k: int) -> None:
+        """Count the refreshes of a ``k``-entry delta: all ``k`` when
+        ``counts`` is None, else the sum of the 0/1 ``counts``, added on
+        the device without a read (one program, whatever came before)."""
+        if counts is None:
+            self._refreshes += k
+            return
+        if self._unread_bound + k > _INT32_MAX:
+            self._fold_refreshes()
+        if self._unread is None:
+            self._unread = jnp.zeros((), jnp.int32)
+        self._unread = _add_applied(self._unread, counts)
+        self._unread_bound += k
+
+    def _fold_refreshes(self) -> None:
+        if self._unread is not None:
+            self._refreshes += int(_pull_to_host(self._unread, self))
+            self._unread, self._unread_bound = None, 0
 
 
 class Repartitioner:
@@ -289,7 +360,12 @@ class Repartitioner:
         return self._cache_token
 
     def num_active(self) -> int:
-        return int(self.dps.active.sum())
+        return int(self._pull(self.dps.active.sum()))
+
+    def _pull(self, x: jax.Array) -> np.ndarray:
+        """Blocking device->host read of ``x``, in a ``repartition.sync``
+        span, counted in ``stats.host_syncs`` / ``stats.host_pull_bytes``."""
+        return _pull_to_host(x, self.stats)
 
     def partition_of(self, slot_ids) -> np.ndarray:
         """Current part id per given storage slot, validated.
@@ -307,7 +383,7 @@ class Repartitioner:
                 f"slot ids out of range [0, {self.capacity}): "
                 f"min={ids.min()}, max={ids.max()}"
             )
-        part = np.asarray(self._part)[ids]
+        part = self._pull(self._part)[ids]
         if (part < 0).any():
             bad = ids[part < 0][:8]
             raise ValueError(f"inactive slots queried: {bad.tolist()}...")
@@ -369,11 +445,11 @@ class Repartitioner:
         rank_eff = jnp.where(act, rank_pp, M + 1)
         order = jnp.argsort(rank_eff, stable=True).astype(jnp.int32)
         keys_sorted = jnp.where(act, key_pp, jnp.uint32(KEY_SENTINEL))[order]
-        nb = max(1, int(border.num_buckets))
+        nb = max(1, int(self._pull(border.num_buckets)))
         cnt_leaf = jax.ops.segment_sum(
             act.astype(jnp.int32), self.dps.leaf_id, num_segments=M
         )
-        cnt_rank = np.asarray(cnt_leaf[border.order])
+        cnt_rank = self._pull(cnt_leaf[border.order])
         starts = np.zeros((nb + 1,), np.int64)
         starts[1:] = np.cumsum(cnt_rank[:nb])
         starts[nb] = self.num_active()  # widen the tail bucket (see above)
@@ -396,8 +472,8 @@ class Repartitioner:
     # -- key generation against the frozen frame ----------------------------
 
     def _freeze_frame(self) -> None:
-        pts = np.asarray(self.dps.points)
-        act = np.asarray(self.dps.active)
+        pts = self._pull(self.dps.points)
+        act = self._pull(self.dps.active)
         live = pts[act] if act.any() else np.zeros((1, pts.shape[1]), np.float32)
         lo, hi = live.min(axis=0), live.max(axis=0)
         span = np.where(hi > lo, hi - lo, 1.0)
@@ -451,84 +527,87 @@ class Repartitioner:
     def update_weights(self, weights: jax.Array, slot_ids: jax.Array | None = None) -> None:
         """Replace weights (full (C,)/(n_active,) vector, or a sparse batch
         at ``slot_ids``). Weight changes never invalidate cached keys."""
-        if slot_ids is not None:
-            new_w = self.dps.weights.at[jnp.asarray(slot_ids)].set(weights)
-        else:
-            weights = jnp.asarray(weights, jnp.float32)
-            k = weights.shape[0]
-            if k == self.capacity:
-                new_w = weights
-            elif k == self.num_active():  # aligned with active slots in slot order
-                act_slots = jnp.nonzero(self.dps.active, size=k)[0]
-                new_w = self.dps.weights.at[act_slots].set(weights)
+        with TraceAnnotation("repartition.update_weights"):
+            if slot_ids is not None:
+                new_w = self.dps.weights.at[jnp.asarray(slot_ids)].set(weights)
             else:
-                # any other length would silently scatter the tail into
-                # slot 0 (fixed-shape nonzero pads with 0)
-                raise ValueError(
-                    f"weights length {k} matches neither capacity "
-                    f"({self.capacity}) nor active count ({self.num_active()})"
+                weights = jnp.asarray(weights, jnp.float32)
+                k = weights.shape[0]
+                if k == self.capacity:
+                    new_w = weights
+                elif k == self.num_active():  # aligned with active slots in slot order
+                    act_slots = jnp.nonzero(self.dps.active, size=k)[0]
+                    new_w = self.dps.weights.at[act_slots].set(weights)
+                else:
+                    # any other length would silently scatter the tail into
+                    # slot 0 (fixed-shape nonzero pads with 0)
+                    raise ValueError(
+                        f"weights length {k} matches neither capacity "
+                        f"({self.capacity}) nor active count ({self.num_active()})"
+                    )
+            self.dps = self.dps._replace(weights=new_w)
+            if self.tree_mode:
+                # keep the exposed summary truthful under weight drift: one
+                # segment_sum re-aggregates live weights onto the buckets
+                # (count/centroid/bbox/keys are untouched — weight drift
+                # moves nothing on the curve)
+                w_leaf = jax.ops.segment_sum(
+                    jnp.where(self.dps.active, new_w, 0.0),
+                    self.dps.leaf_id,
+                    num_segments=self._summary.num_nodes,
                 )
-        self.dps = self.dps._replace(weights=new_w)
-        if self.tree_mode:
-            # keep the exposed summary truthful under weight drift: one
-            # segment_sum re-aggregates live weights onto the buckets
-            # (count/centroid/bbox/keys are untouched — weight drift
-            # moves nothing on the curve)
-            w_leaf = jax.ops.segment_sum(
-                jnp.where(self.dps.active, new_w, 0.0),
-                self.dps.leaf_id,
-                num_segments=self._summary.num_nodes,
-            )
-            self._summary = dataclasses.replace(self._summary, weight=w_leaf)
+                self._summary = dataclasses.replace(self._summary, weight=w_leaf)
 
     def insert(self, points: jax.Array, weights: jax.Array) -> jax.Array:
         """Insert a point batch; returns their storage slot ids. Keys are
         generated for the delta batch only (frozen frame); the cached
         curve order is re-sorted but not re-keyed."""
-        k = points.shape[0]
-        n_free = self.capacity - self.num_active()
-        if k > n_free:
-            # without this check the overflow scatters into one slot and
-            # silently drops points (fixed-shape nonzero fill semantics)
-            raise ValueError(
-                f"insert of {k} points exceeds free capacity {n_free}; "
-                f"grow the Repartitioner (capacity={self.capacity})"
-            )
-        free = jnp.nonzero(~self.dps.active, size=k, fill_value=self.capacity - 1)[0]
-        self.dps = _dyn.insert(self.dps, points, weights)
-        if self.tree_mode:
-            # bucket substrate: the located leaves are the only dirtied
-            # summaries — refresh them by delta scatter; no key-gen, no
-            # resort (there is no per-point key array to maintain)
-            self._summary_apply_delta(
-                points, jnp.asarray(weights, jnp.float32),
-                self.dps.leaf_id[free], sign=+1,
-            )
-            self._index_version += 1
-        else:
-            self._keys = self._keys.at[free].set(self._keys_in_frame(points))
-            self._resort()
-        self.topology_version += 1
-        return free
+        with TraceAnnotation("repartition.insert"):
+            k = points.shape[0]
+            n_free = self.capacity - self.num_active()
+            if k > n_free:
+                # without this check the overflow scatters into one slot and
+                # silently drops points (fixed-shape nonzero fill semantics)
+                raise ValueError(
+                    f"insert of {k} points exceeds free capacity {n_free}; "
+                    f"grow the Repartitioner (capacity={self.capacity})"
+                )
+            free = jnp.nonzero(~self.dps.active, size=k, fill_value=self.capacity - 1)[0]
+            self.dps = _dyn.insert(self.dps, points, weights)
+            if self.tree_mode:
+                # bucket substrate: the located leaves are the only dirtied
+                # summaries — refresh them by delta scatter; no key-gen, no
+                # resort (there is no per-point key array to maintain)
+                self._summary_apply_delta(
+                    points, jnp.asarray(weights, jnp.float32),
+                    self.dps.leaf_id[free], sign=+1,
+                )
+                self._index_version += 1
+            else:
+                self._keys = self._keys.at[free].set(self._keys_in_frame(points))
+                self._resort()
+            self.topology_version += 1
+            return free
 
     def delete(self, slot_ids: jax.Array) -> None:
-        slot_ids = jnp.asarray(slot_ids)
-        # first-occurrence live slots only — the exact mask dynamic.delete
-        # applies, so summary deltas track tree counters; computed once
-        # and handed down
-        removed = self.dps.active[slot_ids] & _dyn.first_occurrence_mask(slot_ids)
-        self.dps = _dyn.delete(self.dps, slot_ids, removed=removed)
-        if self.tree_mode:
-            w = jnp.where(removed, self.dps.weights[slot_ids], 0.0)
-            self._summary_apply_delta(
-                self.dps.points[slot_ids], w, self.dps.leaf_id[slot_ids],
-                sign=-1, counts=removed.astype(jnp.int32),
-            )
-            self._index_version += 1
-        else:
-            self._keys = self._keys.at[slot_ids].set(jnp.uint32(KEY_SENTINEL))
-            self._resort()
-        self.topology_version += 1
+        with TraceAnnotation("repartition.delete"):
+            slot_ids = jnp.asarray(slot_ids)
+            # first-occurrence live slots only — the exact mask dynamic.delete
+            # applies, so summary deltas track tree counters; computed once
+            # and handed down
+            removed = self.dps.active[slot_ids] & _dyn.first_occurrence_mask(slot_ids)
+            self.dps = _dyn.delete(self.dps, slot_ids, removed=removed)
+            if self.tree_mode:
+                w = jnp.where(removed, self.dps.weights[slot_ids], 0.0)
+                self._summary_apply_delta(
+                    self.dps.points[slot_ids], w, self.dps.leaf_id[slot_ids],
+                    sign=-1, counts=removed.astype(jnp.int32),
+                )
+                self._index_version += 1
+            else:
+                self._keys = self._keys.at[slot_ids].set(jnp.uint32(KEY_SENTINEL))
+                self._resort()
+            self.topology_version += 1
 
     # -- tree-mode bucket statistics -----------------------------------------
 
@@ -551,7 +630,7 @@ class Repartitioner:
             bits=self.bits,
             curve=self.cfg.curve,
         )
-        self.stats.keygen_buckets += int(self._border.num_buckets)
+        self.stats.keygen_buckets += int(self._pull(self._border.num_buckets))
 
     def _summary_apply_delta(
         self,
@@ -591,8 +670,9 @@ class Repartitioner:
             is_bucket=self.dps.tree.is_leaf & (cnt > 0),
         )
         # count entries actually applied (masked no-ops excluded), so the
-        # counter reflects dirtied work, not batch size
-        self.stats.summary_refreshes += int(jnp.sum(jnp.abs(ones)))
+        # counter reflects dirtied work, not batch size; an insert applies
+        # its whole batch, a delete's mask is summed on the device unread
+        self.stats._add_refreshes(counts, int(leaf_ids.shape[0]))
 
     def summary(self) -> "_kdtree.BucketSummary":
         """Tree mode: the live per-bucket statistics."""
@@ -623,7 +703,7 @@ class Repartitioner:
             part, loads_d = _slice_kernel(
                 self._order, self.dps.active, self.dps.weights, self.num_parts
             )
-        loads = np.asarray(loads_d)
+        loads = self._pull(loads_d)
         mean = max(float(loads.mean()), 1e-12)
         return part, loads, float(loads.max()) / mean
 
@@ -636,7 +716,7 @@ class Repartitioner:
               **extra) -> RepartitionStep:
         # stable elements only (active in both assignments) migrate
         counts = _send_counts_kernel(self._part, part, self.num_parts)
-        plan = self._make_plan(np.asarray(counts))
+        plan = self._make_plan(self._pull(counts))
         self._part = part
         self.stats.history.append((kind, float(imbalance), int(plan.total_moved)))
         return RepartitionStep(
@@ -649,33 +729,37 @@ class Repartitioner:
     def rebalance(self) -> RepartitionStep:
         """Force an incremental re-slice of the cached curve (no key-gen,
         no tree adjustment)."""
-        part, loads, imb = self._slice_current()
+        with TraceAnnotation("repartition.slice"):
+            part, loads, imb = self._slice_current()
         self.stats.incremental_steps += 1
-        return self._emit("incremental", part, loads, imb, reused=True)
+        with TraceAnnotation("repartition.plan"):
+            return self._emit("incremental", part, loads, imb, reused=True)
 
     def rebuild(self) -> RepartitionStep:
         """Force a full rebuild: tree adjustments, fresh frame, fresh keys
         (bucket keys in tree mode — O(B), never the points)."""
-        if self.stats.rebuilds or self.stats.incremental_steps:
-            # skip Alg. 1 on the pristine initial build
-            self.dps = _dyn.adjustments(self.dps)
-        self._freeze_frame()
-        self._invalidate_keys()
-        if self.tree_mode:
-            self._refresh_bucket_stats()
-            self._index_version += 1
-        else:
-            act = self.dps.active
-            keys = self._keys_in_frame(self.dps.points, cache=True)
-            self._keys = jnp.where(act, keys, jnp.uint32(KEY_SENTINEL))
-            self._resort()
-        part, loads, imb = self._slice_current()
-        self.stats.rebuilds += 1
-        cost = self._rebuild_cost if self._rebuild_cost is not None else float(self.num_active())
-        self.controller.balanced(
-            lb_cost=cost, num_buckets=int(_dyn.num_buckets(self.dps)), timeop=imb
-        )
-        return self._emit("rebuild", part, loads, imb, reused=False)
+        with TraceAnnotation("repartition.rebuild"):
+            if self.stats.rebuilds or self.stats.incremental_steps:
+                # skip Alg. 1 on the pristine initial build
+                self.dps = _dyn.adjustments(self.dps)
+            self._freeze_frame()
+            self._invalidate_keys()
+            if self.tree_mode:
+                self._refresh_bucket_stats()
+                self._index_version += 1
+            else:
+                act = self.dps.active
+                keys = self._keys_in_frame(self.dps.points, cache=True)
+                self._keys = jnp.where(act, keys, jnp.uint32(KEY_SENTINEL))
+                self._resort()
+            with TraceAnnotation("repartition.slice"):
+                part, loads, imb = self._slice_current()
+            self.stats.rebuilds += 1
+            cost = self._rebuild_cost if self._rebuild_cost is not None else float(self.num_active())
+            num_buckets = int(self._pull(_dyn.num_buckets(self.dps)))
+            self.controller.balanced(lb_cost=cost, num_buckets=num_buckets, timeop=imb)
+            with TraceAnnotation("repartition.plan"):
+                return self._emit("rebuild", part, loads, imb, reused=False)
 
     def resize(self, num_parts: int) -> RepartitionStep:
         """Elastic part-count change (device loss / growth): re-slice the
@@ -690,15 +774,17 @@ class Repartitioner:
         the same curve re-carved, never a cold rebuild)."""
         old_part, old_parts_n = self._part, self.num_parts
         self.num_parts = int(num_parts)
-        part, loads, imb = self._slice_current()
-        union = max(old_parts_n, self.num_parts)
-        counts = np.asarray(_send_counts_kernel(old_part, part, union))
-        plan = _migration.plan_from_counts(counts)
-        self._part = part
-        self._index_version += 1
-        self.stats.incremental_steps += 1
-        self.stats.resizes += 1
-        self.stats.history.append(("resize", float(imb), int(plan.total_moved)))
+        with TraceAnnotation("repartition.slice"):
+            part, loads, imb = self._slice_current()
+        with TraceAnnotation("repartition.plan"):
+            union = max(old_parts_n, self.num_parts)
+            counts = self._pull(_send_counts_kernel(old_part, part, union))
+            plan = _migration.plan_from_counts(counts)
+            self._part = part
+            self._index_version += 1
+            self.stats.incremental_steps += 1
+            self.stats.resizes += 1
+            self.stats.history.append(("resize", float(imb), int(plan.total_moved)))
         return RepartitionStep(
             kind="incremental", part=part, plan=plan, loads=loads,
             imbalance=imb, reused_keys=True,
@@ -713,14 +799,17 @@ class Repartitioner:
         under the *new* weights stands in for it — a hot part means slow
         ops, which is exactly the drift the credit scheme meters.
         """
-        if timeop is None:
-            loads = np.zeros(self.num_parts, np.float64)
-            part = np.asarray(self._part)
-            w = np.asarray(self.dps.weights) * np.asarray(self.dps.active)
-            np.add.at(loads, np.maximum(part, 0), np.where(part >= 0, w, 0.0))
-            timeop = float(loads.max() / max(loads.mean(), 1e-12))
-        fire = self.controller.observe(timeop, int(_dyn.num_buckets(self.dps)))
-        return self.rebuild() if fire else self.rebalance()
+        with TraceAnnotation("repartition.step"):
+            with TraceAnnotation("repartition.timeop"):
+                if timeop is None:
+                    loads = np.zeros(self.num_parts, np.float64)
+                    part = self._pull(self._part)
+                    w = self._pull(self.dps.weights) * self._pull(self.dps.active)
+                    np.add.at(loads, np.maximum(part, 0), np.where(part >= 0, w, 0.0))
+                    timeop = float(loads.max() / max(loads.mean(), 1e-12))
+                num_buckets = int(self._pull(_dyn.num_buckets(self.dps)))
+            fire = self.controller.observe(timeop, num_buckets)
+            return self.rebuild() if fire else self.rebalance()
 
 
 class HierarchicalRepartitioner(Repartitioner):
@@ -791,8 +880,8 @@ class HierarchicalRepartitioner(Repartitioner):
         # scatters) already hold the active point mass per bucket —
         # aggregating them through the frozen bucket->node map costs two
         # (M,) transfers, never a point-length one
-        w_leaf = np.asarray(self._summary.weight)
-        node_b = np.asarray(self._bucket_node)
+        w_leaf = self._pull(self._summary.weight)
+        node_b = self._pull(self._bucket_node)
         loads = np.zeros(self.plan.num_nodes)
         np.add.at(loads, node_b, w_leaf)
         return float(loads.max() / max(loads.mean(), 1e-12)), loads
@@ -807,8 +896,8 @@ class HierarchicalRepartitioner(Repartitioner):
             self._border.order, self.plan.num_nodes, self.plan.devices_per_node,
         )
         self._bucket_node = bucket_node
-        self._node_loads = np.asarray(node_loads_d)
-        loads = np.asarray(loads_d)
+        self._node_loads = self._pull(node_loads_d)
+        loads = self._pull(loads_d)
         return part, loads, float(loads.max()) / max(float(loads.mean()), 1e-12)
 
     def _slice_intra(self) -> tuple[jax.Array, np.ndarray, float]:
@@ -817,8 +906,8 @@ class HierarchicalRepartitioner(Repartitioner):
             self._border.order, self._bucket_node,
             self.plan.num_nodes, self.plan.devices_per_node,
         )
-        self._node_loads = np.asarray(node_loads_d)
-        loads = np.asarray(loads_d)
+        self._node_loads = self._pull(node_loads_d)
+        loads = self._pull(loads_d)
         return part, loads, float(loads.max()) / max(float(loads.mean()), 1e-12)
 
     def _make_plan(self, counts: np.ndarray) -> _migration.MigrationPlan:
@@ -850,18 +939,20 @@ class HierarchicalRepartitioner(Repartitioner):
         old_part, old_parts_n = self._part, self.num_parts
         self.plan = plan
         self.num_parts = int(plan.num_parts)
-        part, loads, imb = self._slice_current()   # refreshes _bucket_node
-        union = max(old_parts_n, self.num_parts)
-        counts = np.asarray(_send_counts_kernel(old_part, part, union))
-        mplan = _migration.plan_from_counts(
-            counts, hierarchy=plan if union == self.num_parts else None
-        )
-        self._part = part
-        self._index_version += 1
-        self.stats.incremental_steps += 1
-        self.stats.inter_reslices += 1
-        self.stats.resizes += 1
-        self.stats.history.append(("resize", float(imb), int(mplan.total_moved)))
+        with TraceAnnotation("repartition.slice"):
+            part, loads, imb = self._slice_current()   # refreshes _bucket_node
+        with TraceAnnotation("repartition.plan"):
+            union = max(old_parts_n, self.num_parts)
+            counts = self._pull(_send_counts_kernel(old_part, part, union))
+            mplan = _migration.plan_from_counts(
+                counts, hierarchy=plan if union == self.num_parts else None
+            )
+            self._part = part
+            self._index_version += 1
+            self.stats.incremental_steps += 1
+            self.stats.inter_reslices += 1
+            self.stats.resizes += 1
+            self.stats.history.append(("resize", float(imb), int(mplan.total_moved)))
         nl = self._node_loads
         return RepartitionStep(
             kind="incremental", part=part, plan=mplan, loads=loads,
@@ -873,21 +964,23 @@ class HierarchicalRepartitioner(Repartitioner):
     def rebalance(self, level: str | None = None) -> RepartitionStep:
         """Incremental re-slice; ``level`` forces "intra"/"inter", default
         consults the node-level trigger."""
-        if level is None:
-            nimb, _ = self._node_state()
-            level = "inter" if nimb > self.node_threshold else "intra"
-        if level == "inter":
-            part, loads, imb = self._slice_current()
-            self.stats.inter_reslices += 1
-        elif level == "intra":
-            part, loads, imb = self._slice_intra()
-            self.stats.intra_reslices += 1
-        else:
-            raise ValueError(f"unknown re-slice level {level!r}")
+        with TraceAnnotation("repartition.slice"):
+            if level is None:
+                nimb, _ = self._node_state()
+                level = "inter" if nimb > self.node_threshold else "intra"
+            if level == "inter":
+                part, loads, imb = self._slice_current()
+                self.stats.inter_reslices += 1
+            elif level == "intra":
+                part, loads, imb = self._slice_intra()
+                self.stats.intra_reslices += 1
+            else:
+                raise ValueError(f"unknown re-slice level {level!r}")
         self.stats.incremental_steps += 1
-        return self._emit(
-            "incremental", part, loads, imb, reused=True, level=level,
-        )
+        with TraceAnnotation("repartition.plan"):
+            return self._emit(
+                "incremental", part, loads, imb, reused=True, level=level,
+            )
 
 
 # ---------------------------------------------------------------------------
@@ -953,9 +1046,9 @@ class DistributedRepartitioner:
     def migration_between(self, old_part: jax.Array, new_part: jax.Array) -> _migration.MigrationPlan:
         """Bounded-message exchange plan between two sorted-layout
         assignments (invalid slots excluded)."""
-        valid = np.asarray(self.valid)
+        valid = _pull_to_host(self.valid)
         return _migration.migration_plan(
-            np.asarray(old_part)[valid], np.asarray(new_part)[valid], self.num_parts
+            _pull_to_host(old_part)[valid], _pull_to_host(new_part)[valid], self.num_parts
         )
 
 
@@ -1035,6 +1128,6 @@ class DistributedBucketRepartitioner:
         """Exchange plan between two original-layout assignments —
         level-aware when the engine's hierarchy is non-trivial."""
         return _migration.migration_plan(
-            np.asarray(old_part), np.asarray(new_part), self.num_parts,
+            _pull_to_host(old_part), _pull_to_host(new_part), self.num_parts,
             hierarchy=self.plan if self.plan.num_nodes > 1 else None,
         )
