@@ -69,14 +69,15 @@ _TOKEN_SOURCE = itertools.count(1)
 _INT32_MAX = 2**31 - 1
 
 
-def _pull_to_host(x: jax.Array, stats: "RepartitionStats | None" = None) -> np.ndarray:
+def _pull_to_host(x, stats: "RepartitionStats | None" = None):
     """The one blocking device->host read of this module: waits for ``x``
-    inside a ``repartition.sync`` span and counts it in ``stats``."""
+    (an array, or a tuple of arrays read together) inside a
+    ``repartition.sync`` span and counts it once in ``stats``."""
     with TraceAnnotation("repartition.sync"):
-        out = np.asarray(x)
+        out = jax.device_get(x)
     if stats is not None:
         stats.host_syncs += 1
-        stats.host_pull_bytes += out.nbytes
+        stats.host_pull_bytes += sum(a.nbytes for a in jax.tree.leaves(out))
     return out
 
 
@@ -161,6 +162,19 @@ def _hier_intra_slice_kernel(
     return part, loads, node_loads
 
 
+@functools.partial(jax.jit, static_argnames=("num_parts",))
+def _live_loads_kernel(part, dps, num_parts):
+    """The imbalance fallback's input on the device: the (P,) float32 load
+    of assignment ``part`` under the store's live weights, slot by slot
+    (slots with part < 0 left out, inactive slots charged 0), and the
+    non-empty bucket count. A compare-and-reduce over the P parts: no
+    scatter, no (C, P) intermediate (XLA fuses it into the reduction)."""
+    w = jnp.where(dps.active, dps.weights, 0.0)
+    hit = part[:, None] == jnp.arange(num_parts, dtype=part.dtype)
+    loads = jnp.sum(jnp.where(hit, w[:, None], 0.0), axis=0)
+    return loads, _dyn.num_buckets(dps)
+
+
 @jax.jit
 def _add_applied(total, counts):
     """Running device count of applied summary deltas (no host read)."""
@@ -200,12 +214,12 @@ class RepartitionStats:
     """Cumulative counters of one engine, for operators and tests.
 
     ``host_syncs`` / ``host_pull_bytes`` are the engine's device->host
-    traffic: each blocking read of a device array (``np.asarray``,
-    ``int``/``float`` of a ``jax.Array``) counts once, with the bytes it
+    traffic: each blocking read of device arrays (``jax.device_get`` of
+    an array or of a tuple read together) counts once, with the bytes it
     returned. Each such read is also a ``repartition.sync`` span in any
     ``jax.profiler`` trace, nested in the ``repartition.<phase>`` span
-    that made it. A plain tree-mode ``step()`` makes 6 (the imbalance
-    fallback's part/weights/active and bucket count, the part loads, the
+    that made it. A plain tree-mode ``step()`` makes 3 (the imbalance
+    fallback's (P,) loads with the bucket count, the part loads, the
     (P, P) migration counts); ``delete`` none, ``insert`` one.
     """
 
@@ -362,9 +376,10 @@ class Repartitioner:
     def num_active(self) -> int:
         return int(self._pull(self.dps.active.sum()))
 
-    def _pull(self, x: jax.Array) -> np.ndarray:
-        """Blocking device->host read of ``x``, in a ``repartition.sync``
-        span, counted in ``stats.host_syncs`` / ``stats.host_pull_bytes``."""
+    def _pull(self, x):
+        """Blocking device->host read of ``x`` (an array or a tuple of
+        them, read together), in a ``repartition.sync`` span, counted once
+        in ``stats.host_syncs`` / ``stats.host_pull_bytes``."""
         return _pull_to_host(x, self.stats)
 
     def partition_of(self, slot_ids) -> np.ndarray:
@@ -802,13 +817,12 @@ class Repartitioner:
         with TraceAnnotation("repartition.step"):
             with TraceAnnotation("repartition.timeop"):
                 if timeop is None:
-                    loads = np.zeros(self.num_parts, np.float64)
-                    part = self._pull(self._part)
-                    w = self._pull(self.dps.weights) * self._pull(self.dps.active)
-                    np.add.at(loads, np.maximum(part, 0), np.where(part >= 0, w, 0.0))
-                    timeop = float(loads.max() / max(loads.mean(), 1e-12))
-                num_buckets = int(self._pull(_dyn.num_buckets(self.dps)))
-            fire = self.controller.observe(timeop, num_buckets)
+                    loads, num_buckets = self._pull(
+                        _live_loads_kernel(self._part, self.dps, self.num_parts))
+                    timeop = float(loads.max()) / max(float(loads.mean()), 1e-12)
+                else:
+                    num_buckets = self._pull(_dyn.num_buckets(self.dps))
+            fire = self.controller.observe(timeop, int(num_buckets))
             return self.rebuild() if fire else self.rebalance()
 
 

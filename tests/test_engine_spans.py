@@ -106,7 +106,7 @@ def test_each_step_holds_one_of_each_phase_and_its_syncs(traced):
             assert [sp[0] for sp in inner].count(phase) == 1, phase
         phases = [sp for sp in inner if sp[0] in PHASES]
         syncs = [sp for sp in inner if sp[0] == "repartition.sync"]
-        assert len(syncs) == 6
+        assert len(syncs) == 3
         assert all(any(_inside(s, p) for p in phases) for s in syncs)
     for churn in ("repartition.delete", "repartition.insert"):
         [op] = [sp for sp in spans if sp[0] == churn]
@@ -115,8 +115,8 @@ def test_each_step_holds_one_of_each_phase_and_its_syncs(traced):
     assert sum(1 for sp in spans if sp[0] == "repartition.sync" and _inside(sp, insert)) == 1
 
 
-def test_step_syncs_reads_six(traced):
-    assert _load(BENCH / "metrics" / "drift.step_syncs.py").read(traced) == 6.0
+def test_step_syncs_reads_three(traced):
+    assert _load(BENCH / "metrics" / "drift.step_syncs.py").read(traced) == 3.0
 
 
 @pytest.mark.parametrize("name", READERS)
@@ -134,9 +134,10 @@ def test_reader_is_none_without_engine_spans(name):
 
 
 def test_plain_step_syncs_and_bytes(traced):
-    # part, weights, active, the bucket count, loads, the (P, P) counts
-    want = 9 * N + 4 * PARTS + 4 * PARTS**2 + 4
-    assert traced.plain == [(6, want)] * PLAIN_STEPS
+    # the fallback's (P,) loads with the bucket count, the slice's loads,
+    # the (P, P) counts: nothing that grows with the point count
+    want = 8 * PARTS + 4 * PARTS**2 + 4
+    assert traced.plain == [(3, want)] * PLAIN_STEPS
 
 
 def test_churn_syncs_once_and_counts_refreshes_when_read():

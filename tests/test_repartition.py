@@ -1,9 +1,10 @@
 """Incremental repartitioning engine (repro.core.repartition)."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import dynamic
-from repro.core.repartition import Repartitioner
+from repro.core.repartition import Repartitioner, _live_loads_kernel
 
 
 def _mk(rng, n=1024, parts=8, **kw):
@@ -122,6 +123,119 @@ def test_step_default_timeop_uses_live_imbalance(rng):
         rp.update_weights(jnp.asarray(1.0 + hot))
         kinds.append(rp.step().kind)
     assert "rebuild" in kinds
+
+
+# --- imbalance fallback on the device -----------------------------------------
+
+def _host_loads(rp) -> np.ndarray:
+    """The fallback's former host formula, kept as a float64 oracle: the
+    current assignment's loads under the live weights, slot by slot."""
+    part = np.asarray(rp.part)
+    w = np.asarray(rp.dps.weights, np.float64) * np.asarray(rp.dps.active)
+    loads = np.zeros(rp.num_parts)
+    np.add.at(loads, np.maximum(part, 0), np.where(part >= 0, w, 0.0))
+    return loads
+
+
+def _host_imbalance(rp) -> float:
+    loads = _host_loads(rp)
+    return float(loads.max() / max(loads.mean(), 1e-12))
+
+
+def _fallback_case(case: str, rng):
+    from repro.core import partitioner as pt
+    from repro.core.repartition import HierarchicalRepartitioner
+
+    n = 2048
+    pts = jnp.asarray(rng.random((n, 3)), jnp.float32)
+    w = jnp.asarray(0.5 + rng.random(n), jnp.float32)
+    drift = jnp.asarray(1.0 + 3.0 * (rng.random(n) < 0.2), jnp.float32)
+    tree = pt.PartitionerConfig(use_tree=True)
+    if case == "hierarchical":
+        rp = HierarchicalRepartitioner(pts, w, pt.HierarchyPlan(2, 4), max_depth=8)
+    else:
+        rp = Repartitioner(pts, w, 8, tree if case.startswith("tree") else pt.PartitionerConfig(),
+                           max_depth=8, capacity=n if case == "tree_full_churn" else None)
+    rp.update_weights(w * drift)
+
+    def insert(k):
+        rp.insert(jnp.asarray(rng.random((k, 3)), jnp.float32),
+                  jnp.asarray(2.0 + rng.random(k), jnp.float32))
+
+    def delete(k):
+        rp.delete(jnp.asarray(rng.choice(n, k, replace=False).astype(np.int32)))
+
+    if case in ("key", "hierarchical"):
+        insert(400)   # into free slots: part -1, left out
+    if case in ("tree_delete", "key", "hierarchical"):
+        delete(300)   # inactive, their part still >= 0: charged 0
+    if case == "tree_full_churn":
+        # a full store refills the slots just freed: their stale part is
+        # charged the new point's weight
+        delete(128)
+        insert(128)
+    return rp
+
+
+@pytest.mark.parametrize(
+    "case", ["tree_weights", "tree_delete", "tree_full_churn", "key", "hierarchical"])
+def test_step_fallback_matches_host_formula(case):
+    """``step()`` without a timeop reads the loads of the current
+    assignment under the new weights from one device program: the same
+    numbers, slot by slot, as the float64 host formula it replaced."""
+    rp = _fallback_case(case, np.random.default_rng(11))
+    part, act = _active_parts(rp)
+    deleted, fresh = ((part >= 0) & ~act).any(), ((part < 0) & act).any()
+    assert (deleted, fresh) == {"tree_weights": (False, False), "tree_delete": (True, False),
+                                "tree_full_churn": (False, False)}.get(case, (True, True))
+    want = _host_loads(rp)
+    loads, nb = _live_loads_kernel(rp.part, rp.dps, rp.num_parts)
+    assert loads.dtype == jnp.float32 and loads.shape == (rp.num_parts,)
+    np.testing.assert_allclose(np.asarray(loads), want, rtol=1e-5)
+    assert int(nb) == int(dynamic.num_buckets(rp.dps))
+    seen = len(rp.controller.history)
+    rp.step()
+    tag, cost, *_ = rp.controller.history[seen]
+    assert tag in ("base", "obs")
+    np.testing.assert_allclose(cost / int(nb), want.max() / want.mean(), rtol=1e-5)
+
+
+def test_scripted_drift_takes_the_host_formulas_decisions():
+    """A drift run with churn on a full store: the engine that computes
+    the fallback on the device takes, step for step, the decisions of a
+    twin fed the float64 host formula as its timeop."""
+    from repro.core import partitioner as pt
+
+    n, k = 4096, 256
+    rng = np.random.default_rng(5)
+    pts = rng.random((n, 3)).astype(np.float32)
+    w0 = (0.5 + rng.random(n)).astype(np.float32)
+    cfg = pt.PartitionerConfig(use_tree=True)
+    twins = [Repartitioner(jnp.asarray(pts), jnp.asarray(w0), 8, cfg, capacity=n,
+                           max_depth=8, rebuild_cost=40.0) for _ in range(2)]
+    kinds = ([], [])
+    for t in range(16):
+        if t % 4 == 3:
+            slots = np.sort(rng.choice(n, k, replace=False)).astype(np.int32)
+            pts[slots] = rng.random((k, 3))
+            w0[slots] = 0.5 + rng.random(k)
+            for rp in twins:
+                rp.delete(jnp.asarray(slots))
+                rp.insert(jnp.asarray(pts[slots]), jnp.asarray(w0[slots]))
+        centre = np.array([0.2 + 0.04 * t, 0.5, 0.5], np.float32)
+        hot = w0 * (1.0 + 3.0 * np.exp(-((pts - centre) ** 2).sum(1) / 0.02))
+        for rp in twins:
+            rp.update_weights(jnp.asarray(hot, jnp.float32))
+        kinds[0].append(twins[0].step().kind)
+        kinds[1].append(twins[1].step(timeop=_host_imbalance(twins[1])).kind)
+    assert kinds[0] == kinds[1]
+    assert "rebuild" in kinds[0] and "incremental" in kinds[0], kinds[0]
+    device, host = (rp.controller.history for rp in twins)
+    assert [h[0] for h in device] == [h[0] for h in host]
+    np.testing.assert_allclose([h[1:] for h in device if h[0] != "obs"],
+                               [h[1:] for h in host if h[0] != "obs"], rtol=1e-5)
+    np.testing.assert_allclose([h[1:] for h in device if h[0] == "obs"],
+                               [h[1:] for h in host if h[0] == "obs"], rtol=1e-5, atol=1e-3)
 
 
 # --- migration plans ----------------------------------------------------------
