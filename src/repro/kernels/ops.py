@@ -132,15 +132,15 @@ def stencil_update(
 ) -> jax.Array:
     """Fused stencil row update (gather + mask + coeff*(v-u) + K-reduce).
 
-    The mesh stencil executors' inner loop. ``use_pallas`` dispatches the
+    The mesh stencil executors' inner loop; ``u_rows`` is (R,) for one
+    field or (R, V) for V fields per cell. ``use_pallas`` dispatches the
     Pallas kernel; the default jnp path is bit-equal by construction —
     both evaluate `kernels.stencil_update.stencil_update_ref`'s
     expression.
     """
     if use_pallas:
-        return _su.fused_stencil_update(
-            vals_all, u_rows, nbr, valid, coeff, interpret=interpret()
-        )
+        fused = _su.fused_stencil_update_v if u_rows.ndim == 2 else _su.fused_stencil_update
+        return fused(vals_all, u_rows, nbr, valid, coeff, interpret=interpret())
     return _su.stencil_update_ref(vals_all, u_rows, nbr, valid, coeff)
 
 

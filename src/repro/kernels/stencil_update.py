@@ -21,17 +21,44 @@ ordinary float arithmetic XLA must not reassociate, so every caller —
 reference executor, pre-split baseline, overlapped executor, Pallas
 kernel — produces identical bits by construction. The distributed
 stencil gates on this (``np.array_equal`` against the single-device
-reference across repartition events).
+reference across repartition events). The construction is the form of
+the chain, not its algebra: XLA's CPU backend contracts a multiply and
+the add after it into one fused multiply-add in some fusions and not
+in others, so a rewrite that is exact in real arithmetic (summing only
+a row's valid slots, say) can differ in the last bit.
+
+V-wide form (V fields per cell, rows (R, V)): the same expression per
+field. :func:`fused_stencil_update_v` works through the rows in blocks
+of ``GATHER_ROWS``, so the gathered neighbour rows never exist for all
+rows at once. A cell's fields sit on the lanes, padded to a whole 128
+(XLA:TPU gathers 128-lane rows about 6x faster than 40-lane ones), and
+the rows on sublanes: each neighbour slot's gathered (block, 128) rows
+go to the kernel as they are, with no transpose. Most rows of a mesh's
+table have one valid slot a face; the kernel gathers only that slot's
+values for them and keeps the full K-slot chain (see
+:func:`fused_stencil_update_v`).
 """
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLOCK_R = 512  # rows per grid step (lanes)
+
+
+def face_group(K: int) -> int:
+    """Slots per face of a K-wide face-neighbour table (2d faces of
+    2^(d-1) sub-slots, ``mesh.amr.face_neighbors``): the first slot of a
+    face holds a same-level or coarser neighbour, the others only finer
+    ones. 1 where K is no such width."""
+    for d in (1, 2, 3):
+        if K == 2 * d * (1 << (d - 1)):
+            return 1 << (d - 1)
+    return 1
 
 
 def stencil_update_ref(
@@ -51,10 +78,14 @@ def stencil_update_ref(
     # gather with (R, K) indices ~60x slower (90 s at 1.4M rows), with
     # the same elementwise arithmetic either way. Fixed-order K
     # accumulation (see module docstring: NOT jnp.sum).
+    # V-wide rows (R, V): the row's mask and coefficient apply to every
+    # field (a trailing axis of 1 broadcasts them)
+    wide = (slice(None), None) if u_rows.ndim == 2 else (slice(None),)
     acc = None
     for k in range(nbr.shape[1]):
         contrib = jnp.where(
-            valid[:, k], coeff[:, k] * (vals_all[nbr[:, k]] - u_rows), jnp.float32(0.0)
+            valid[:, k][wide], coeff[:, k][wide] * (vals_all[nbr[:, k]] - u_rows),
+            jnp.float32(0.0),
         )
         acc = contrib if acc is None else acc + contrib
     return u_rows + acc
@@ -116,3 +147,142 @@ def fused_stencil_update(
         cols(coeff),
     )
     return out[0, :R]
+
+
+# ---------------------------------------------------------------------------
+# V-wide form: (R, V) rows, rows on sublanes, fields on 128 lanes
+# ---------------------------------------------------------------------------
+
+BLOCK_RV = 256        # rows per grid step of the V-wide kernel (sublanes)
+GATHER_ROWS = 65536   # rows whose neighbour values are gathered at once
+
+
+def _update_kernel_v(*refs):
+    # stencil_update_ref's expression on BLOCK_RV rows: one (rows, 128)
+    # tile of gathered values per slot, fields in its first V lanes; or
+    # one per face, which then stands for every slot of the face (a row
+    # whose other slots are all empty adds +0.0 for them, whatever the
+    # value: the result is the same)
+    *vals_refs, u_ref, valid_ref, coeff_ref, out_ref = refs
+    u = u_ref[...]                                   # (BLOCK_RV, V)
+    V = u.shape[1]
+    valid = valid_ref[...] != 0                      # (BLOCK_RV, K)
+    coeff = coeff_ref[...]
+    K = valid.shape[1]
+    group = K // len(vals_refs)
+    vals = [r[:, :V] for r in vals_refs]
+    acc = None
+    for k in range(K):
+        contrib = jnp.where(valid[:, k:k + 1], coeff[:, k:k + 1] * (vals[k // group] - u),
+                            jnp.float32(0.0))
+        acc = contrib if acc is None else acc + contrib
+    out_ref[...] = u + acc
+
+
+def _rows_pass(vals_p, u_rows, nbr, valid, coeff, interpret):
+    """The kernel over every row of the tables: rows in blocks of
+    ``GATHER_ROWS`` (fewer when R is small), each gathering one (block,
+    128-lane) array of neighbour rows per column of ``nbr`` (every slot,
+    or the first slot of each face: see :func:`_update_kernel_v`).
+    The last block ends at row R and overlaps the one before it, whose
+    rows it computes again from the same inputs, so no table is copied
+    to pad it."""
+    R, C = nbr.shape
+    K = valid.shape[1]
+    V = u_rows.shape[1]
+    block = min(GATHER_ROWS, pl.cdiv(R, BLOCK_RV) * BLOCK_RV)
+    if R < block:
+        # pad rows: no valid slot, centre 0 -> 0 + 0 (dropped by the slice)
+        pad = lambda a: jnp.pad(a, ((0, block - R), (0, 0)))
+        nbr, valid, coeff, u_rows = pad(nbr), pad(valid), pad(coeff), pad(u_rows)
+    n = nbr.shape[0]
+    rows = lambda w: pl.BlockSpec((BLOCK_RV, w), lambda i: (i, 0))
+    kernel = pl.pallas_call(
+        _update_kernel_v,
+        grid=(block // BLOCK_RV,),
+        in_specs=[rows(vals_p.shape[1])] * C + [rows(V), rows(K), rows(K)],
+        out_specs=rows(V),
+        out_shape=jax.ShapeDtypeStruct((block, V), jnp.float32),
+        name="stencil_update_v",
+        interpret=interpret,
+    )
+
+    def body(i, out):
+        start = jnp.minimum(i * block, n - block)
+        take = lambda a: jax.lax.dynamic_slice_in_dim(a, start, block)
+        nbr_i = take(nbr)
+        # an empty slot (-1) reads row 0, which the mask drops
+        vals = [jnp.take(vals_p, nbr_i[:, c], axis=0, mode="clip") for c in range(C)]
+        new = kernel(*vals, take(u_rows), take(valid).astype(jnp.int32), take(coeff))
+        return jax.lax.dynamic_update_slice_in_dim(out, new, start, 0)
+
+    out = jax.lax.fori_loop(0, pl.cdiv(n, block), body, jnp.zeros((n, V), jnp.float32))
+    return out[:R]
+
+
+def _first_rows(mask: jax.Array, cap: int) -> jax.Array:
+    """The indices of the first ``cap`` True rows of ``mask`` in
+    ascending order, R (out of range) past their end: ``jnp.nonzero``
+    with ``size=cap``. Each row's rank among the True rows is a prefix
+    sum, taken in blocks of 512 by a matmul with a triangle of ones
+    (exact: 0/1 terms, sums <= 512) and then over the block totals:
+    for 5.33M rows XLA:TPU compiles a cumsum in 7 s and a nonzero in
+    13 s, this in under 3."""
+    R = mask.shape[0]
+    B = 512
+    nb = pl.cdiv(R, B)
+    m = jnp.pad(mask, (0, nb * B - R)).reshape(nb, B).astype(jnp.float32)
+    tri = jnp.triu(jnp.ones((B, B), jnp.float32))
+    within = jnp.dot(m, tri, preferred_element_type=jnp.float32).astype(jnp.int32)
+    before = jnp.cumsum(within[:, -1]) - within[:, -1]
+    rank = (within + before[:, None]).reshape(-1)[:R] - 1
+    pos = jnp.where(mask, rank, cap)            # cap: dropped
+    return jnp.full((cap,), R, jnp.int32).at[pos].set(
+        jnp.arange(R, dtype=jnp.int32), mode="drop")
+
+
+FINER_SHARE = 16      # the second pass takes at most R / 16 rows
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def fused_stencil_update_v(
+    vals_all: jax.Array,
+    u_rows: jax.Array,
+    nbr: jax.Array,
+    valid: jax.Array,
+    coeff: jax.Array,
+    *,
+    interpret: bool = True,
+) -> jax.Array:
+    """V-wide fused update: ``vals_all`` (M, V), ``u_rows`` (R, V),
+    tables (R, K); returns the (R, V) updated rows, bit-equal to
+    :func:`stencil_update_ref`.
+
+    A face-neighbour table has one valid slot a face on most rows: the
+    kernel first runs every row with the values of the first slot of
+    each face only (K / g gathers a row, g = :func:`face_group`), then
+    runs the rows with a valid slot past the first of a face, gathered
+    into a list of at most R / ``FINER_SHARE``, with the values of all K
+    slots, and puts them back. Where more rows than that have such a
+    slot, every row runs with all K."""
+    R, K = nbr.shape
+    V = u_rows.shape[1]
+    lanes = pl.cdiv(V, 128) * 128
+    vals_p = jnp.pad(vals_all, ((0, 0), (0, lanes - V)))
+    full = lambda: _rows_pass(vals_p, u_rows, nbr, valid, coeff, interpret)
+    g = face_group(K)
+    if g == 1:
+        return full()
+    # a slot past the first of a face is valid (an OR of columns: XLA:TPU
+    # compiles a reduction over an (R, K/g, g-1) bool view in ~15 s)
+    finer = functools.reduce(operator.or_, [valid[:, k] for k in range(K) if k % g])
+    cap = pl.cdiv(max(R // FINER_SHARE, 1), BLOCK_RV) * BLOCK_RV
+
+    def faces_then_finer():
+        out = _rows_pass(vals_p, u_rows, nbr[:, ::g], valid, coeff, interpret)
+        rows = _first_rows(finer, cap)
+        take = lambda a: jnp.take(a, rows, axis=0, mode="clip")
+        fine = _rows_pass(vals_p, take(u_rows), take(nbr), take(valid), take(coeff), interpret)
+        return out.at[rows].set(fine, mode="drop")
+
+    return jax.lax.cond(jnp.sum(finer) <= cap, faces_then_finer, full)

@@ -40,9 +40,23 @@ from dataclasses import dataclass
 
 import numpy as np
 
-# bits reserved per dimension in the packed (level, ij) cell key; caps
-# max_level at 20 which is far beyond any mesh this module drives
+# bits per coordinate in the packed (level, ij) cell key: 20 for d <= 2,
+# 19 for d = 3 (3 x 19 coordinate bits leave 6 for the level in a signed
+# int64). The cap on max_level is the smaller of the coordinate bits and
+# the largest level the remaining bits hold: 20 in 1-D and 2-D, 19 in 3-D
+# (see ``max_level_cap``)
 _COORD_BITS = 20
+
+
+def _coord_bits(d: int) -> int:
+    return min(_COORD_BITS, 57 // max(d, 1))
+
+
+def max_level_cap(d: int) -> int:
+    """Finest level a ``d``-dimensional mesh may hold: its cell keys stay
+    unique (no two cells alias) up to this level."""
+    b = _coord_bits(d)
+    return min(b, (1 << (63 - d * b)) - 1)
 
 
 @dataclass(frozen=True)
@@ -78,15 +92,14 @@ class AMRMesh:
 
 def uniform_mesh(d: int = 2, base_level: int = 3, max_level: int = 6) -> AMRMesh:
     """Uniform mesh of ``2**(d*base_level)`` cells at ``base_level``."""
-    if not (0 <= base_level <= max_level <= _COORD_BITS):
+    if not 0 <= base_level <= max_level:
         raise ValueError(f"bad levels base={base_level} max={max_level}")
-    # the packed key shifts level above d * _COORD_BITS bits; a level that
-    # does not fit the remaining signed-int64 headroom would alias other
-    # cells' keys and make _CellLookup return unrelated neighbors
-    if max_level >= 1 << (63 - d * _COORD_BITS):
+    # a level past the cap would alias other cells' packed keys and make
+    # _CellLookup return unrelated neighbors
+    if max_level > max_level_cap(d):
         raise ValueError(
             f"max_level={max_level} overflows the packed cell key for d={d} "
-            f"(limit {(1 << (63 - d * _COORD_BITS)) - 1})"
+            f"(limit {max_level_cap(d)})"
         )
     side = 1 << base_level
     grids = np.meshgrid(*([np.arange(side, dtype=np.int64)] * d), indexing="ij")
@@ -106,9 +119,10 @@ def uniform_mesh(d: int = 2, base_level: int = 3, max_level: int = 6) -> AMRMesh
 
 def _pack(level: np.ndarray, ij: np.ndarray) -> np.ndarray:
     """Unique int64 key per (level, ij) cell."""
+    b = _coord_bits(ij.shape[1])
     key = level.astype(np.int64)
     for a in range(ij.shape[1]):
-        key = (key << _COORD_BITS) | ij[:, a].astype(np.int64)
+        key = (key << b) | ij[:, a].astype(np.int64)
     return key
 
 
@@ -178,32 +192,30 @@ def face_neighbors(mesh: AMRMesh) -> np.ndarray:
             ij2 = mesh.ij.copy()
             ij2[:, a] += s
             in_dom = (ij2[:, a] >= 0) & (ij2[:, a] < (1 << lvl))
-            # same level
-            same = np.where(in_dom, look.find(mesh.level, ij2), -1)
-            # one coarser (only valid where the same-level cell is absent)
-            coarse = np.where(
-                in_dom & (same < 0) & (lvl > 0),
-                look.find(mesh.level - 1, ij2 >> 1),
-                -1,
-            )
+            # same level; then, where that cell is absent, one coarser;
+            # then one finer. Each lookup runs only on the rows still open.
+            same = np.full((n,), -1, np.int64)
+            rows = np.flatnonzero(in_dom)
+            same[rows] = look.find(mesh.level[rows], ij2[rows])
+            coarse = np.full((n,), -1, np.int64)
+            rows = np.flatnonzero(in_dom & (same < 0) & (lvl > 0))
+            coarse[rows] = look.find(mesh.level[rows] - 1, ij2[rows] >> 1)
             nbr[:, f * sub] = np.where(same >= 0, same, coarse)
             # one finer: the 2^(d-1) children of ij2 adjacent to the face.
             # Child a-coord: low side (2*ij2[a]) when we look in +a, high
             # side (2*ij2[a] + 1) when we look in -a.
-            need_fine = in_dom & (same < 0) & (coarse < 0) & (lvl < mesh.max_level)
-            if not need_fine.any():
+            rows = np.flatnonzero(
+                in_dom & (same < 0) & (coarse < 0) & (lvl < mesh.max_level))
+            if not rows.size:
                 continue
             other = [x for x in range(d) if x != a]
-            base = ij2 * 2
+            base = ij2[rows] * 2
             for t in range(sub):
                 child = base.copy()
                 child[:, a] = base[:, a] + (1 if s < 0 else 0)
                 for oi, ax in enumerate(other):
                     child[:, ax] = base[:, ax] + sub_offs[t, oi]
-                fine = np.where(need_fine, look.find(mesh.level + 1, child), -1)
-                nbr[:, f * sub + t] = np.where(
-                    need_fine, fine, nbr[:, f * sub + t]
-                )
+                nbr[rows, f * sub + t] = look.find(mesh.level[rows] + 1, child)
     return nbr.astype(np.int32)
 
 
@@ -273,11 +285,51 @@ def apply_transfer(u_old: np.ndarray, tr: Transfer) -> np.ndarray:
 
     The ONE transfer implementation: both the distributed simulation and
     the single-device reference call this (host-side, float32), so their
-    fields stay bitwise comparable across mesh changes.
+    fields stay bitwise comparable across mesh changes. ``u_old`` is one
+    field (n,) or V fields (n, V); each column of the (n, V) result
+    equals the transfer of that column alone, bit for bit.
     """
     u = np.asarray(u_old, np.float32)
-    vals = np.where(tr.src >= 0, u[np.maximum(tr.src, 0)], np.float32(0.0))
-    return (vals.sum(axis=1) / tr.cnt.astype(np.float32)).astype(np.float32)
+    if u.ndim == 1:
+        vals = np.where(tr.src >= 0, u[np.maximum(tr.src, 0)], np.float32(0.0))
+        return (vals.sum(axis=1) / tr.cnt.astype(np.float32)).astype(np.float32)
+    # a one-source row sums its value and exact zeros, divided by 1:
+    # value + 0.0 (which turns -0.0 into +0.0, as the sum does)
+    out = u[tr.src[:, 0]] + np.float32(0.0)
+    many = np.flatnonzero(tr.cnt > 1)
+    if many.size:
+        sub = Transfer(tr.src[many], tr.cnt[many], tr.born[many], tr.died_idx)
+        out[many] = np.stack([apply_transfer(u[:, v], sub) for v in range(u.shape[1])], 1)
+    return out
+
+
+def apply_transfers(u_old: np.ndarray, trs) -> np.ndarray:
+    """:func:`apply_transfer` through a sequence of steps, in order."""
+    for tr in trs:
+        u_old = apply_transfer(u_old, tr)
+    return u_old
+
+
+def lineage(trs, n_old: int) -> tuple[np.ndarray, np.ndarray]:
+    """Cell identity across a sequence of transfers: ``(src0, died_idx)``
+    with ``src0[k]`` the old index cell ``k`` was kept from (-1 for a
+    cell born anywhere in the sequence) and ``died_idx`` the old cells
+    no new cell was kept from. One transfer gives its own bookkeeping."""
+    src0 = np.arange(n_old, dtype=np.int64)
+    for tr in trs:
+        src0 = np.where(tr.born, -1, src0[np.maximum(tr.src[:, 0], 0)])
+    alive = np.zeros((n_old,), bool)
+    alive[src0[src0 >= 0]] = True
+    return src0, np.flatnonzero(~alive)
+
+
+def ancestors(trs, n_old: int) -> np.ndarray:
+    """(n_new,) an old cell that overlaps each new cell: the one it was
+    kept from, its refined ancestor, or the first child it merged."""
+    anc = np.arange(n_old, dtype=np.int64)
+    for tr in trs:
+        anc = anc[tr.src[:, 0]]
+    return anc
 
 
 def refine_coarsen(
@@ -296,8 +348,14 @@ def refine_coarsen(
     old order), then children (refined-parent order x fixed child
     order), then merged parents (group order).
     """
-    n, d = mesh.n, mesh.d
-    k2 = 1 << d
+    refine, cand = _graded_masks(mesh, refine_mask, coarsen_mask)
+    return _apply_masks(mesh, refine, cand)
+
+
+def _graded_masks(mesh: AMRMesh, refine_mask, coarsen_mask):
+    """(refine, coarsen candidates) closed under the 2:1 rules of
+    :func:`refine_coarsen`; a candidate merges only with all 2^d
+    siblings (resolved by :func:`_apply_masks`)."""
     refine = np.asarray(refine_mask, bool) & (mesh.level < mesh.max_level)
     coarsen = np.asarray(coarsen_mask, bool) & (mesh.level > mesh.base_level)
     nbr = face_neighbors(mesh)
@@ -319,28 +377,33 @@ def refine_coarsen(
     # level + 1 == parent_level + 2 - 1 (merged parent keeps 2:1)
     nb_post = np.where(nbr >= 0, post[np.maximum(nbr, 0)], -(10**6))
     safe = nb_post.max(axis=1) <= mesh.level.astype(np.int64)
-    cand = coarsen & safe
-    parent_key = _pack(mesh.level - 1, mesh.ij >> 1)
-    # complete groups: all 2^d siblings present and willing
+    return refine, coarsen & safe
+
+
+def _sibling_groups(mesh: AMRMesh, cand: np.ndarray) -> np.ndarray:
+    """(g, 2^d) complete sibling groups among the candidate cells: groups
+    in ascending parent key, children in their own key order (= fixed
+    child order, as pack sorts ij lexicographically)."""
+    k2 = 1 << mesh.d
     cand_idx = np.nonzero(cand)[0]
-    merged_parent_ids: np.ndarray
-    group_children = np.zeros((0, k2), np.int64)
-    if cand_idx.size:
-        pk = parent_key[cand_idx]
-        order = np.argsort(pk, kind="stable")
-        pk_s, idx_s = pk[order], cand_idx[order]
-        uniq, starts, counts = np.unique(pk_s, return_index=True, return_counts=True)
-        full = counts == k2
-        if full.any():
-            starts_f = starts[full]
-            # children of each full group, sorted by their own cell key =
-            # fixed child order (pack sorts ij lexicographically)
-            rows = []
-            for s in starts_f:
-                grp = idx_s[s : s + k2]
-                ck = _pack(mesh.level[grp], mesh.ij[grp])
-                rows.append(grp[np.argsort(ck)])
-            group_children = np.stack(rows, axis=0)
+    if not cand_idx.size:
+        return np.zeros((0, k2), np.int64)
+    pk = _pack(mesh.level[cand_idx] - 1, mesh.ij[cand_idx] >> 1)
+    ck = _pack(mesh.level[cand_idx], mesh.ij[cand_idx])
+    order = np.lexsort((ck, pk))
+    pk_s, idx_s = pk[order], cand_idx[order]
+    _, starts, counts = np.unique(pk_s, return_index=True, return_counts=True)
+    starts_f = starts[counts == k2]
+    return idx_s[starts_f[:, None] + np.arange(k2)[None, :]]
+
+
+def _apply_masks(mesh: AMRMesh, refine: np.ndarray, cand: np.ndarray):
+    """Build the adapted mesh and its transfer from final masks: every
+    ``refine`` cell splits, every complete sibling group of ``cand``
+    cells merges (the masks must already satisfy the 2:1 rules)."""
+    n, d = mesh.n, mesh.d
+    k2 = 1 << d
+    group_children = _sibling_groups(mesh, cand)
     removed = np.zeros(n, bool)
     if group_children.shape[0]:
         removed[group_children.reshape(-1)] = True
@@ -421,3 +484,114 @@ def adapt_masks(
         np.sum((mesh.centers().astype(np.float64) - c[None, :]) ** 2, axis=1)
     )
     return dist < r_refine, dist > r_coarsen
+
+
+# ---------------------------------------------------------------------------
+# miniAMR (Mantevo's proxy for block-structured AMR codes) on the octree
+# ---------------------------------------------------------------------------
+#
+# miniAMR holds the mesh as blocks of 2^b cells per side that refine or
+# coarsen whole: a refined block becomes 2^d blocks one level finer, which
+# is one refine of every cell of the block. So its mesh is this module's
+# cell mesh with masks that are constant per block, and a block is the
+# set of cells of one level sharing ``ij >> b``.
+
+@dataclass(frozen=True)
+class Spheroid:
+    """miniAMR ``--object 2 ...``: the surface of an axis-aligned
+    spheroid. At timestep ``t`` its centre is ``center + t * move`` and
+    its semi-axes ``size + t * inc``."""
+
+    center: tuple
+    move: tuple
+    size: tuple
+    inc: tuple = (0.0, 0.0, 0.0)
+
+    def at(self, t: float) -> tuple[np.ndarray, np.ndarray]:
+        c = np.asarray(self.center, np.float64) + t * np.asarray(self.move, np.float64)
+        r = np.asarray(self.size, np.float64) + t * np.asarray(self.inc, np.float64)
+        return c, r
+
+
+def blocks_of(mesh: AMRMesh, block_bits: int) -> tuple[AMRMesh, np.ndarray]:
+    """The block mesh of a block-structured cell mesh (blocks of
+    ``2**block_bits`` cells per side; levels shifted down by
+    ``block_bits``) and the block index of every cell."""
+    level = mesh.level - block_bits
+    ij = mesh.ij >> block_bits
+    _, first, inv = np.unique(_pack(level, ij), return_index=True, return_inverse=True)
+    blocks = AMRMesh(
+        level=level[first], ij=ij[first],
+        base_level=mesh.base_level - block_bits,
+        max_level=mesh.max_level - block_bits,
+    )
+    return blocks, inv.reshape(-1)
+
+
+def surface_hit(blocks: AMRMesh, objects, t: float) -> np.ndarray:
+    """(nb,) bool: the block's closed box meets some object's surface,
+    i.e. the box holds points on both sides of it (miniAMR's test for a
+    surface object): min over the box of sum(((x - c) / r)^2) <= 1 <=
+    its max, the min at the clamped centre and the max at the farthest
+    corner."""
+    h = (0.5 ** blocks.level.astype(np.float64))[:, None]
+    lo = blocks.ij.astype(np.float64) * h
+    hi = lo + h
+    hit = np.zeros((blocks.n,), bool)
+    for obj in objects:
+        c, r = obj.at(t)
+        near = np.clip(c[None, :], lo, hi)
+        far = np.where(np.abs(lo - c) > np.abs(hi - c), lo, hi)
+        dmin = np.sum(((near - c) / r) ** 2, axis=1)
+        dmax = np.sum(((far - c) / r) ** 2, axis=1)
+        hit |= (dmin <= 1.0) & (dmax >= 1.0)
+    return hit
+
+
+def miniamr_adapt(
+    mesh: AMRMesh, objects, t: float, *, block_bits: int = 3, block_change: int = 1,
+) -> tuple[AMRMesh, tuple]:
+    """miniAMR's refine step: up to ``block_change`` rounds, each marking
+    every block a surface crosses for refinement and every other block
+    for coarsening, grading the blocks 2:1 across faces (the rules of
+    :func:`refine_coarsen`, applied to blocks), and splitting or merging
+    whole blocks. Returns the new mesh, its cells in packed-key order
+    (so one geometry gives one cell order, whatever the history), and
+    the per-round transfers (see :func:`apply_transfers`,
+    :func:`lineage`); stops early when a round changes nothing."""
+    transfers = []
+    for _ in range(block_change):
+        blocks, cell_block = blocks_of(mesh, block_bits)
+        hit = surface_hit(blocks, objects, t)
+        refine, cand = _graded_masks(blocks, hit, ~hit)
+        merge = np.zeros((blocks.n,), bool)
+        merge[_sibling_groups(blocks, cand).reshape(-1)] = True
+        if not (refine.any() or merge.any()):
+            break
+        mesh, tr = _apply_masks(mesh, refine[cell_block], merge[cell_block])
+        transfers.append(tr)
+    if transfers:
+        perm = np.argsort(_pack(mesh.level, mesh.ij))
+        mesh = AMRMesh(level=mesh.level[perm], ij=mesh.ij[perm],
+                       base_level=mesh.base_level, max_level=mesh.max_level)
+        tr = transfers[-1]
+        transfers[-1] = Transfer(src=tr.src[perm], cnt=tr.cnt[perm], born=tr.born[perm],
+                                 died_idx=tr.died_idx)
+    return mesh, tuple(transfers)
+
+
+def same_cells(a: AMRMesh, b: AMRMesh) -> bool:
+    """The two meshes hold the same cells in the same order."""
+    return np.array_equal(a.level, b.level) and np.array_equal(a.ij, b.ij)
+
+
+def miniamr_coeffs(mesh: AMRMesh, nbr: np.ndarray) -> np.ndarray:
+    """(n, K) float32 coefficients of miniAMR's 7-point average in the
+    fused form ``u + sum_k c_k (u_k - u)``: 1/7 for a same-level or
+    coarser face neighbour, 1/28 for each of the 4 finer ones (their
+    mean stands in for the face's ghost); 0 on an empty slot, so a
+    boundary face adds nothing, as if its ghost held the cell's value."""
+    nb = np.maximum(nbr, 0)
+    finer = mesh.level[nb] > mesh.level[:, None]
+    c = np.where(finer, 1.0 / (7 * (1 << (mesh.d - 1))), 1.0 / 7)
+    return np.where(nbr >= 0, c, 0.0).astype(np.float32)

@@ -155,19 +155,113 @@ class HaloPlan:
         return tuple((s.axis, s.lanes, s.cap) for s in self.stages)
 
     def pack_cells(self, u_cells: np.ndarray) -> np.ndarray:
-        """Global cell-order field -> (S*cap,) owned device layout."""
-        out = np.zeros((self.owned_idx.shape[0], self.cap), np.float32)
+        """Global cell-order field (n,) or fields (n, V) -> the owned
+        device layout (S*cap,) or (S*cap, V)."""
+        u = np.asarray(u_cells, np.float32)
+        out = np.zeros((self.owned_idx.shape[0], self.cap) + u.shape[1:], np.float32)
         m = self.owned_idx >= 0
-        out[m] = np.asarray(u_cells, np.float32)[self.owned_idx[m]]
-        return out.reshape(-1)
+        out[m] = u[self.owned_idx[m]]
+        return out.reshape((-1,) + u.shape[1:])
 
     def unpack_cells(self, u_dev: np.ndarray, n_cells: int) -> np.ndarray:
-        """(S*cap,) owned device layout -> global cell-order field."""
-        u = np.asarray(u_dev, np.float32).reshape(self.owned_idx.shape)
-        out = np.zeros((n_cells,), np.float32)
+        """Owned device layout (S*cap,) or (S*cap, V) -> global cell order."""
+        u = np.asarray(u_dev, np.float32)
+        u = u.reshape(self.owned_idx.shape + u.shape[1:])
+        out = np.zeros((n_cells,) + u.shape[2:], np.float32)
         m = self.owned_idx >= 0
         out[self.owned_idx[m]] = u[m]
         return out
+
+    @property
+    def caps(self) -> dict:
+        """The padded sizes that shape the compiled executors."""
+        return {"cap": self.cap, "gcap": self.gcap,
+                "icap": self.interior_idx.shape[1], "bcap": self.boundary_idx.shape[1],
+                "stages": tuple(s.cap for s in self.stages)}
+
+    def padded(self, caps: dict) -> "HaloPlan":
+        """The same plan with every padded size raised to ``caps`` (none
+        may shrink): the executors then compile one program for every
+        plan padded alike, and compute the same values."""
+        S, cap, K = self.nbr_local.shape
+        C = caps["cap"]
+        owned_idx = _pad_last(self.owned_idx, C, -1)
+        owned_slot = _pad_last(self.owned_slot, C, -1)
+        # ghost references (>= cap) follow the ghost block to C
+        nl = np.where(self.nbr_valid & (self.nbr_local >= cap),
+                      self.nbr_local + (C - cap), self.nbr_local)
+        nbr_local = np.zeros((S, C, K), np.int32)
+        nbr_local[:, :cap] = nl
+        nbr_valid = np.zeros((S, C, K), bool)
+        nbr_valid[:, :cap] = self.nbr_valid
+        coeff = np.zeros((S, C, K), np.float32)
+        coeff[:, :cap] = self.coeff
+        stages, fetch = _pad_stages(self.stages, caps["stages"], self.ghost_fetch)
+        return HaloPlan(
+            axes=self.axes, num_parts=self.num_parts, cap=C, gcap=caps["gcap"], K=K,
+            owned_idx=owned_idx, owned_slot=owned_slot, nbr_local=nbr_local,
+            nbr_valid=nbr_valid, coeff=coeff, stages=stages,
+            ghost_fetch=_pad_last(fetch, caps["gcap"], -1),
+            interior_idx=_pad_last(self.interior_idx, caps["icap"], -1),
+            boundary_idx=_pad_last(self.boundary_idx, caps["bcap"], -1),
+            metrics=self.metrics,
+        )
+
+
+def _pad_last(a: np.ndarray, size: int, fill) -> np.ndarray:
+    """``a`` with its last axis padded with ``fill`` to ``size``."""
+    if size < a.shape[-1]:
+        raise ValueError(f"cannot pad {a.shape[-1]} down to {size}")
+    out = np.full(a.shape[:-1] + (size,), fill, a.dtype)
+    out[..., : a.shape[-1]] = a
+    return out
+
+
+def _pad_stages(stages, caps, fetch=None):
+    """Stages padded to lane capacities ``caps``. A hop's receive buffer
+    is (lanes, cap) flat, so the positions the next hop (or ``fetch``)
+    reads from it move from ``lane * cap + t`` to ``lane * cap' + t``."""
+    out = []
+    prev = None       # (old cap, new cap) of the previous hop's lanes
+    for st, c in zip(stages, caps):
+        idx = st.idx
+        if prev is not None:
+            idx = np.where(idx >= 0, (idx // prev[0]) * prev[1] + idx % prev[0], -1)
+        out.append(Stage(axis=st.axis, lanes=st.lanes, cap=int(c),
+                         idx=_pad_last(idx.astype(np.int32), int(c), -1)))
+        prev = (st.cap, int(c))
+    if fetch is not None and prev is not None:
+        fetch = np.where(fetch >= 0, (fetch // prev[0]) * prev[1] + fetch % prev[0], -1)
+    return tuple(out), None if fetch is None else fetch.astype(np.int32)
+
+
+def layout_plan(slot: np.ndarray, part: np.ndarray, *, hierarchy=None,
+                num_parts: int | None = None, device_axis: str = "device") -> HaloPlan:
+    """A plan that only places cells: each part's owned cells in
+    ascending-slot order, as :func:`build_halo_plan` lays them out, with
+    no stencil tables and no exchange. For placing state that a
+    :func:`build_move_plan` then carries to a full plan's layout."""
+    slot = np.asarray(slot, np.int64)
+    part64 = np.asarray(part).astype(np.int64)
+    n = slot.shape[0]
+    N, D, S, axes = _plan_shape(part64, hierarchy, num_parts, device_axis)
+    ocells = np.lexsort((slot, part64))
+    ocounts = np.bincount(part64, minlength=S)
+    ostarts = np.concatenate(([0], np.cumsum(ocounts)))
+    cap = _roundup(int(ocounts.max()) if n else 0)
+    drow = part64[ocells] * cap + np.arange(n) - ostarts[part64[ocells]]
+    owned_idx = np.full((S * cap,), -1, np.int32)
+    owned_slot = np.full((S * cap,), -1, np.int64)
+    owned_idx[drow] = ocells
+    owned_slot[drow] = slot[ocells]
+    none = np.full((S, 1), -1, np.int32)
+    return HaloPlan(
+        axes=axes, num_parts=S, cap=cap, gcap=1, K=0,
+        owned_idx=owned_idx.reshape(S, cap), owned_slot=owned_slot.reshape(S, cap),
+        nbr_local=np.zeros((S, cap, 0), np.int32), nbr_valid=np.zeros((S, cap, 0), bool),
+        coeff=np.zeros((S, cap, 0), np.float32), stages=(), ghost_fetch=none,
+        interior_idx=none, boundary_idx=none,
+    )
 
 
 def owners_from_index(index, part_by_slot: np.ndarray, centers) -> np.ndarray:
@@ -822,6 +916,17 @@ class MovePlan:
     @property
     def stage_meta(self) -> tuple:
         return tuple((s.axis, s.lanes, s.cap) for s in self.stages)
+
+    def padded(self, cap_old: int, cap_new: int, stage_caps: tuple) -> "MovePlan":
+        """The same move between layouts padded to ``cap_old`` /
+        ``cap_new`` rows, its hops to ``stage_caps`` (see
+        :meth:`HaloPlan.padded`)."""
+        stages, _ = _pad_stages(self.stages, stage_caps)
+        return MovePlan(
+            kind=self.kind, axes=self.axes, cap_old=int(cap_old), cap_new=int(cap_new),
+            keep=_pad_last(self.keep, int(cap_old), False), stages=stages,
+            migration=self.migration, metrics=self.metrics,
+        )
 
 
 def build_move_plan(

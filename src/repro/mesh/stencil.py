@@ -20,7 +20,11 @@ update itself is the fused `kernels.ops.stencil_update` (gather + mask
 + coeff*(v-u) + K-reduce in one pass; optional Pallas kernel, bit-equal
 jnp fallback). The step loop is a ``fori_loop`` over a *traced* step
 count, so ONE compiled executor serves every sweep length — ``steps``
-is not part of the cache signature.
+is not part of the cache signature. With V fields a row (``u`` of shape
+(rows, V)) the executor does not split: it waits for the exchange and
+updates every row in one pass, since the split's gathers of the row
+subsets' centres and tables and its scatter back are each a pass over
+V-wide rows that costs more than the exchange it would hide.
 
 Bit-equality contract: :func:`reference_stencil` (single device, global
 cell order) and :func:`stencil_steps` (sharded, owned+ghost layout)
@@ -52,16 +56,26 @@ from repro.mesh.halo import GID_SENTINEL, HaloPlan, MovePlan
 
 def _a2a(buf, axis):
     r = jax.lax.all_to_all(buf, axis, split_axis=0, concat_axis=0, tiled=False)
-    return r.reshape(-1)
+    return r.reshape((-1,) + buf.shape[2:])
+
+
+def _rows(mask, like):
+    """A row mask broadcast over the fields of (rows, V) values."""
+    return mask[:, None] if like.ndim == 2 else mask
 
 
 def _route(prev, stage_meta, stage_idx, fill):
-    """Replay the plan's hops: gather into lane buffers, exchange."""
+    """Replay the plan's hops: gather rows into lane buffers, exchange.
+    ``prev`` is (rows,) or (rows, V): a row carries all its fields."""
     for (ax, lanes, scap), idx in zip(stage_meta, stage_idx):
         src = jnp.clip(idx, 0, prev.shape[0] - 1)
-        buf = jnp.where(idx >= 0, prev[src], fill).reshape(lanes, scap)
-        prev = _a2a(buf, ax)
+        buf = jnp.where(_rows(idx >= 0, prev), prev[src], fill)
+        prev = _a2a(buf.reshape((lanes, scap) + prev.shape[1:]), ax)
     return prev
+
+
+def _ghosts(recv, fetch):
+    return jnp.where(_rows(fetch >= 0, recv), recv[jnp.clip(fetch, 0, recv.shape[0] - 1)], 0.0)
 
 
 def _rows_update(u_out, u, vals_all, nbr, valid, coeff, rows, use_pallas):
@@ -116,6 +130,13 @@ def _stencil_fn(
     length."""
 
     def kernel(steps, u, nbr, valid, coeff, fetch, interior, boundary, *stage_idx):
+        def body_wide(_, u):
+            # V fields a row: the exchange, then every row at once (see
+            # the module docstring)
+            recv = _route(u, stage_meta, stage_idx, jnp.float32(0.0))
+            vals_all = jnp.concatenate([u, _ghosts(recv, fetch)])
+            return _ops.stencil_update(vals_all, u, nbr, valid, coeff, use_pallas=use_pallas)
+
         def body(_, u):
             # launch the ghost exchange; nothing below depends on it
             # until the boundary update, so XLA is free to run the
@@ -124,14 +145,11 @@ def _stencil_fn(
             # interior rows: all reads come from u itself
             u_new = _rows_update(u, u, u, nbr, valid, coeff, interior, use_pallas)
             # boundary rows: wait for the recv, fetch ghosts, update
-            ghosts = jnp.where(
-                fetch >= 0, recv[jnp.clip(fetch, 0, recv.shape[0] - 1)], 0.0
-            )
-            vals_all = jnp.concatenate([u, ghosts])
+            vals_all = jnp.concatenate([u, _ghosts(recv, fetch)])
             return _rows_update(
                 u_new, u, vals_all, nbr, valid, coeff, boundary, use_pallas
             )
-        return jax.lax.fori_loop(0, steps, body, u)
+        return jax.lax.fori_loop(0, steps, body_wide if u.ndim == 2 else body, u)
 
     spec = P(axes)
     in_specs = (P(),) + (spec,) * (7 + len(stage_meta))
@@ -153,10 +171,7 @@ def _stencil_fn_presplit(
     def kernel(u, nbr, valid, coeff, fetch, *stage_idx):
         for _ in range(steps):
             recv = _route(u, stage_meta, stage_idx, jnp.float32(0.0))
-            ghosts = jnp.where(
-                fetch >= 0, recv[jnp.clip(fetch, 0, recv.shape[0] - 1)], 0.0
-            )
-            vals_all = jnp.concatenate([u, ghosts])
+            vals_all = jnp.concatenate([u, _ghosts(recv, fetch)])
             u = _ops.stencil_update(vals_all, u, nbr, valid, coeff)
         return u
 
@@ -181,7 +196,7 @@ def halo_args(jax_mesh: jax.sharding.Mesh, plan: HaloPlan) -> HaloArgs:
     per plan, outside the timed sweep loop)."""
     sh = NamedSharding(jax_mesh, P(plan.axes))
     S = plan.owned_idx.shape[0]
-    put = lambda a: jax.device_put(jnp.asarray(a), sh)
+    put = lambda a: jax.device_put(a, sh)   # host shards straight to their devices
     core = (
         put(plan.nbr_local.reshape(S * plan.cap, plan.K)),
         put(plan.nbr_valid.reshape(S * plan.cap, plan.K)),
@@ -210,7 +225,8 @@ def stencil_steps(
 ):
     """Run ``steps`` distributed sweeps over the plan's layout.
 
-    ``u_dev`` is the (S*cap,) owned field (``plan.pack_cells`` layout);
+    ``u_dev`` is the (S*cap,) owned field or (S*cap, V) owned fields
+    (``plan.pack_cells`` layout);
     ``args`` from :func:`halo_args`. The default overlapped executor
     updates interior rows while the exchange is in flight and reuses
     ONE compiled program for every ``steps``; ``overlap=False`` runs the
@@ -223,9 +239,9 @@ def stencil_steps(
 
 
 def put_state(jax_mesh, plan: HaloPlan, u_cells: np.ndarray):
-    """Host cell-order field -> device owned layout."""
+    """Host cell-order field (n,) or fields (n, V) -> device owned layout."""
     sh = NamedSharding(jax_mesh, P(plan.axes))
-    return jax.device_put(jnp.asarray(plan.pack_cells(u_cells)), sh)
+    return jax.device_put(plan.pack_cells(u_cells), sh)
 
 
 # ---------------------------------------------------------------------------
@@ -241,8 +257,7 @@ def _phase_fns(mesh: jax.sharding.Mesh, axes: tuple, stage_meta: tuple):
     spec = P(axes)
 
     def exchange(u, fetch, *stage_idx):
-        recv = _route(u, stage_meta, stage_idx, jnp.float32(0.0))
-        return jnp.where(fetch >= 0, recv[jnp.clip(fetch, 0, recv.shape[0] - 1)], 0.0)
+        return _ghosts(_route(u, stage_meta, stage_idx, jnp.float32(0.0)), fetch)
 
     def interior(u, nbr, valid, coeff, rows):
         return _rows_update(u, u, u, nbr, valid, coeff, rows, False)
@@ -316,9 +331,9 @@ def _move_fn(
         for (ax, lanes, scap), idx in zip(stage_meta, stage_idx):
             src = jnp.clip(idx, 0, prev_u.shape[0] - 1)
             sel = idx >= 0
-            buf_u = jnp.where(sel, prev_u[src], 0.0).reshape(lanes, scap)
+            buf_u = jnp.where(_rows(sel, prev_u), prev_u[src], 0.0)
             buf_g = jnp.where(sel, prev_g[src], GID_SENTINEL).reshape(lanes, scap)
-            prev_u = _a2a(buf_u, ax)
+            prev_u = _a2a(buf_u.reshape((lanes, scap) + u.shape[1:]), ax)
             prev_g = _a2a(buf_g, ax)
         kept_g = jnp.where(keep, gid, GID_SENTINEL)
         if stage_meta:
@@ -326,9 +341,12 @@ def _move_fn(
             all_u = jnp.concatenate([u, prev_u])
         else:
             all_g, all_u = kept_g, u
-        order = jnp.argsort(all_g, stable=True)[:cap_new]
+        # slots are unique but for the sentinel of empty rows, which end
+        # last and read 0, so an unstable sort gives the same rows (and
+        # XLA:TPU compiles it in two thirds of the time)
+        order = jnp.argsort(all_g, stable=False)[:cap_new]
         out_g = all_g[order]
-        return jnp.where(out_g != GID_SENTINEL, all_u[order], 0.0)
+        return jnp.where(_rows(out_g != GID_SENTINEL, all_u), all_u[order], 0.0)
 
     spec = P(axes)
     in_specs = (spec,) * (3 + len(stage_meta))
@@ -348,3 +366,33 @@ def move_state(jax_mesh, mv: MovePlan, old: HaloPlan, u_dev):
     stages = tuple(put(s.idx.reshape(S * s.lanes * s.cap)) for s in mv.stages)
     fn = _move_fn(jax_mesh, mv.axes, mv.stage_meta, int(mv.cap_new))
     return fn(u_dev, gid, keep, *stages)
+
+
+# ---------------------------------------------------------------------------
+# global checksums
+# ---------------------------------------------------------------------------
+
+CHECKSUM_BLOCK = 1024   # rows summed together before the block sums
+
+
+@functools.lru_cache(maxsize=16)
+def _checksum_fn(mesh: jax.sharding.Mesh, axes: tuple):
+    """Jitted per-field global sum: each device sums its owned rows (pad
+    rows hold 0) in blocks of ``CHECKSUM_BLOCK``, then the block sums,
+    then one ``psum`` over the mesh (so no float32 chain of adds is
+    longer than a block or the block count)."""
+
+    def kernel(u):
+        pad = -u.shape[0] % CHECKSUM_BLOCK
+        u = jnp.pad(u, ((0, pad),) + ((0, 0),) * (u.ndim - 1))
+        blocks = jnp.sum(u.reshape((-1, CHECKSUM_BLOCK) + u.shape[1:]), axis=1)
+        return jax.lax.psum(jnp.sum(blocks, axis=0), axes)
+
+    return jax.jit(jax.shard_map(
+        kernel, mesh=mesh, in_specs=(P(axes),), out_specs=P(), check_vma=False,
+    ))
+
+
+def checksum(jax_mesh, plan: HaloPlan, u_dev):
+    """(V,) float32 sum of every field over all cells (a device array)."""
+    return _checksum_fn(jax_mesh, plan.axes)(u_dev)

@@ -61,9 +61,14 @@ def test_uniform_mesh_tiles_domain():
         # interior cells have exactly 2d same-level neighbors
         assert (nbr >= 0).sum(axis=1).max() == 2 * d
     # levels that would overflow the packed int64 cell key are rejected
-    # up front (a d=3 level >= 8 aliases other cells' keys)
-    with pytest.raises(ValueError, match="overflow"):
-        amr.uniform_mesh(3, 2, 8)
+    # up front, naming the cap of their dimension count (19 in 3-D, 20
+    # in 2-D); miniAMR's level-9 octree fits
+    assert amr.max_level_cap(3) == 19 and amr.max_level_cap(2) == 20
+    with pytest.raises(ValueError, match=r"overflow.*limit 19"):
+        amr.uniform_mesh(3, 2, 20)
+    with pytest.raises(ValueError, match=r"overflow.*limit 20"):
+        amr.uniform_mesh(2, 2, 21)
+    amr.uniform_mesh(3, 2, 9)
 
 
 @settings(max_examples=6, deadline=None)

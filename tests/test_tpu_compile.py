@@ -1,5 +1,5 @@
 """Every Pallas kernel compiles for a TPU v5e at the sizes chip_smoke.py
-runs, with no chip attached: the TPU compiler lowers the kernel for a
+(and, for the V-wide stencil, the miniAMR cell) runs, with no chip attached: the TPU compiler lowers the kernel for a
 described v5e chip (``topologies.get_topology_desc``), which catches
 what interpret mode cannot — layouts Mosaic refuses, scoped-VMEM
 overflow, and padded HBM temporaries (a (n, 3) block padded to 128
@@ -21,6 +21,7 @@ from repro.kernels import hilbert, morton, pair_force, stencil_update
 N_POINTS = 1 << 24      # partition phase: keys of every point
 MESH_ROWS = 1_397_674   # mesh phase: cells after the last refinement
 PARTICLES, K_PAIR = 32768, 112   # particle phase: atoms, table width
+AMR_ROWS, AMR_V = 1_400_064, 40   # miniAMR cell: rows a chip updates, fields
 
 u32, i32, f32, b1 = jnp.uint32, jnp.int32, jnp.float32, jnp.bool_
 
@@ -33,6 +34,10 @@ CASES = {
         lambda v, u, n, m, c: stencil_update.fused_stencil_update(v, u, n, m, c, interpret=False),
         [((MESH_ROWS,), f32), ((MESH_ROWS,), f32), ((MESH_ROWS, 8), i32),
          ((MESH_ROWS, 8), b1), ((MESH_ROWS, 8), f32)]),
+    "stencil_update_v": (
+        lambda v, u, n, m, c: stencil_update.fused_stencil_update_v(v, u, n, m, c, interpret=False),
+        [((AMR_ROWS + 200_064, AMR_V), f32), ((AMR_ROWS, AMR_V), f32), ((AMR_ROWS, 24), i32),
+         ((AMR_ROWS, 24), b1), ((AMR_ROWS, 24), f32)]),
     "pair_force": (
         lambda p, m, x, n, v, r: pair_force.fused_pair_accel(p, m, x, n, v, r, interpret=False),
         [((PARTICLES, 3), f32), ((PARTICLES,), f32), ((PARTICLES, 3), f32),
