@@ -84,41 +84,87 @@ def from_points(
 @functools.partial(jax.jit, static_argnames=("max_depth",))
 def locate(tree: LinearKdTree, pts: jax.Array, max_depth: int) -> jax.Array:
     """Vectorized root→leaf walk along split hyperplanes (InsertDelete /
-    point-location path). Returns heap leaf id per query point."""
+    point-location path). Returns heap leaf id per query point.
+
+    Each level gathers one row per point: the node's split dimension
+    (-1 at a leaf) beside its split value's bits."""
+    dim = jnp.where(tree.is_leaf | (tree.split_dim < 0), -1, tree.split_dim)
+    table = jnp.stack([dim, jax.lax.bitcast_convert_type(tree.split_val, jnp.int32)], axis=1)
 
     def body(_, node):
-        dim = tree.split_dim[node]
-        val = tree.split_val[node]
-        leaf = tree.is_leaf[node] | (tree.split_dim[node] < 0)
-        coord = jnp.take_along_axis(pts, jnp.maximum(dim, 0)[:, None], axis=1)[:, 0]
-        side = (coord > val).astype(jnp.int32)
-        nxt = 2 * node + 1 + side
-        return jnp.where(leaf, node, nxt)
+        row = table[node]
+        dim, val = row[:, 0], jax.lax.bitcast_convert_type(row[:, 1], jnp.float32)
+        coord = pts[:, 0]
+        for j in range(1, pts.shape[1]):
+            coord = jnp.where(dim == j, pts[:, j], coord)
+        nxt = 2 * node + 1 + (coord > val).astype(jnp.int32)
+        return jnp.where(dim < 0, node, nxt)
 
     node0 = jnp.zeros((pts.shape[0],), dtype=jnp.int32)
     return jax.lax.fori_loop(0, max_depth, body, node0)
 
 
+def padded_rows(k: int) -> int:
+    """Rows a ``k``-row insert/delete batch is padded to: ``k`` itself
+    below 16, else the least ``m * 2**e >= k`` with ``m`` in [8, 16).
+    So a batch wastes less than ``k / 8`` rows, and the sizes a stream
+    of varying batches compiles for are 8 an octave."""
+    k = int(k)
+    step = 1 << max(k.bit_length() - 4, 0)
+    return -(-k // step) * step
+
+
+def _insert_rows(dps: DynamicPointSet, new_pts, new_wts, valid, walk=None):
+    """Insert the rows of a batch where ``valid`` is set into the lowest
+    free slots, in row order, and count them into the tree. Returns the
+    new set, each row's slot (``capacity`` where the row is padding or
+    found no free slot: its scatters are dropped) and leaf (``num_nodes``
+    there). ``walk`` is the point location (default ``locate``)."""
+    C, M = dps.capacity, dps.tree.num_nodes
+    free = jnp.nonzero(~dps.active, size=new_pts.shape[0], fill_value=C)[0]
+    ok = valid & (free < C)
+    slot = jnp.where(ok, free, C)
+    lid = (walk or locate)(dps.tree, new_pts, dps.tree.max_depth)
+    leaf = jnp.where(ok, lid, M)
+    tree = _tree_add(dps.tree, leaf, ok.astype(jnp.int32), jnp.where(ok, new_wts, 0.0))
+    out = DynamicPointSet(
+        points=dps.points.at[slot].set(new_pts, mode="drop"),
+        weights=dps.weights.at[slot].set(new_wts, mode="drop"),
+        active=dps.active.at[slot].set(True, mode="drop"),
+        leaf_id=dps.leaf_id.at[slot].set(lid, mode="drop"),
+        tree=tree,
+    )
+    return out, slot, leaf
+
+
+def _delete_rows(dps: DynamicPointSet, slot_ids, valid):
+    """Deactivate the slots of the rows where ``valid`` is set. A row
+    counts (``removed``) only at the first occurrence of a live slot, so
+    duplicates and already-inactive slots are no-ops. Returns the new set,
+    ``removed``, and each row's leaf (``num_nodes`` where not removed)
+    and weight (0 where not removed)."""
+    C, M = dps.capacity, dps.tree.num_nodes
+    removed = valid & dps.active[slot_ids] & first_occurrence_mask(slot_ids)
+    leaf = jnp.where(removed, dps.leaf_id[slot_ids], M)
+    wts = jnp.where(removed, dps.weights[slot_ids], 0.0)
+    tree = _tree_add(dps.tree, leaf, -removed.astype(jnp.int32), -wts)
+    active = dps.active.at[jnp.where(valid, slot_ids, C)].set(False, mode="drop")
+    return dps._replace(active=active, tree=tree), removed, leaf, wts
+
+
+@jax.jit
 def insert(dps: DynamicPointSet, new_pts: jax.Array, new_wts: jax.Array) -> DynamicPointSet:
-    """Insert a batch of points into free slots and locate their buckets."""
-    k = new_pts.shape[0]
-    free = jnp.nonzero(~dps.active, size=k, fill_value=dps.capacity - 1)[0]
-    lid = locate(dps.tree, new_pts, dps.tree.max_depth)
-    points = dps.points.at[free].set(new_pts)
-    weights = dps.weights.at[free].set(new_wts)
-    active = dps.active.at[free].set(True)
-    leaf_id = dps.leaf_id.at[free].set(lid)
-    # bump subtree weights along the path root→leaf
-    tree = _bump_counts(dps.tree, lid, new_wts, sign=+1)
-    return DynamicPointSet(points, weights, active, leaf_id, tree)
+    """Insert a batch of points into the lowest free slots and locate
+    their buckets (one program). Points beyond the free capacity are
+    dropped; ``Repartitioner.insert`` refuses such a batch."""
+    valid = jnp.ones((new_pts.shape[0],), bool)
+    return _insert_rows(dps, new_pts, new_wts, valid)[0]
 
 
 def first_occurrence_mask(slot_ids: jax.Array) -> jax.Array:
     """(k,) bool: True at the first occurrence of each id in the batch.
 
-    The dedup mask behind delete's no-op guarantee — shared with the
-    repartitioning engine so its bucket-summary deltas apply exactly the
-    ids the tree counters decrement."""
+    The dedup mask behind delete's no-op guarantee."""
     order = jnp.argsort(slot_ids, stable=True)
     sorted_ids = slot_ids[order]
     first_sorted = jnp.concatenate(
@@ -127,54 +173,38 @@ def first_occurrence_mask(slot_ids: jax.Array) -> jax.Array:
     return jnp.zeros_like(first_sorted).at[order].set(first_sorted)
 
 
-def delete(
-    dps: DynamicPointSet,
-    slot_ids: jax.Array,
-    removed: jax.Array | None = None,
-) -> DynamicPointSet:
-    """Deactivate points by storage slot id. Already-inactive ids and
-    duplicates (within or across calls) are no-ops: the weight and count
-    decrements are masked by ``active`` and a first-occurrence filter, so
-    tree counters stay consistent with storage. ``removed`` overrides the
-    mask (a caller that already computed ``active & first_occurrence``
-    passes it to avoid a second argsort of the batch)."""
-    act = (
-        dps.active[slot_ids] & first_occurrence_mask(slot_ids)
-        if removed is None
-        else removed
-    )
-    wts = dps.weights[slot_ids] * act
-    tree = _bump_counts(
-        dps.tree, dps.leaf_id[slot_ids], wts, sign=-1, counts=act.astype(jnp.int32)
-    )
-    active = dps.active.at[slot_ids].set(False)
-    return dps._replace(active=active, tree=tree)
+@jax.jit
+def delete(dps: DynamicPointSet, slot_ids: jax.Array) -> DynamicPointSet:
+    """Deactivate points by storage slot id (one program). Already-inactive
+    ids and duplicates (within or across calls) are no-ops: the weight and
+    count decrements are masked by ``active`` and a first-occurrence
+    filter, so tree counters stay consistent with storage."""
+    valid = jnp.ones(slot_ids.shape, bool)
+    return _delete_rows(dps, slot_ids, valid)[0]
 
 
-def _bump_counts(
-    tree: LinearKdTree,
-    leaf_ids: jax.Array,
-    wts: jax.Array,
-    sign: int,
-    counts: jax.Array | None = None,
-) -> LinearKdTree:
-    """Add +-(count, weight) along all root→leaf paths (vectorized over the
-    batch, one scatter-add per level). ``counts`` overrides the default
-    count delta of 1 per id (used to mask no-op deletes)."""
-    count, weight = tree.count, tree.weight
-    node = leaf_ids
-    ones = (jnp.ones_like(leaf_ids) if counts is None else counts) * sign
-    swts = wts * sign
-    for _ in range(tree.max_depth + 1):
-        count = count.at[node].add(ones)
-        weight = weight.at[node].add(swts)
-        done = node == 0
-        node = jnp.where(done, -1, (node - 1) // 2)  # -1 scatters are dropped
-        ones = jnp.where(done, 0, ones)
-        swts = jnp.where(done, 0.0, swts)
-    # after reaching the root, node becomes -1 (wraps to the last node) but
-    # the added values are zeroed, so the wrapped scatters are no-ops
-    return tree._replace(count=count, weight=weight)
+def _tree_add(tree: LinearKdTree, leaf_ids, counts, wts) -> LinearKdTree:
+    """Add per-row (count, weight) deltas to the subtree counters of every
+    node on each row's root→leaf path: one scatter of the rows onto their
+    leaves, then the dense bottom-up pass of ``recount``. Rows with a leaf
+    id of ``num_nodes`` (padding, no-ops) are dropped."""
+    M = tree.num_nodes
+    dc = jnp.zeros((M,), jnp.int32).at[leaf_ids].add(counts, mode="drop")
+    dw = jnp.zeros((M,), jnp.float32).at[leaf_ids].add(wts, mode="drop")
+    dc, dw = _subtree_sums(dc, dw, tree.max_depth)
+    return tree._replace(count=tree.count + dc, weight=tree.weight + dw)
+
+
+def _subtree_sums(cnt: jax.Array, wt: jax.Array, max_depth: int):
+    """Per-node (count, weight) to subtree sums, bottom-up over the
+    heap's levels: each level adds its children's (already summed)
+    values."""
+    for level in range(max_depth - 1, -1, -1):
+        start, end = (1 << level) - 1, (1 << (level + 1)) - 1
+        kids_c, kids_w = cnt[end:2 * end + 1], wt[end:2 * end + 1]
+        cnt = cnt.at[start:end].add(kids_c[0::2] + kids_c[1::2])
+        wt = wt.at[start:end].add(kids_w[0::2] + kids_w[1::2])
+    return cnt, wt
 
 
 # ---------------------------------------------------------------------------
@@ -195,14 +225,7 @@ def recount(dps: DynamicPointSet) -> DynamicPointSet:
     leaf_wt = jax.ops.segment_sum(
         jnp.where(dps.active, dps.weights, 0.0), dps.leaf_id, num_segments=M
     )
-    cnt, wt = leaf_cnt, leaf_wt
-    for level in range(tree.max_depth - 1, -1, -1):
-        start, end = (1 << level) - 1, (1 << (level + 1)) - 1
-        child_lo = 2 * jnp.arange(start, end) + 1
-        add_c = cnt[child_lo] + cnt[child_lo + 1]
-        add_w = wt[child_lo] + wt[child_lo + 1]
-        cnt = cnt.at[start:end].add(add_c)
-        wt = wt.at[start:end].add(add_w)
+    cnt, wt = _subtree_sums(leaf_cnt, leaf_wt, tree.max_depth)
     return dps._replace(tree=tree._replace(count=cnt, weight=wt))
 
 

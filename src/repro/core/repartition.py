@@ -12,8 +12,9 @@ change                       work done
 weights only                 re-slice the cached curve (no key-gen,
                              no sort, no tree work)
 insert / delete points       key-gen for the delta batch only, re-sort
-                             cached keys, re-slice; kd-tree updated via
-                             ``dynamic.insert``/``delete`` bumps
+                             cached keys, re-slice; kd-tree updated by
+                             one compiled program per call (tree mode:
+                             with the bucket-summary delta)
 credit exhaustion            full rebuild: ``dynamic.adjustments``
                              (Alg. 1), fresh quantization frame, fresh
                              keys (Alg. 3 decides *when*)
@@ -175,10 +176,76 @@ def _live_loads_kernel(part, dps, num_parts):
     return loads, _dyn.num_buckets(dps)
 
 
+def _summary_delta(s, is_leaf, pts, wts, leaf_ids, counts, sign: int):
+    """The bucket summaries after a delta of rows (``leaf_ids`` of
+    ``num_nodes`` are dropped): count/weight/centroid exact, bboxes grown
+    on insert only (re-tightened at the next rebuild; a loose bbox never
+    mis-keys a bucket, keys come from centroids)."""
+    ones = counts * sign
+    cnt = s.count.at[leaf_ids].add(ones, mode="drop")
+    wsum = s.weight.at[leaf_ids].add(jnp.float32(sign) * wts, mode="drop")
+    csum = s.centroid * s.count[:, None].astype(jnp.float32)
+    csum = csum.at[leaf_ids].add(
+        jnp.float32(sign) * pts * jnp.abs(ones)[:, None].astype(jnp.float32), mode="drop"
+    )
+    centroid = csum / jnp.maximum(cnt[:, None].astype(jnp.float32), 1.0)
+    lo, hi = s.bbox_lo, s.bbox_hi
+    if sign > 0:
+        lo = lo.at[leaf_ids].min(pts, mode="drop")
+        hi = hi.at[leaf_ids].max(pts, mode="drop")
+    return _kdtree.BucketSummary(
+        count=cnt, weight=wsum, centroid=centroid, bbox_lo=lo, bbox_hi=hi,
+        is_bucket=is_leaf & (cnt > 0),
+    )
+
+
 @jax.jit
-def _add_applied(total, counts):
-    """Running device count of applied summary deltas (no host read)."""
-    return total + jnp.sum(counts)
+def _delete_program(dps, summary, slot_ids, n, unread):
+    """One delete: the first ``n`` of the padded ``slot_ids`` leave the
+    store and the tree; in tree mode their buckets' summaries lose them
+    and ``unread`` (the device sum of applied summary entries) gains the
+    count removed. Reads nothing back."""
+    valid = jnp.arange(slot_ids.shape[0]) < n
+    out, removed, leaf, wts = _dyn._delete_rows(dps, slot_ids, valid)
+    if summary is None:
+        return out, None, None
+    summary = _summary_delta(
+        summary, out.tree.is_leaf, dps.points[slot_ids], wts, leaf,
+        removed.astype(jnp.int32), sign=-1,
+    )
+    return out, summary, unread + jnp.sum(removed, dtype=jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("walk",))
+def _insert_program(dps, summary, pts, wts, n, walk):
+    """One insert: the first ``n`` rows of the padded batch go to the
+    lowest free slots, into the tree, and in tree mode into their
+    buckets' summaries. Returns the new state and each row's slot.
+    ``walk`` is ``dynamic.locate`` as it stands at the call, so a
+    substitute of it compiles a program of its own."""
+    valid = jnp.arange(pts.shape[0]) < n
+    out, slot, leaf = _dyn._insert_rows(dps, pts, wts, valid, walk)
+    if summary is not None:
+        summary = _summary_delta(
+            summary, out.tree.is_leaf, pts, wts, leaf,
+            (leaf < out.tree.num_nodes).astype(jnp.int32), sign=+1,
+        )
+    return out, summary, slot
+
+
+def _pad_rows(x, rows: int, fill, dtype):
+    """``x`` as ``dtype`` with its leading axis padded to ``rows`` with
+    ``fill``: on the host for a host array, else one device op."""
+    if isinstance(x, jax.Array):
+        x = x.astype(dtype)
+        pad = jnp.pad
+    else:
+        x = np.asarray(x, dtype)
+        pad = np.pad
+    k = x.shape[0]
+    if k == rows:
+        return x
+    return pad(x, [(0, rows - k)] + [(0, 0)] * (x.ndim - 1), constant_values=fill)
 
 
 @functools.partial(jax.jit, static_argnames=("num_parts",))
@@ -221,6 +288,12 @@ class RepartitionStats:
     that made it. A plain tree-mode ``step()`` makes 3 (the imbalance
     fallback's (P,) loads with the bucket count, the part loads, the
     (P, P) migration counts); ``delete`` none, ``insert`` one.
+
+    Every ``insert``/``delete`` runs as one compiled program over its
+    batch padded to ``dynamic.padded_rows``: ``delta_programs`` counts
+    those calls, ``delta_pad_rows`` the padding rows they ran, and
+    ``delta_sizes`` holds the padded sizes seen (each compiles the
+    insert and/or delete program once).
     """
 
     rebuilds: int = 0
@@ -241,6 +314,9 @@ class RepartitionStats:
     resizes: int = 0
     host_syncs: int = 0
     host_pull_bytes: int = 0
+    delta_programs: int = 0
+    delta_pad_rows: int = 0
+    delta_sizes: set = field(default_factory=set)
     history: list = field(default_factory=list)
     # summary refreshes: a host count, plus the deletes' applied entries
     # summed on the device (int32, at most ``_unread_bound``) until read
@@ -256,19 +332,19 @@ class RepartitionStats:
         self._fold_refreshes()
         return self._refreshes
 
-    def _add_refreshes(self, counts: jax.Array | None, k: int) -> None:
-        """Count the refreshes of a ``k``-entry delta: all ``k`` when
-        ``counts`` is None, else the sum of the 0/1 ``counts``, added on
-        the device without a read (one program, whatever came before)."""
-        if counts is None:
-            self._refreshes += k
-            return
+    def _unread_sum(self, k: int):
+        """The device sum a ``k``-entry delete adds its applied count to
+        (in its own program), folded into the host count first if the
+        addition could pass int32."""
         if self._unread_bound + k > _INT32_MAX:
             self._fold_refreshes()
-        if self._unread is None:
-            self._unread = jnp.zeros((), jnp.int32)
-        self._unread = _add_applied(self._unread, counts)
         self._unread_bound += k
+        return np.zeros((), np.int32) if self._unread is None else self._unread
+
+    def _count_delta(self, k: int, rows: int) -> None:
+        self.delta_programs += 1
+        self.delta_pad_rows += rows - k
+        self.delta_sizes.add(rows)
 
     def _fold_refreshes(self) -> None:
         if self._unread is not None:
@@ -587,16 +663,21 @@ class Repartitioner:
                     f"insert of {k} points exceeds free capacity {n_free}; "
                     f"grow the Repartitioner (capacity={self.capacity})"
                 )
-            free = jnp.nonzero(~self.dps.active, size=k, fill_value=self.capacity - 1)[0]
-            self.dps = _dyn.insert(self.dps, points, weights)
+            rows = _dyn.padded_rows(k)
+            self.stats._count_delta(k, rows)
+            # bucket substrate: the located leaves are the only dirtied
+            # summaries, refreshed in the same program; no key-gen, no
+            # resort (there is no per-point key array to maintain)
+            self.dps, summary, free = _insert_program(
+                self.dps, self._summary if self.tree_mode else None,
+                _pad_rows(points, rows, 0.0, np.float32),
+                _pad_rows(weights, rows, 0.0, np.float32), np.int32(k),
+                walk=_dyn.locate,
+            )
+            free = free if k == rows else free[:k]
             if self.tree_mode:
-                # bucket substrate: the located leaves are the only dirtied
-                # summaries — refresh them by delta scatter; no key-gen, no
-                # resort (there is no per-point key array to maintain)
-                self._summary_apply_delta(
-                    points, jnp.asarray(weights, jnp.float32),
-                    self.dps.leaf_id[free], sign=+1,
-                )
+                self._summary = summary
+                self.stats._refreshes += k
                 self._index_version += 1
             else:
                 self._keys = self._keys.at[free].set(self._keys_in_frame(points))
@@ -606,20 +687,21 @@ class Repartitioner:
 
     def delete(self, slot_ids: jax.Array) -> None:
         with TraceAnnotation("repartition.delete"):
-            slot_ids = jnp.asarray(slot_ids)
-            # first-occurrence live slots only — the exact mask dynamic.delete
-            # applies, so summary deltas track tree counters; computed once
-            # and handed down
-            removed = self.dps.active[slot_ids] & _dyn.first_occurrence_mask(slot_ids)
-            self.dps = _dyn.delete(self.dps, slot_ids, removed=removed)
+            k = len(slot_ids)
+            rows = _dyn.padded_rows(k)
+            self.stats._count_delta(k, rows)
+            # the summary entries applied are the first-occurrence live
+            # slots that the tree counters lose: summed on the device, unread
+            self.dps, summary, unread = _delete_program(
+                self.dps, self._summary if self.tree_mode else None,
+                _pad_rows(slot_ids, rows, self.capacity, np.int32), np.int32(k),
+                self.stats._unread_sum(k) if self.tree_mode else None,
+            )
             if self.tree_mode:
-                w = jnp.where(removed, self.dps.weights[slot_ids], 0.0)
-                self._summary_apply_delta(
-                    self.dps.points[slot_ids], w, self.dps.leaf_id[slot_ids],
-                    sign=-1, counts=removed.astype(jnp.int32),
-                )
+                self._summary, self.stats._unread = summary, unread
                 self._index_version += 1
             else:
+                slot_ids = jnp.asarray(slot_ids)
                 self._keys = self._keys.at[slot_ids].set(jnp.uint32(KEY_SENTINEL))
                 self._resort()
             self.topology_version += 1
@@ -646,48 +728,6 @@ class Repartitioner:
             curve=self.cfg.curve,
         )
         self.stats.keygen_buckets += int(self._pull(self._border.num_buckets))
-
-    def _summary_apply_delta(
-        self,
-        pts: jax.Array,
-        wts: jax.Array,
-        leaf_ids: jax.Array,
-        sign: int,
-        counts: jax.Array | None = None,
-    ) -> None:
-        """Refresh ONLY the dirtied bucket summaries (O(delta) scatters).
-
-        Count/weight/centroid are exact; bboxes grow on insert and are
-        only re-tightened at the next rebuild (a stale-loose bbox never
-        mis-keys a bucket — keys are regenerated from centroids at
-        rebuild time). Bucket keys and the curve order are untouched:
-        membership deltas do not move buckets on the curve.
-        """
-        s = self._summary
-        ones = (jnp.ones_like(leaf_ids) if counts is None else counts) * sign
-        cnt = s.count.at[leaf_ids].add(ones)
-        wsum = s.weight.at[leaf_ids].add(jnp.float32(sign) * wts)
-        csum = s.centroid * s.count[:, None].astype(jnp.float32)
-        csum = csum.at[leaf_ids].add(
-            jnp.float32(sign) * pts * (jnp.abs(ones))[:, None].astype(jnp.float32)
-        )
-        centroid = csum / jnp.maximum(cnt[:, None].astype(jnp.float32), 1.0)
-        lo, hi = s.bbox_lo, s.bbox_hi
-        if sign > 0:
-            lo = lo.at[leaf_ids].min(pts)
-            hi = hi.at[leaf_ids].max(pts)
-        self._summary = _kdtree.BucketSummary(
-            count=cnt,
-            weight=wsum,
-            centroid=centroid,
-            bbox_lo=lo,
-            bbox_hi=hi,
-            is_bucket=self.dps.tree.is_leaf & (cnt > 0),
-        )
-        # count entries actually applied (masked no-ops excluded), so the
-        # counter reflects dirtied work, not batch size; an insert applies
-        # its whole batch, a delete's mask is summed on the device unread
-        self.stats._add_refreshes(counts, int(leaf_ids.shape[0]))
 
     def summary(self) -> "_kdtree.BucketSummary":
         """Tree mode: the live per-bucket statistics."""

@@ -352,16 +352,13 @@ class DistributedSim:
             with _span("mesh.engine"):
                 died = self.slots[died_idx]
                 if died.size:
-                    rp.delete(jnp.asarray(died))
+                    rp.delete(died)
                 slots_new = np.full((ev.mesh.n,), -1, np.int64)
                 kept = src0 >= 0
                 slots_new[kept] = self.slots[src0[kept]]
                 born_idx = np.nonzero(~kept)[0]
                 if born_idx.size:
-                    got = rp.insert(
-                        jnp.asarray(ev.mesh.centers()[born_idx]),
-                        jnp.asarray(ev.weights[born_idx]),
-                    )
+                    got = rp.insert(ev.mesh.centers()[born_idx], ev.weights[born_idx])
                     slots_new[born_idx] = np.asarray(got)
                 self.slots = slots_new
             if parent is not None:

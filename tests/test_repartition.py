@@ -3,6 +3,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _delta_reference as ref
 from repro.core import dynamic
 from repro.core.repartition import Repartitioner, _live_loads_kernel
 
@@ -446,3 +447,116 @@ def test_pallas_key_cache_token_roundtrip(rng):
     assert ops.invalidate_key_cache(0) == 1  # token-scoped invalidation
     assert ops.key_cache_stats()["entries"] == 1
     ops.invalidate_key_cache()
+
+
+# --- insert/delete as one compiled program ------------------------------------
+
+CHURN_N = 131072
+
+
+def _big_tree_engine(seed, n, capacity):
+    from repro.core import partitioner as pt
+
+    r = np.random.default_rng(seed)
+    pts = jnp.asarray(r.random((n, 3)), jnp.float32)
+    w = jnp.asarray(0.5 + r.random(n), jnp.float32)
+    cfg = pt.PartitionerConfig(use_tree=True)
+    return r, Repartitioner(pts, w, 8, cfg, capacity=capacity, max_depth=10)
+
+
+def _assert_summary(got, want):
+    for name in ("count", "centroid", "bbox_lo", "bbox_hi", "is_bucket"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                      np.asarray(getattr(want, name)), err_msg=name)
+    np.testing.assert_allclose(np.asarray(got.weight), np.asarray(want.weight),
+                               rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("k", [1, 7, 3000, 65536])
+def test_tree_mode_delta_matches_per_level_reference(k):
+    """Engine insert, then a delete of inserted, live, inactive and repeated
+    slots, each against the reference: store, tree counts and the bucket
+    summaries' count/centroid/bbox exactly, the refresh count as before."""
+    r, rp = _big_tree_engine(k, 81920, 163840)
+    pts = jnp.asarray(r.random((k, 3)), jnp.float32)
+    w = jnp.asarray(0.5 + r.random(k), jnp.float32)
+    before, s0 = rp.dps, rp.summary()
+    refreshes = rp.stats.summary_refreshes
+    want, free, lid = ref.insert(before, pts, w)
+    got = rp.insert(pts, w)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(free))
+    for name in ("points", "weights", "active", "leaf_id"):
+        np.testing.assert_array_equal(np.asarray(getattr(rp.dps, name)),
+                                      np.asarray(getattr(want, name)), err_msg=name)
+    np.testing.assert_array_equal(np.asarray(rp.dps.tree.count), np.asarray(want.tree.count))
+    _assert_summary(rp.summary(), ref.summary_delta(s0, want.tree.is_leaf, pts, w, lid, +1))
+    assert rp.stats.summary_refreshes == refreshes + k
+
+    ids = np.concatenate([np.asarray(got)[: (k + 1) // 2],
+                          r.integers(0, 163840, k).astype(np.int32)])
+    ids[-1] = ids[0]
+    ids = jnp.asarray(ids)
+    before, s1 = rp.dps, rp.summary()
+    want, removed = ref.delete(before, ids)
+    rp.delete(ids)
+    for name in ("active", "leaf_id", "points"):
+        np.testing.assert_array_equal(np.asarray(getattr(rp.dps, name)),
+                                      np.asarray(getattr(want, name)), err_msg=name)
+    np.testing.assert_array_equal(np.asarray(rp.dps.tree.count), np.asarray(want.tree.count))
+    _assert_summary(rp.summary(), ref.summary_delta(
+        s1, want.tree.is_leaf, before.points[ids], jnp.where(removed, before.weights[ids], 0.0),
+        before.leaf_id[ids], -1, counts=removed.astype(jnp.int32)))
+    assert rp.stats.summary_refreshes == refreshes + k + int(removed.sum())
+
+
+def test_padding_rows_touch_no_slot_or_node():
+    """A 3,000-row delete and insert run 3,072 rows: the 72 padding rows
+    leave the last slot (live at the delete, free at the insert) and every
+    node as the unpadded batch leaves them."""
+    from repro.core.dynamic import padded_rows
+
+    r, rp = _big_tree_engine(5, 4096, 8192)
+    k = 3000
+    assert padded_rows(k) == 3072
+    live = jnp.asarray(r.choice(4096, k, replace=False).astype(np.int32))
+    want = dynamic.delete(rp.dps, live)
+    rp.delete(live)
+    assert rp.stats.delta_pad_rows == 72
+    for name in ("active", "points", "weights", "leaf_id"):
+        np.testing.assert_array_equal(np.asarray(getattr(rp.dps, name)),
+                                      np.asarray(getattr(want, name)), err_msg=name)
+    for name in ("count", "weight"):
+        np.testing.assert_array_equal(np.asarray(getattr(rp.dps.tree, name)),
+                                      np.asarray(getattr(want.tree, name)), err_msg=name)
+
+    # 4096 - 3000 live, so 7096 free slots: the padding rows would take
+    # free slots up to 4096 + 3000 + 72 if they were not masked
+    pts = jnp.asarray(r.random((k, 3)), jnp.float32)
+    w = jnp.asarray(0.5 + r.random(k), jnp.float32)
+    want = dynamic.insert(rp.dps, pts, w)
+    rp.insert(pts, w)
+    assert rp.stats.delta_pad_rows == 144
+    assert int(rp.dps.active.sum()) == 4096
+    assert not bool(rp.dps.active[-1]) and float(rp.dps.weights[-1]) == 0.0
+    for name in ("active", "points", "weights", "leaf_id"):
+        np.testing.assert_array_equal(np.asarray(getattr(rp.dps, name)),
+                                      np.asarray(getattr(want, name)), err_msg=name)
+    for name in ("count", "weight"):
+        np.testing.assert_array_equal(np.asarray(getattr(rp.dps.tree, name)),
+                                      np.asarray(getattr(want.tree, name)), err_msg=name)
+
+
+def test_repeated_churn_compiles_one_size():
+    """Eight 65,536-row churns of a full store: one padded size, no
+    padding, two programs a churn, and the store stays consistent."""
+    r, rp = _big_tree_engine(6, CHURN_N, CHURN_N)
+    sizes = set(rp.stats.delta_sizes)
+    for _ in range(8):
+        slots = jnp.asarray(np.sort(r.choice(CHURN_N, 65536, replace=False)).astype(np.int32))
+        rp.delete(slots)
+        got = rp.insert(jnp.asarray(r.random((65536, 3)), jnp.float32),
+                        jnp.ones(65536, jnp.float32))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(slots))
+    assert rp.stats.delta_sizes - sizes == {65536} and len(rp.stats.delta_sizes) == len(sizes) + 1
+    assert rp.stats.delta_pad_rows == 0 and rp.stats.delta_programs == 16
+    assert int(rp.dps.tree.count[0]) == CHURN_N == int(rp.dps.active.sum())
