@@ -81,11 +81,9 @@ def main() -> None:
         sys.exit(compare_artifacts(paths[0], paths[1] if len(paths) > 1 else None))
     from benchmarks import (
         bench_hierarchy,
-        bench_mesh,
         bench_moe,
         bench_particles,
         bench_partitioner,
-        bench_plans,
         bench_spmv,
     )
 
@@ -98,9 +96,7 @@ def main() -> None:
         ("queries (Figs 12-13)", bench_partitioner.bench_queries),
         ("incremental LB (SIV)", bench_partitioner.bench_migration),
         ("hierarchical reslice (nodes x devices)", bench_hierarchy.bench_hierarchy_rows),
-        ("AMR mesh stencil loop (SI, SIV)", bench_mesh.bench_mesh_rows),
         ("particle N-body + coupled PIC (SV-C)", bench_particles.bench_particles_rows),
-        ("plan construction (vectorized vs legacy)", bench_plans.bench_plans_rows),
         ("spmv tables (Tables II-VII)", bench_spmv.bench_spmv_tables),
         ("spmv execution", bench_spmv.bench_spmv_execution),
         ("moe dispatch (DESIGN S3)", bench_moe.bench_moe_dispatch),

@@ -26,7 +26,7 @@ same discipline `kernels.stencil_update` established: a ``jnp.sum``
 lowers to an XLA Reduce whose accumulation order is chosen per fusion
 context, while a fixed add chain is ordinary float arithmetic XLA must
 not reassociate. Every caller — single-device reference integrator,
-interior/boundary distributed executor, Pallas kernel — produces
+distributed executor, Pallas kernel — produces
 identical bits by construction, which is what the particle drivers gate
 on (``np.array_equal`` across repartition events).
 """

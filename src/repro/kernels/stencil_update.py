@@ -15,11 +15,11 @@ elementwise adds in ascending k. The unroll is load-bearing: a
 ``jnp.sum(axis=-1)`` lowers to an XLA Reduce whose accumulation order
 is an implementation choice made per fusion context, so two programs
 computing "the same" row can disagree in the last ulp (observed on
-CPU: a standalone reduce vectorizes, the same reduce inside the
-overlapped stencil executor runs sequentially). A fixed add chain is
+CPU: a standalone reduce vectorizes, the same reduce inside a
+distributed stencil executor runs sequentially). A fixed add chain is
 ordinary float arithmetic XLA must not reassociate, so every caller —
-reference executor, pre-split baseline, overlapped executor, Pallas
-kernel — produces identical bits by construction. The distributed
+reference executor, distributed executor, Pallas kernel — produces
+identical bits by construction. The distributed
 stencil gates on this (``np.array_equal`` against the single-device
 reference across repartition events). The construction is the form of
 the chain, not its algebra: XLA's CPU backend contracts a multiply and

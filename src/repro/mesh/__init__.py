@@ -15,9 +15,7 @@ from repro.mesh.halo import (  # noqa: F401
     HaloPlan,
     MovePlan,
     build_halo_plan,
-    build_halo_plan_legacy,
     build_move_plan,
-    build_move_plan_legacy,
     owners_from_index,
     plan_quality_metrics,
 )

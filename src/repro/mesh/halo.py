@@ -21,11 +21,6 @@ Two plan flavors, mirroring PR 4's two-level machinery:
   the node — node-local ghosts never cross the inter-node boundary, by
   construction.
 
-Each plan also compiles the *interior/boundary split* of the owned
-rows (fixed-shape index sets): interior rows have no ghost neighbors,
-so the executors can update them while the exchange collectives are in
-flight and apply only the boundary rows after the recv lands.
-
 Ghost *ownership* is resolved against the ``CurveIndex`` directory
 (:func:`owners_from_index`): a face neighbor's key is looked up in the
 O(B) bucket directory and the bucket's part is read off — the same
@@ -42,7 +37,7 @@ pays — with `repro.core.migration` providing the level-aware accounting.
 **Plan cost is a hot-path cost.** Plans are rebuilt on every
 repartition event, so host-side construction bounds how *dynamic* a
 dynamic workload can be (the paper's "minimal partitioning cost"
-requirement). The default builders therefore contain **zero per-part
+requirement). The builders therefore contain **zero per-part
 and zero per-cell Python loops**: every table is produced by numpy
 segment operations — one ``lexsort`` over (part, slot) defines the
 owned layout, sorted-run ranks fill the lane tables, ``searchsorted``
@@ -50,12 +45,10 @@ over a packed (part, slot-rank) key replaces the per-part ghost
 position dicts, and the hop-A dedup is a sorted-unique over
 (owner, dest-node, cell). The canonical ascending-slot ordering makes
 the output a pure function of ``(slot, part, nbr, coeff)``, so the
-vectorized builders are **bit-identical** to the straightforward
-per-part reference builders (:func:`build_halo_plan_legacy`,
-:func:`build_move_plan_legacy`), which are kept as the equivalence-test
-oracle and the ``benchmarks/bench_plans.py`` baseline. Every builder
-records its own walltime as ``PlanBuildSeconds`` in ``plan.metrics``;
-``with_metrics=False`` skips the O(n*K) partition-quality pass
+builders are **bit-identical** to straightforward per-part loop
+builders (the equivalence-test oracle in ``tests/_plan_reference.py``).
+Every builder records its own walltime as ``PlanBuildSeconds`` in
+``plan.metrics``; ``with_metrics=False`` skips the O(n*K) quality pass
 (``partition_report`` over the face-edge list) for hot-loop callers
 that do not read it — the returned index tables are identical either
 way (:func:`plan_quality_metrics` recovers the skipped report).
@@ -78,27 +71,6 @@ GID_SENTINEL = np.int32(2**31 - 1)
 def _roundup(x: int, q: int = 8) -> int:
     """Round capacities up so nearby plans share compiled executors."""
     return max(q, ((int(x) + q - 1) // q) * q)
-
-
-class _ProfTimer:
-    """Accumulates per-stage build walltime into a caller-owned dict.
-
-    A no-op when ``sink`` is None, so the hot path pays one branch per
-    section. Keys accumulate, so patched and scratch sections of one
-    bench run can share a sink."""
-
-    __slots__ = ("sink", "t")
-
-    def __init__(self, sink):
-        self.sink = sink
-        self.t = time.perf_counter() if sink is not None else 0.0
-
-    def mark(self, key):
-        if self.sink is None:
-            return
-        now = time.perf_counter()
-        self.sink[key] = self.sink.get(key, 0.0) + (now - self.t)
-        self.t = now
 
 
 @dataclass(frozen=True)
@@ -138,15 +110,6 @@ class HaloPlan:
     coeff: np.ndarray              # (S, cap, K) float32
     stages: tuple[Stage, ...]      # value-routing hops
     ghost_fetch: np.ndarray        # (S, gcap) int32 into final recv, -1 pad
-    # interior/boundary split of the owned rows, compiled into the plan:
-    # a row is *interior* iff every valid neighbor slot points below
-    # ``cap`` (owned by the same device), so its update is provably
-    # independent of the ghost exchange; *boundary* rows read at least
-    # one ghost. The sets partition the real owned rows (-1 pads) and
-    # let the executor update interior cells while the exchange is in
-    # flight, applying boundary rows only after the recv lands.
-    interior_idx: np.ndarray = None  # (S, icap) int32 local row, -1 pad
-    boundary_idx: np.ndarray = None  # (S, bcap) int32 local row, -1 pad
     metrics: dict = field(default_factory=dict)
 
     @property
@@ -176,7 +139,6 @@ class HaloPlan:
     def caps(self) -> dict:
         """The padded sizes that shape the compiled executors."""
         return {"cap": self.cap, "gcap": self.gcap,
-                "icap": self.interior_idx.shape[1], "bcap": self.boundary_idx.shape[1],
                 "stages": tuple(s.cap for s in self.stages)}
 
     def padded(self, caps: dict) -> "HaloPlan":
@@ -202,8 +164,6 @@ class HaloPlan:
             owned_idx=owned_idx, owned_slot=owned_slot, nbr_local=nbr_local,
             nbr_valid=nbr_valid, coeff=coeff, stages=stages,
             ghost_fetch=_pad_last(fetch, caps["gcap"], -1),
-            interior_idx=_pad_last(self.interior_idx, caps["icap"], -1),
-            boundary_idx=_pad_last(self.boundary_idx, caps["bcap"], -1),
             metrics=self.metrics,
         )
 
@@ -254,13 +214,12 @@ def layout_plan(slot: np.ndarray, part: np.ndarray, *, hierarchy=None,
     owned_slot = np.full((S * cap,), -1, np.int64)
     owned_idx[drow] = ocells
     owned_slot[drow] = slot[ocells]
-    none = np.full((S, 1), -1, np.int32)
     return HaloPlan(
         axes=axes, num_parts=S, cap=cap, gcap=1, K=0,
         owned_idx=owned_idx.reshape(S, cap), owned_slot=owned_slot.reshape(S, cap),
         nbr_local=np.zeros((S, cap, 0), np.int32), nbr_valid=np.zeros((S, cap, 0), bool),
-        coeff=np.zeros((S, cap, 0), np.float32), stages=(), ghost_fetch=none,
-        interior_idx=none, boundary_idx=none,
+        coeff=np.zeros((S, cap, 0), np.float32), stages=(),
+        ghost_fetch=np.full((S, 1), -1, np.int32),
     )
 
 
@@ -291,7 +250,7 @@ def owners_from_index(index, part_by_slot: np.ndarray, centers) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 def _plan_shape(part, hierarchy, num_parts, device_axis):
-    """Resolve (N, D, S, axes) — shared by both builder implementations."""
+    """Resolve (N, D, S, axes) of a plan over ``hierarchy`` or a flat axis."""
     if hierarchy is not None and hierarchy.num_nodes > 1:
         N, D = int(hierarchy.num_nodes), int(hierarchy.devices_per_node)
         axes = (hierarchy.node_axis, hierarchy.device_axis)
@@ -332,7 +291,7 @@ def plan_quality_metrics(part, nbr, num_parts, weights=None) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# plan construction — vectorized (the default builder)
+# plan construction
 # ---------------------------------------------------------------------------
 
 def build_halo_plan(
@@ -348,7 +307,6 @@ def build_halo_plan(
     with_metrics: bool = True,
     cache=None,
     topo_token=None,
-    profile: dict | None = None,
     _topo=None,
     _capture: dict | None = None,
 ) -> HaloPlan:
@@ -372,13 +330,11 @@ def build_halo_plan(
     output is bit-identical to the from-scratch build (see
     ``plan_cache``). ``topo_token`` keys the cached topology state (pass
     the engine's ``topology_version``); a changed token forces a
-    topology refresh. ``profile`` (a dict) accumulates per-stage build
-    seconds. ``_topo``/``_capture`` are the cache's private handshake
-    with the scratch builder.
+    topology refresh. ``_topo``/``_capture`` are the cache's private
+    handshake with the scratch builder.
 
     The construction is pure numpy segment ops (no per-part or per-cell
-    Python loops) and is bit-identical to
-    :func:`build_halo_plan_legacy`, the per-part reference builder.
+    Python loops).
     """
     if cache is not None:
         from repro.mesh import plan_cache as _plan_cache
@@ -386,10 +342,9 @@ def build_halo_plan(
         return _plan_cache.cached_build_halo_plan(
             cache, slot, part, nbr, coeff, hierarchy=hierarchy,
             num_parts=num_parts, device_axis=device_axis, weights=weights,
-            with_metrics=with_metrics, topo_token=topo_token, profile=profile,
+            with_metrics=with_metrics, topo_token=topo_token,
         )
     t_build = time.perf_counter()
-    prof = _ProfTimer(profile)
     slot = np.asarray(slot, np.int64)
     part = np.asarray(part)
     n, K = nbr.shape
@@ -412,7 +367,6 @@ def build_halo_plan(
         nbc = np.where(valid, nbr, 0).astype(np.int64)
         if _capture is not None:
             _capture["sorder"] = sorder
-    prof.mark("slot_sort_s")
 
     # --- owned layout: one lexsort over (part, slot) -----------------------
     ocells = np.lexsort((slot, part64))            # cells by (part, slot)
@@ -422,14 +376,12 @@ def build_halo_plan(
     orank = np.arange(n, dtype=np.int64) - ostarts[oprow]
     local_pos = np.empty((n,), np.int64)
     local_pos[ocells] = orank
-    prof.mark("owned_lexsort_s")
 
     # one (n, K) gather of the neighbor's owner, shared by the ghost
     # pass and the stencil tables (the dominant cost at ~1M cells)
     pn = part64[nbc]                                # neighbor's owner
     same = valid & (pn == part64[:, None])
     other = valid & ~same                           # ghost-reading lanes
-    prof.mark("gather_s")
 
     # --- ghost sets: cross-part face pairs, deduped per (part, slot) ------
     grow, gcol = np.nonzero(other)
@@ -444,7 +396,6 @@ def build_halo_plan(
     gcounts = np.bincount(gp, minlength=S)
     gstarts = np.concatenate(([0], np.cumsum(gcounts)))
     grank = np.arange(gp.size, dtype=np.int64) - gstarts[gp]
-    prof.mark("ghost_dedup_s")
 
     cap = _roundup(int(ocounts.max()) if n else 0)
     gcap = _roundup(max(int(gcounts.max()) if gcounts.size else 0, 1))
@@ -475,28 +426,6 @@ def build_halo_plan(
     nbr_valid = nbr_valid.reshape(S, cap, K)
     coeff_l = coeff_l.reshape(S, cap, K)
 
-    # --- interior/boundary split -------------------------------------------
-    # a row reads a ghost iff any of its lanes is an `other` lane (valid
-    # neighbor owned elsewhere — exactly the lanes with loc >= cap);
-    # rows beyond the owned count belong to neither set
-    reads_ghost = np.zeros((S * cap,), bool)
-    reads_ghost[drow] = other.any(axis=1)
-    reads_ghost = reads_ghost.reshape(S, cap)
-    real = owned_idx >= 0
-    pi, ri = np.nonzero(real & ~reads_ghost)        # row-major: part, then row
-    pb, rb = np.nonzero(real & reads_ghost)
-    icounts = np.bincount(pi, minlength=S)
-    bcounts = np.bincount(pb, minlength=S)
-    icap = _roundup(max(int(icounts.max()) if icounts.size else 0, 1))
-    bcap = _roundup(max(int(bcounts.max()) if bcounts.size else 0, 1))
-    istarts = np.concatenate(([0], np.cumsum(icounts)))
-    bstarts = np.concatenate(([0], np.cumsum(bcounts)))
-    interior_idx = np.full((S, icap), -1, np.int32)
-    boundary_idx = np.full((S, bcap), -1, np.int32)
-    interior_idx[pi, np.arange(pi.size) - istarts[pi]] = ri
-    boundary_idx[pb, np.arange(pb.size) - bstarts[pb]] = rb
-    prof.mark("tables_s")
-
     # --- routing stages ----------------------------------------------------
     if N == 1:
         stages, ghost_fetch = _flat_stages_vec(
@@ -506,23 +435,19 @@ def build_halo_plan(
         stages, ghost_fetch = _two_hop_stages_vec(
             axes, N, D, n, gp, gc, gr, grank, part64, local_pos, gcap
         )
-    prof.mark("stage_pack_s")
 
     mets = _halo_metrics_vec(
         part, nbr, ocounts, gcounts, gp, gc, D, stages, weights,
         with_quality=with_metrics,
     )
-    mets["InteriorCells"] = int(pi.size)
-    mets["BoundaryCells"] = int(pb.size)
+    _row_counts(mets, other)
     mets["PlanBuildSeconds"] = time.perf_counter() - t_build
-    prof.mark("metrics_s")
     if _capture is not None:
         _capture.update(
             part64=part64, srank=srank, valid=valid, nbc=nbc,
             ocells=ocells, okey=oprow * n + srank[ocells],
             ocounts=ocounts, local_pos=local_pos, same=same, other=other,
-            gp=gp, gc=gc, gr=gr, gcounts=gcounts,
-            reads_ghost=reads_ghost, cap=cap, gcap=gcap,
+            gp=gp, gc=gc, gr=gr, gcounts=gcounts, cap=cap, gcap=gcap,
         )
     return HaloPlan(
         axes=axes,
@@ -537,10 +462,16 @@ def build_halo_plan(
         coeff=coeff_l,
         stages=stages,
         ghost_fetch=ghost_fetch,
-        interior_idx=interior_idx,
-        boundary_idx=boundary_idx,
         metrics=mets,
     )
+
+
+def _row_counts(mets: dict, other: np.ndarray) -> None:
+    """``BoundaryCells``: owned rows with a ghost neighbor (a row of the
+    (n, K) ``other`` lane flags set); ``InteriorCells``: the rest."""
+    bnd = int(other.any(axis=1).sum())
+    mets["InteriorCells"] = other.shape[0] - bnd
+    mets["BoundaryCells"] = bnd
 
 
 def _flat_stages_vec(axis, S, n, gp, gc, gr, grank, part64, local_pos, gcap):
@@ -639,255 +570,6 @@ def _halo_metrics_vec(
 
 
 # ---------------------------------------------------------------------------
-# plan construction — per-part reference (oracle + bench baseline)
-# ---------------------------------------------------------------------------
-
-def _owned_layout(slot: np.ndarray, part: np.ndarray, num_parts: int):
-    """Per-part owned cell lists in ascending-slot order + local position
-    of every cell on its owner."""
-    n = slot.shape[0]
-    owned = []
-    local_pos = np.full((n,), -1, np.int64)
-    for p in range(num_parts):
-        cells = np.nonzero(part == p)[0]
-        cells = cells[np.argsort(slot[cells], kind="stable")]
-        owned.append(cells)
-        local_pos[cells] = np.arange(cells.size)
-    return owned, local_pos
-
-
-def _ghost_sets(owned, part: np.ndarray, nbr: np.ndarray, slot: np.ndarray, num_parts: int):
-    """Per-part ghost cell lists (ascending slot): cells owned elsewhere
-    that neighbor at least one owned cell."""
-    ghosts = []
-    for p in range(num_parts):
-        nb = nbr[owned[p]]
-        cand = np.unique(nb[nb >= 0])
-        g = cand[part[cand] != p]
-        ghosts.append(g[np.argsort(slot[g], kind="stable")])
-    return ghosts
-
-
-def build_halo_plan_legacy(
-    slot: np.ndarray,
-    part: np.ndarray,
-    nbr: np.ndarray,
-    coeff: np.ndarray,
-    *,
-    hierarchy=None,
-    num_parts: int | None = None,
-    device_axis: str = "device",
-    weights: np.ndarray | None = None,
-    with_metrics: bool = True,
-) -> HaloPlan:
-    """Per-part reference implementation of :func:`build_halo_plan`.
-
-    Straight-line Python loops over parts/cells — O(parts * cells) host
-    work per event. Kept as the equivalence-test oracle (the vectorized
-    builder must reproduce its output bit-for-bit) and as the
-    ``bench_plans`` baseline; do not use on the hot path.
-    """
-    t_build = time.perf_counter()
-    slot = np.asarray(slot, np.int64)
-    part = np.asarray(part)
-    n, K = nbr.shape
-    N, D, S, axes = _plan_shape(part, hierarchy, num_parts, device_axis)
-
-    owned, local_pos = _owned_layout(slot, part, S)
-    ghosts = _ghost_sets(owned, part, nbr, slot, S)
-    cap = _roundup(max(o.size for o in owned))
-    gcap = _roundup(max(max(g.size for g in ghosts), 1))
-
-    owned_idx = np.full((S, cap), -1, np.int32)
-    owned_slot = np.full((S, cap), -1, np.int64)
-    for p in range(S):
-        owned_idx[p, : owned[p].size] = owned[p]
-        owned_slot[p, : owned[p].size] = slot[owned[p]]
-
-    # local stencil tables: neighbor j of owned cell -> local position in
-    # [u_own (cap) | ghosts (gcap)]
-    ghost_pos = [
-        {int(c): i for i, c in enumerate(g)} for g in ghosts
-    ]
-    nbr_local = np.zeros((S, cap, K), np.int32)
-    nbr_valid = np.zeros((S, cap, K), bool)
-    coeff_l = np.zeros((S, cap, K), np.float32)
-    for p in range(S):
-        cells = owned[p]
-        nb = nbr[cells]
-        coeff_l[p, : cells.size] = coeff[cells]
-        valid = nb >= 0
-        nbr_valid[p, : cells.size] = valid
-        loc = np.zeros_like(nb, dtype=np.int64)
-        same = valid & (part[np.maximum(nb, 0)] == p)
-        loc[same] = local_pos[nb[same]]
-        other = valid & ~same
-        if other.any():
-            gp = ghost_pos[p]
-            loc[other] = np.array([cap + gp[int(c)] for c in nb[other]], np.int64)
-        nbr_local[p, : cells.size] = np.where(valid, loc, 0)
-
-    # interior/boundary split (see build_halo_plan for the invariant)
-    reads_ghost = (nbr_valid & (nbr_local >= cap)).any(axis=2)  # (S, cap)
-    real = owned_idx >= 0
-    int_lists = [np.flatnonzero(real[p] & ~reads_ghost[p]) for p in range(S)]
-    bnd_lists = [np.flatnonzero(real[p] & reads_ghost[p]) for p in range(S)]
-    icap = _roundup(max(max(r.size for r in int_lists), 1))
-    bcap = _roundup(max(max(r.size for r in bnd_lists), 1))
-    interior_idx = np.full((S, icap), -1, np.int32)
-    boundary_idx = np.full((S, bcap), -1, np.int32)
-    for p in range(S):
-        interior_idx[p, : int_lists[p].size] = int_lists[p]
-        boundary_idx[p, : bnd_lists[p].size] = bnd_lists[p]
-
-    if N == 1:
-        stages, ghost_fetch = _flat_stages(
-            axes[0], S, owned, ghosts, part, local_pos, gcap
-        )
-    else:
-        stages, ghost_fetch = _two_hop_stages(
-            axes, N, D, owned, ghosts, part, slot, local_pos, gcap
-        )
-
-    mets = _halo_metrics(
-        part, nbr, owned, ghosts, N, D, stages, weights, with_quality=with_metrics
-    )
-    mets["InteriorCells"] = int(sum(r.size for r in int_lists))
-    mets["BoundaryCells"] = int(sum(r.size for r in bnd_lists))
-    mets["PlanBuildSeconds"] = time.perf_counter() - t_build
-    return HaloPlan(
-        axes=axes,
-        num_parts=S,
-        cap=cap,
-        gcap=gcap,
-        K=K,
-        owned_idx=owned_idx,
-        owned_slot=owned_slot,
-        nbr_local=nbr_local,
-        nbr_valid=nbr_valid,
-        coeff=coeff_l,
-        stages=stages,
-        ghost_fetch=ghost_fetch,
-        interior_idx=interior_idx,
-        boundary_idx=boundary_idx,
-        metrics=mets,
-    )
-
-
-def _flat_stages(axis, S, owned, ghosts, part, local_pos, gcap):
-    """One all_to_all: lane (o -> p) carries o's cells that p ghosts,
-    ordered by p's ghost order (ascending slot)."""
-    counts = np.zeros((S, S), np.int64)
-    for p in range(S):
-        for c in ghosts[p]:
-            counts[part[c], p] += 1
-    hcap = _roundup(int(counts.max()) if counts.size else 1)
-    idx = np.full((S, S, hcap), -1, np.int32)
-    fetch = np.full((S, gcap), -1, np.int32)
-    for p in range(S):
-        fill = np.zeros((S,), np.int64)
-        for gpos, c in enumerate(ghosts[p]):
-            o = int(part[c])
-            t = fill[o]
-            fill[o] += 1
-            idx[o, p, t] = local_pos[c]
-            fetch[p, gpos] = o * hcap + t
-    return (Stage(axis=axis, lanes=S, cap=hcap, idx=idx),), fetch
-
-
-def _two_hop_stages(axes, N, D, owned, ghosts, part, slot, local_pos, gcap):
-    """Node-aware exchange: hop A (node axis, per-destination-node
-    deduplicated), hop B (device axis, fan-out inside the node)."""
-    node_axis, device_axis = axes
-    S = N * D
-    # hop A dedup: (owner shard, dest node) -> ordered cell list
-    a_members: dict[tuple[int, int], dict[int, int]] = {}
-    for p in range(S):
-        m = p // D
-        for c in ghosts[p]:
-            key = (int(part[c]), m)
-            a_members.setdefault(key, {})
-            a_members[key].setdefault(int(c), -1)
-    for key, cells in a_members.items():
-        order = sorted(cells, key=lambda c: int(slot[c]))
-        for t, c in enumerate(order):
-            cells[c] = t
-    capA = _roundup(max((len(v) for v in a_members.values()), default=1))
-    idxA = np.full((S, N, capA), -1, np.int32)
-    for (o, m), cells in a_members.items():
-        for c, t in cells.items():
-            idxA[o, m, t] = local_pos[c]
-
-    # hop B: intermediate (m, d_o) restages recvA entries to device lanes
-    b_fill = np.zeros((S, D), np.int64)
-    b_entries: dict[tuple[int, int], list[tuple[int, int]]] = {}
-    fetch = np.full((S, gcap), -1, np.int32)
-    capB_needed = 1
-    fetch_tmp = []
-    for p in range(S):
-        m, d_req = p // D, p % D
-        for gpos, c in enumerate(ghosts[p]):
-            o = int(part[c])
-            n_o, d_o = o // D, o % D
-            q = m * D + d_o                      # intermediate shard
-            tA = a_members[(o, m)][int(c)]
-            srcA = n_o * capA + tA               # position in q's recvA
-            t2 = b_fill[q, d_req]
-            b_fill[q, d_req] += 1
-            b_entries.setdefault((q, d_req), []).append((t2, srcA))
-            fetch_tmp.append((p, gpos, d_o, t2))
-            capB_needed = max(capB_needed, t2 + 1)
-    capB = _roundup(capB_needed)
-    idxB = np.full((S, D, capB), -1, np.int32)
-    for (q, d_req), entries in b_entries.items():
-        for t2, srcA in entries:
-            idxB[q, d_req, t2] = srcA
-    for p, gpos, d_o, t2 in fetch_tmp:
-        fetch[p, gpos] = d_o * capB + t2
-    return (
-        Stage(axis=node_axis, lanes=N, cap=capA, idx=idxA),
-        Stage(axis=device_axis, lanes=D, cap=capB, idx=idxB),
-    ), fetch
-
-
-def _halo_metrics(part, nbr, owned, ghosts, N, D, stages, weights, *, with_quality=True):
-    """Partition quality of this halo: the paper's table columns through
-    the ONE `repro.core.metrics` implementation, plus surface index and
-    the per-level ghost/byte split the hierarchy targets."""
-    S = N * D
-    rep = {}
-    if with_quality:
-        rep = plan_quality_metrics(part, nbr, S, weights)
-    owned_counts = np.array([o.size for o in owned])
-    ghost_counts = np.array([g.size for g in ghosts])
-    rep.update(_metrics.surface_index(owned_counts, ghost_counts))
-    intra = inter = 0
-    for p in range(S):
-        if ghosts[p].size:
-            owner_node = part[ghosts[p]] // D
-            inter += int((owner_node != p // D).sum())
-            intra += int((owner_node == p // D).sum())
-    rep["IntraNodeGhosts"] = intra
-    rep["InterNodeGhosts"] = inter
-    # inter-node float32 payload of ONE exchange (hop A lanes leaving the
-    # node; the flat plan's lanes crossing nodes)
-    ib = 0
-    st = stages[0]
-    for o in range(S):
-        for lane in range(st.lanes):
-            cnt = int((st.idx[o, lane] >= 0).sum())
-            if len(stages) == 1:
-                if lane // D != o // D:
-                    ib += cnt
-            else:
-                if lane != o // D:
-                    ib += cnt
-    rep["InterNodeValuesPerExchange"] = ib
-    rep["InterNodeBytesPerExchange"] = 4 * ib
-    return rep
-
-
-# ---------------------------------------------------------------------------
 # migration (state-move) plans
 # ---------------------------------------------------------------------------
 
@@ -949,8 +631,7 @@ def build_move_plan(
 
     Vectorized: the old and new layouts are joined on ``owned_slot`` by
     one sort + ``searchsorted`` (no per-slot dicts), and the lane
-    tables fill by sorted-run ranks — bit-identical to
-    :func:`build_move_plan_legacy`.
+    tables fill by sorted-run ranks.
 
     ``cache`` (the same :class:`~repro.mesh.plan_cache.PlanCache` the
     halo builds used) shares the per-event owner gather: when ``old``
@@ -1051,118 +732,6 @@ def build_move_plan(
         kind=kind, axes=old.axes, cap_old=old.cap, cap_new=new.cap,
         keep=keep, stages=stages, migration=mig,
         metrics={**mets_extra, "PlanBuildSeconds": time.perf_counter() - t_build},
-    )
-
-
-def build_move_plan_legacy(
-    old: HaloPlan,
-    new: HaloPlan,
-    *,
-    hierarchy=None,
-    full: bool = False,
-) -> MovePlan:
-    """Per-slot dict reference implementation of :func:`build_move_plan`
-    (the equivalence-test oracle and ``bench_plans`` baseline)."""
-    t_build = time.perf_counter()
-    S = old.owned_idx.shape[0]
-    # old shard + local position per slot
-    slot_old: dict[int, tuple[int, int]] = {}
-    for p in range(S):
-        for t, s in enumerate(old.owned_slot[p]):
-            if s >= 0:
-                slot_old[int(s)] = (p, t)
-    part_of_slot: dict[int, int] = {}
-    for p in range(S):
-        for s in new.owned_slot[p]:
-            if s >= 0:
-                part_of_slot[int(s)] = p
-    slots = sorted(slot_old)
-    old_part = np.array([slot_old[s][0] for s in slots], np.int64)
-    new_part = np.array([part_of_slot[s] for s in slots], np.int64)
-    mig = _migration.migration_plan(
-        old_part, new_part, S,
-        hierarchy=hierarchy if (hierarchy is not None and hierarchy.num_nodes > 1) else None,
-    )
-    keep = np.zeros((S, old.cap), bool)
-    moved: list[tuple[int, int, int, int]] = []  # (slot, src, dst, src_pos)
-    for s in slots:
-        p_old, t = slot_old[s]
-        p_new = part_of_slot[s]
-        if p_new == p_old and not full:
-            keep[p_old, t] = True
-        else:
-            moved.append((s, p_old, p_new, t))
-    if not moved:
-        return MovePlan(
-            kind="none", axes=old.axes, cap_old=old.cap, cap_new=new.cap,
-            keep=keep, stages=(), migration=mig,
-            metrics={"PlanBuildSeconds": time.perf_counter() - t_build},
-        )
-
-    if hierarchy is not None and hierarchy.num_nodes > 1:
-        N, D = int(hierarchy.num_nodes), int(hierarchy.devices_per_node)
-        node_local = all(src // D == dst // D for _, src, dst, _ in moved)
-        if node_local and not full:
-            counts = np.zeros((S, D), np.int64)
-            for _, src, dst, _ in moved:
-                counts[src, dst % D] += 1
-            cap = _roundup(int(counts.max()))
-            idx = np.full((S, D, cap), -1, np.int32)
-            fill = np.zeros((S, D), np.int64)
-            for _, src, dst, t in sorted(moved):
-                lane = dst % D
-                idx[src, lane, fill[src, lane]] = t
-                fill[src, lane] += 1
-            stages = (Stage(axis=hierarchy.device_axis, lanes=D, cap=cap, idx=idx),)
-            kind = "device"
-        else:
-            # two hops: dest node, then dest device inside it
-            cntA = np.zeros((S, N), np.int64)
-            for _, src, dst, _ in moved:
-                cntA[src, dst // D] += 1
-            capA = _roundup(int(cntA.max()))
-            idxA = np.full((S, N, capA), -1, np.int32)
-            fillA = np.zeros((S, N), np.int64)
-            posA: dict[int, tuple[int, int, int]] = {}  # slot -> (inter q, srcA, dst)
-            for s, src, dst, t in sorted(moved):
-                m = dst // D
-                tA = fillA[src, m]
-                fillA[src, m] += 1
-                idxA[src, m, tA] = t
-                q = m * D + src % D
-                posA[s] = (q, (src // D) * capA + tA, dst)
-            cntB = np.zeros((S, D), np.int64)
-            for q, _, dst in posA.values():
-                cntB[q, dst % D] += 1
-            capB = _roundup(int(cntB.max()))
-            idxB = np.full((S, D, capB), -1, np.int32)
-            fillB = np.zeros((S, D), np.int64)
-            for s in sorted(posA):
-                q, srcA, dst = posA[s]
-                lane = dst % D
-                idxB[q, lane, fillB[q, lane]] = srcA
-                fillB[q, lane] += 1
-            stages = (
-                Stage(axis=hierarchy.node_axis, lanes=N, cap=capA, idx=idxA),
-                Stage(axis=hierarchy.device_axis, lanes=D, cap=capB, idx=idxB),
-            )
-            kind = "hier"
-    else:
-        counts = np.zeros((S, S), np.int64)
-        for _, src, dst, _ in moved:
-            counts[src, dst] += 1
-        cap = _roundup(int(counts.max()))
-        idx = np.full((S, S, cap), -1, np.int32)
-        fill = np.zeros((S, S), np.int64)
-        for _, src, dst, t in sorted(moved):
-            idx[src, dst, fill[src, dst]] = t
-            fill[src, dst] += 1
-        stages = (Stage(axis=old.axes[-1], lanes=S, cap=cap, idx=idx),)
-        kind = "flat"
-    return MovePlan(
-        kind=kind, axes=old.axes, cap_old=old.cap, cap_new=new.cap,
-        keep=keep, stages=stages, migration=mig,
-        metrics={"PlanBuildSeconds": time.perf_counter() - t_build},
     )
 
 

@@ -42,9 +42,9 @@ blocks of T's parts with the *same formulas* the scratch builder uses;
 Because every retained array region is provably what the scratch
 builder would produce and every rewritten region uses the scratch
 formulas on identical inputs, the patched plan is **bit-identical**
-(``np.array_equal``, every field) to a fresh vectorized build — which
-is itself bit-identical to the per-part legacy builder, a two-deep
-oracle chain exercised in ``tests/test_plan_equivalence.py``.
+(``np.array_equal``, every field) to a fresh scratch build — which is
+itself bit-identical to a per-part loop builder, a two-deep oracle
+chain exercised in ``tests/test_plan_equivalence.py``.
 
 When the owned capacity crosses a roundup quantum the padded table
 shapes change; the patch then copies each part block into the
@@ -203,7 +203,7 @@ class PlanCache:
             ocounts=d["ocounts"], local_pos=d["local_pos"],
             same=d["same"], other=d["other"],
             gp=d["gp"], gc=d["gc"], gr=d["gr"], gcounts=d["gcounts"],
-            reads_ghost=d["reads_ghost"], cap=d["cap"], gcap=d["gcap"],
+            cap=d["cap"], gcap=d["gcap"],
         )
         self._last_plan = plan
 
@@ -234,7 +234,7 @@ class PlanCache:
 def cached_build_halo_plan(
     cache: PlanCache, slot, part, nbr, coeff, *,
     hierarchy=None, num_parts=None, device_axis="device", weights=None,
-    with_metrics=True, topo_token=None, profile=None,
+    with_metrics=True, topo_token=None,
 ):
     """:func:`~repro.mesh.halo.build_halo_plan` through a
     :class:`PlanCache` — bit-identical output, patched construction."""
@@ -253,7 +253,7 @@ def cached_build_halo_plan(
         return _full_build(
             cache, slot_a, part_a, nbr, coeff, hierarchy, num_parts,
             device_axis, weights, with_metrics, topo_ok, topo_token,
-            shape_key, profile,
+            shape_key,
         )
 
     part64 = part_a.astype(np.int64)
@@ -279,8 +279,7 @@ def cached_build_halo_plan(
             gcap=old.gcap, K=old.K, owned_idx=old.owned_idx,
             owned_slot=old.owned_slot, nbr_local=old.nbr_local,
             nbr_valid=old.nbr_valid, coeff=old.coeff, stages=old.stages,
-            ghost_fetch=old.ghost_fetch, interior_idx=old.interior_idx,
-            boundary_idx=old.boundary_idx, metrics=mets,
+            ghost_fetch=old.ghost_fetch, metrics=mets,
         )
         cache._stash_prev()
         cache._last_plan = plan
@@ -289,17 +288,17 @@ def cached_build_halo_plan(
         return _full_build(
             cache, slot_a, part_a, nbr, coeff, hierarchy, num_parts,
             device_axis, weights, with_metrics, topo_ok, topo_token,
-            shape_key, profile,
+            shape_key,
         )
     return _patched_build(
         cache, part_a, part64, moved, nbr, weights, with_metrics,
-        shape_key, t_build, profile,
+        shape_key, t_build,
     )
 
 
 def _full_build(
     cache, slot_a, part_a, nbr, coeff, hierarchy, num_parts, device_axis,
-    weights, with_metrics, topo_ok, topo_token, shape_key, profile,
+    weights, with_metrics, topo_ok, topo_token, shape_key,
 ):
     """Scratch build through the cache: reuse the topology tier when it
     is still valid, capture the intermediates for the next event."""
@@ -311,7 +310,7 @@ def _full_build(
     plan = _halo.build_halo_plan(
         slot_a, part_a, nbr, coeff, hierarchy=hierarchy, num_parts=num_parts,
         device_axis=device_axis, weights=weights, with_metrics=with_metrics,
-        profile=profile, _topo=topo, _capture=cap_d,
+        _topo=topo, _capture=cap_d,
     )
     if topo_ok:
         cache._stash_prev()
@@ -330,11 +329,10 @@ def _full_build(
 
 def _patched_build(
     cache, part_a, part64, moved, nbr, weights, with_metrics, shape_key,
-    t_build, profile,
+    t_build,
 ):
     """Delta-patch the cached build state for a reslice that moved cell
     set ``moved`` (bit-identical to a scratch build, see module doc)."""
-    prof = _halo._ProfTimer(profile)
     topo, st = cache._topo, cache._state
     n, K = topo["n"], topo["K"]
     N, D, S, axes = shape_key
@@ -361,7 +359,6 @@ def _patched_build(
     s_new = va & (part64[nb_aff] == part64[aff // K])
     same.ravel()[aff] = s_new
     other.ravel()[aff] = va & ~s_new
-    prof.mark("patch_flags_s")
 
     # (2) merge the moved rows out of / into the sorted owned layout:
     # one searchsorted over the retained keys replaces the global
@@ -392,7 +389,6 @@ def _patched_build(
     orank = np.arange(n, dtype=np.int64) - ostarts[okey // n]
     local_pos = np.empty((n,), np.int64)
     local_pos[ocells] = orank
-    prof.mark("patch_merge_s")
 
     # (3) ghost pairs: recompute for the touched parts' rows only,
     # splice against the retained pairs of untouched parts, and re-sort
@@ -418,7 +414,6 @@ def _patched_build(
     gstarts = np.concatenate(([0], np.cumsum(gcounts)))
     grank = np.arange(gp2.size, dtype=np.int64) - gstarts[gp2]
     gcap = _halo._roundup(max(int(gcounts.max()) if gcounts.size else 0, 1))
-    prof.mark("patch_ghost_s")
 
     # (4) stencil tables: reset the touched parts' padded blocks and
     # refill them with the scratch formulas; untouched blocks are
@@ -435,7 +430,6 @@ def _patched_build(
         nbr_localf = old.nbr_local.reshape(S * cap, K).copy()
         nbr_validf = old.nbr_valid.reshape(S * cap, K).copy()
         coeff_f = old.coeff.reshape(S * cap, K).copy()
-        reads_f = st["reads_ghost"].reshape(-1).copy()
     else:
         c = min(cap, cap2)
         oi = np.full((S, cap2), -1, np.int32)
@@ -443,20 +437,17 @@ def _patched_build(
         nl = np.zeros((S, cap2, K), np.int32)
         nv = np.zeros((S, cap2, K), bool)
         cf = np.zeros((S, cap2, K), np.float32)
-        rg = np.zeros((S, cap2), bool)
         oi[:, :c] = old.owned_idx[:, :c]
         osl[:, :c] = old.owned_slot[:, :c]
         nl[:, :c] = old.nbr_local[:, :c]
         nv[:, :c] = old.nbr_valid[:, :c]
         cf[:, :c] = old.coeff[:, :c]
-        rg[:, :c] = st["reads_ghost"][:, :c]
         nl[nv & (nl >= cap)] += cap2 - cap
         owned_idx = oi.reshape(-1)
         owned_slot = osl.reshape(-1)
         nbr_localf = nl.reshape(S * cap2, K)
         nbr_validf = nv.reshape(S * cap2, K)
         coeff_f = cf.reshape(S * cap2, K)
-        reads_f = rg.reshape(-1)
         cap = cap2
     blk = (T[:, None] * cap + np.arange(cap, dtype=np.int64)[None, :]).ravel()
     owned_idx[blk] = -1
@@ -464,7 +455,6 @@ def _patched_build(
     nbr_localf[blk] = 0
     nbr_validf[blk] = False
     coeff_f[blk] = 0.0
-    reads_f[blk] = False
     drow = part64[cells_T] * cap + local_pos[cells_T]
     owned_idx[drow] = cells_T.astype(np.int32)
     owned_slot[drow] = topo["slot64"][cells_T]
@@ -480,31 +470,12 @@ def _patched_build(
     nbr_localf[drow] = np.where(va_T, loc, 0)
     nbr_validf[drow] = va_T
     coeff_f[drow] = topo["coeff"][cells_T]
-    reads_f[drow] = other_T.any(axis=1)
 
     owned_idx = owned_idx.reshape(S, cap)
     owned_slot = owned_slot.reshape(S, cap)
     nbr_local = nbr_localf.reshape(S, cap, K)
     nbr_valid = nbr_validf.reshape(S, cap, K)
     coeff_l = coeff_f.reshape(S, cap, K)
-    reads_ghost = reads_f.reshape(S, cap)
-
-    # interior/boundary split over the patched reads_ghost (cheap, and
-    # its caps depend on global counts — patching blocks would not help)
-    real = owned_idx >= 0
-    pi, ri = np.nonzero(real & ~reads_ghost)
-    pb, rb = np.nonzero(real & reads_ghost)
-    icounts = np.bincount(pi, minlength=S)
-    bcounts = np.bincount(pb, minlength=S)
-    icap = _halo._roundup(max(int(icounts.max()) if icounts.size else 0, 1))
-    bcap = _halo._roundup(max(int(bcounts.max()) if bcounts.size else 0, 1))
-    istarts = np.concatenate(([0], np.cumsum(icounts)))
-    bstarts = np.concatenate(([0], np.cumsum(bcounts)))
-    interior_idx = np.full((S, icap), -1, np.int32)
-    boundary_idx = np.full((S, bcap), -1, np.int32)
-    interior_idx[pi, np.arange(pi.size) - istarts[pi]] = ri
-    boundary_idx[pb, np.arange(pb.size) - bstarts[pb]] = rb
-    prof.mark("patch_tables_s")
 
     # (5) routing stages re-pack over the (small) ghost pair lists
     if N == 1:
@@ -515,32 +486,27 @@ def _patched_build(
         stages, ghost_fetch = _halo._two_hop_stages_vec(
             axes, N, D, n, gp2, gc2, gr2, grank, part64, local_pos, gcap
         )
-    prof.mark("stage_pack_s")
 
     mets = _halo._halo_metrics_vec(
         part_a, nbr, ocounts, gcounts, gp2, gc2, D, stages, weights,
         with_quality=with_metrics,
     )
-    mets["InteriorCells"] = int(pi.size)
-    mets["BoundaryCells"] = int(pb.size)
+    _halo._row_counts(mets, other)
     cache.stats.halo_hits += 1
     cache.stats.patched_rows += int(cells_T.size)
     mets["PlanCacheHits"] = cache.stats.halo_hits
     mets["PatchedRows"] = int(cells_T.size)
     mets["PlanBuildSeconds"] = time.perf_counter() - t_build
-    prof.mark("metrics_s")
     plan = _halo.HaloPlan(
         axes=axes, num_parts=S, cap=cap, gcap=gcap, K=K,
         owned_idx=owned_idx, owned_slot=owned_slot, nbr_local=nbr_local,
         nbr_valid=nbr_valid, coeff=coeff_l, stages=stages,
-        ghost_fetch=ghost_fetch, interior_idx=interior_idx,
-        boundary_idx=boundary_idx, metrics=mets,
+        ghost_fetch=ghost_fetch, metrics=mets,
     )
     cache._stash_prev()
     cache._install_state(plan, shape_key, dict(
         part64=part64, ocells=ocells, okey=okey, ocounts=ocounts,
         local_pos=local_pos, same=same, other=other,
-        gp=gp2, gc=gc2, gr=gr2, gcounts=gcounts,
-        reads_ghost=reads_ghost, cap=cap, gcap=gcap,
+        gp=gp2, gc=gc2, gr=gr2, gcounts=gcounts, cap=cap, gcap=gcap,
     ))
     return plan

@@ -184,13 +184,6 @@ class SimStats:
     plan_cache_hits: int = 0
     plan_cache_misses: int = 0
     plan_patched_rows: int = 0
-    # per-phase attribution of the sweep, measured once per compiled
-    # plan by the single-phase probes (reporting only: the hot loop runs
-    # the one fused overlapped program, where interior compute hides
-    # behind the in-flight exchange)
-    stencil_exchange_s: float = 0.0
-    stencil_interior_s: float = 0.0
-    stencil_boundary_s: float = 0.0
     # the current plan's exchange: bytes one stage's halo exchange sends
     # from the busiest chip (real entries of every hop, all fields), and
     # ghost cells over all chips
@@ -247,7 +240,6 @@ class DistributedSim:
         cfg: SimConfig = SimConfig(),
         capacity: int | None = None,
         use_pallas: bool = False,
-        phase_probes: bool = False,
     ):
         import jax.numpy as jnp
 
@@ -257,7 +249,7 @@ class DistributedSim:
         if driver not in ("incremental", "rebuild"):
             raise ValueError(f"unknown driver {driver!r}")
         self.jax_mesh, self.hplan, self.driver = jax_mesh, hplan, driver
-        self.use_pallas, self.phase_probes = bool(use_pallas), bool(phase_probes)
+        self.use_pallas = bool(use_pallas)
         self.floors: dict = {}
         pcfg = _pt.PartitionerConfig(use_tree=True, curve="hilbert")
         self.rp = HierarchicalRepartitioner(
@@ -305,7 +297,7 @@ class DistributedSim:
 
     def _pad(self, plan):
         c = plan.caps
-        f = {k: self._floor(k, c[k]) for k in ("cap", "gcap", "icap", "bcap")}
+        f = {k: self._floor(k, c[k]) for k in ("cap", "gcap")}
         f["stages"] = self._floor(("stages", len(c["stages"])), c["stages"])
         return plan.padded(f)
 
@@ -449,11 +441,6 @@ class DistributedSim:
         self.plan, self.xplan, self.args, self.n = plan, xplan, args, ev.mesh.n
 
         # --- stencil sweeps ------------------------------------------------
-        if self.phase_probes:
-            ph = _st.stencil_phase_times(self.jax_mesh, xplan, self.u_dev, args)
-            st.stencil_exchange_s += substeps * ph["exchange"]
-            st.stencil_interior_s += substeps * ph["interior"]
-            st.stencil_boundary_s += substeps * ph["boundary"]
         every = checksum_every or substeps
         done = 0
         while done < substeps:
@@ -512,7 +499,6 @@ def run_distributed(
     *,
     driver: str = "incremental",
     cfg: SimConfig = SimConfig(),
-    phase_probes: bool = False,
     use_pallas: bool = False,
 ) -> tuple[np.ndarray, SimStats]:
     """Integrate the trajectory on a device mesh under one driver.
@@ -521,16 +507,13 @@ def run_distributed(
     equal the device count of ``jax_mesh`` (parts name shards). ``u0``
     is one field (n,) or V fields (n, V). Returns the final fields in
     global cell order plus phase timings/accounting.
-    ``phase_probes`` additionally attributes sweep walltime to its
-    exchange/interior/boundary phases via the single-phase probe
-    executors (extra per-event probe calls — reporting, not the gate).
     ``use_pallas`` runs the sweeps' row update through the Pallas stencil
     kernel (bit-equal to the jnp definition, so to ``run_reference``).
     """
     sim = DistributedSim(
         events[0], u0, jax_mesh, hplan, driver=driver, cfg=cfg,
         capacity=2 * max(ev.mesh.n for ev in events),
-        use_pallas=use_pallas, phase_probes=phase_probes,
+        use_pallas=use_pallas,
     )
     for ev in events:
         sim.advance(ev, substeps)

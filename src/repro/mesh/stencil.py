@@ -8,40 +8,27 @@ signature (the same lru_cache pattern as ``partitioner._reslice_fn``;
 shard_map must run under jit or every traced op dispatches as its own
 SPMD program).
 
-The default executor overlaps communication with computation: per
-sweep it launches the ghost-exchange hops, updates the plan's
-*interior* rows (compiled to be provably independent of the exchange —
-no valid neighbor slot reaches into the ghost region) while the
-collectives are in flight, and applies the *boundary* rows only after
-the recv lands. Under jit the ``all_to_all`` lowers to an async
-start/done pair and XLA schedules the interior update between them; the
-dataflow admits the overlap by construction, on any backend. The row
-update itself is the fused `kernels.ops.stencil_update` (gather + mask
-+ coeff*(v-u) + K-reduce in one pass; optional Pallas kernel, bit-equal
-jnp fallback). The step loop is a ``fori_loop`` over a *traced* step
-count, so ONE compiled executor serves every sweep length — ``steps``
-is not part of the cache signature. With V fields a row (``u`` of shape
-(rows, V)) the executor does not split: it waits for the exchange and
-updates every row in one pass, since the split's gathers of the row
-subsets' centres and tables and its scatter back are each a pass over
-V-wide rows that costs more than the exchange it would hide.
+Each sweep is one form for one field (rows,) or V fields a row (rows,
+V): route the owned rows along the plan's hops, fetch the ghosts, then
+update every owned row in one pass over ``concat([u, ghosts])``. The
+row update is the fused `kernels.ops.stencil_update` (gather + mask +
+coeff*(v-u) + K-reduce in one pass; a Pallas kernel, scalar or V-wide
+by the rank of ``u``, or the bit-equal jnp definition). The step loop
+is a ``fori_loop`` over a *traced* step count, so ONE compiled executor
+serves every sweep length — ``steps`` is not part of the cache
+signature.
 
 Bit-equality contract: :func:`reference_stencil` (single device, global
 cell order) and :func:`stencil_steps` (sharded, owned+ghost layout)
 evaluate the SAME per-cell expression — ``u_i += sum_k where(valid,
 coeff_ik * (u_nbr - u_i), 0)`` with identical (n, K) coefficient rows,
 identical slot order and identical float32 dtype — so a distributed
-sweep is bitwise equal to the reference sweep, which is what the
-``bench_mesh`` gate holds after repeated repartition + migration
-events. The interior/boundary split preserves this: each row subset
-evaluates the identical expression on the identical values and the
-scatters merely reassemble the rows (row-wise K-reduction order does
-not depend on the row blocking).
+sweep is bitwise equal to the reference sweep, after any number of
+repartition and migration events (``tests/test_mesh.py`` holds it).
 """
 from __future__ import annotations
 
 import functools
-import time
 from dataclasses import dataclass
 
 import jax
@@ -76,17 +63,6 @@ def _route(prev, stage_meta, stage_idx, fill):
 
 def _ghosts(recv, fetch):
     return jnp.where(_rows(fetch >= 0, recv), recv[jnp.clip(fetch, 0, recv.shape[0] - 1)], 0.0)
-
-
-def _rows_update(u_out, u, vals_all, nbr, valid, coeff, rows, use_pallas):
-    """Update the subset ``rows`` of owned cells (-1 pads drop): gather
-    the row tables, run the fused update, scatter the results back."""
-    r = jnp.maximum(rows, 0)
-    out_rows = _ops.stencil_update(
-        vals_all, u[r], nbr[r], valid[r], coeff[r], use_pallas=use_pallas
-    )
-    safe = jnp.where(rows >= 0, r, u.shape[0])  # out of range -> dropped
-    return u_out.at[safe].set(out_rows, mode="drop")
 
 
 # ---------------------------------------------------------------------------
@@ -124,59 +100,19 @@ def _stencil_fn(
     stage_meta: tuple,
     use_pallas: bool,
 ):
-    """Jitted overlapped halo-exchange + fused-update executor, memoized
-    per static (mesh, axes, hop shapes) — NOT per step count: ``steps``
-    is a traced argument, so one compiled program serves any sweep
-    length."""
+    """Jitted halo-exchange + fused-update executor, memoized per static
+    (mesh, axes, hop shapes) — NOT per step count: ``steps`` is a traced
+    argument, so one compiled program serves any sweep length."""
 
-    def kernel(steps, u, nbr, valid, coeff, fetch, interior, boundary, *stage_idx):
-        def body_wide(_, u):
-            # V fields a row: the exchange, then every row at once (see
-            # the module docstring)
+    def kernel(steps, u, nbr, valid, coeff, fetch, *stage_idx):
+        def body(_, u):
             recv = _route(u, stage_meta, stage_idx, jnp.float32(0.0))
             vals_all = jnp.concatenate([u, _ghosts(recv, fetch)])
             return _ops.stencil_update(vals_all, u, nbr, valid, coeff, use_pallas=use_pallas)
-
-        def body(_, u):
-            # launch the ghost exchange; nothing below depends on it
-            # until the boundary update, so XLA is free to run the
-            # interior update between the collective's start/done pair
-            recv = _route(u, stage_meta, stage_idx, jnp.float32(0.0))
-            # interior rows: all reads come from u itself
-            u_new = _rows_update(u, u, u, nbr, valid, coeff, interior, use_pallas)
-            # boundary rows: wait for the recv, fetch ghosts, update
-            vals_all = jnp.concatenate([u, _ghosts(recv, fetch)])
-            return _rows_update(
-                u_new, u, vals_all, nbr, valid, coeff, boundary, use_pallas
-            )
-        return jax.lax.fori_loop(0, steps, body_wide if u.ndim == 2 else body, u)
+        return jax.lax.fori_loop(0, steps, body, u)
 
     spec = P(axes)
-    in_specs = (P(),) + (spec,) * (7 + len(stage_meta))
-    return jax.jit(jax.shard_map(
-        kernel, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False,
-    ))
-
-
-@functools.lru_cache(maxsize=64)
-def _stencil_fn_presplit(
-    mesh: jax.sharding.Mesh, axes: tuple, stage_meta: tuple, steps: int
-):
-    """The pre-split executor (serialize-everything: full exchange, then
-    one unfused (cap, K) gather+reduce over ALL rows; python-unrolled
-    step loop, so the cache is keyed on ``steps`` and every new sweep
-    length recompiles). Kept as the benchmark baseline the overlapped
-    executor is gated against."""
-
-    def kernel(u, nbr, valid, coeff, fetch, *stage_idx):
-        for _ in range(steps):
-            recv = _route(u, stage_meta, stage_idx, jnp.float32(0.0))
-            vals_all = jnp.concatenate([u, _ghosts(recv, fetch)])
-            u = _ops.stencil_update(vals_all, u, nbr, valid, coeff)
-        return u
-
-    spec = P(axes)
-    in_specs = (spec,) * (5 + len(stage_meta))
+    in_specs = (P(),) + (spec,) * (5 + len(stage_meta))
     return jax.jit(jax.shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False,
     ))
@@ -187,7 +123,6 @@ class HaloArgs:
     """Device-resident executor arguments for one halo plan."""
 
     core: tuple     # (nbr, valid, coeff, fetch)
-    split: tuple    # (interior, boundary)
     stages: tuple   # one flat lane-index array per hop
 
 
@@ -203,14 +138,10 @@ def halo_args(jax_mesh: jax.sharding.Mesh, plan: HaloPlan) -> HaloArgs:
         put(plan.coeff.reshape(S * plan.cap, plan.K)),
         put(plan.ghost_fetch.reshape(S * plan.gcap)),
     )
-    split = (
-        put(plan.interior_idx.reshape(-1)),
-        put(plan.boundary_idx.reshape(-1)),
-    )
     stages = tuple(
         put(s.idx.reshape(S * s.lanes * s.cap)) for s in plan.stages
     )
-    return HaloArgs(core=core, split=split, stages=stages)
+    return HaloArgs(core=core, stages=stages)
 
 
 def stencil_steps(
@@ -220,95 +151,21 @@ def stencil_steps(
     args: HaloArgs,
     steps: int,
     *,
-    overlap: bool = True,
     use_pallas: bool = False,
 ):
     """Run ``steps`` distributed sweeps over the plan's layout.
 
     ``u_dev`` is the (S*cap,) owned field or (S*cap, V) owned fields
-    (``plan.pack_cells`` layout);
-    ``args`` from :func:`halo_args`. The default overlapped executor
-    updates interior rows while the exchange is in flight and reuses
-    ONE compiled program for every ``steps``; ``overlap=False`` runs the
-    pre-split baseline (bit-equal, recompiles per sweep length)."""
-    if overlap:
-        fn = _stencil_fn(jax_mesh, plan.axes, plan.stage_meta, bool(use_pallas))
-        return fn(jnp.int32(steps), u_dev, *args.core, *args.split, *args.stages)
-    fn = _stencil_fn_presplit(jax_mesh, plan.axes, plan.stage_meta, int(steps))
-    return fn(u_dev, *args.core, *args.stages)
+    (``plan.pack_cells`` layout); ``args`` from :func:`halo_args`. One
+    compiled program serves every ``steps``."""
+    fn = _stencil_fn(jax_mesh, plan.axes, plan.stage_meta, bool(use_pallas))
+    return fn(jnp.int32(steps), u_dev, *args.core, *args.stages)
 
 
 def put_state(jax_mesh, plan: HaloPlan, u_cells: np.ndarray):
     """Host cell-order field (n,) or fields (n, V) -> device owned layout."""
     sh = NamedSharding(jax_mesh, P(plan.axes))
     return jax.device_put(plan.pack_cells(u_cells), sh)
-
-
-# ---------------------------------------------------------------------------
-# per-phase probes (reporting only — the hot loop runs the fused program)
-# ---------------------------------------------------------------------------
-
-@functools.lru_cache(maxsize=64)
-def _phase_fns(mesh: jax.sharding.Mesh, axes: tuple, stage_meta: tuple):
-    """Three jitted single-phase executors (exchange only / interior only
-    / boundary only) used to attribute sweep walltime to its phases.
-    They exist for measurement — the production executor fuses all three
-    into one program."""
-    spec = P(axes)
-
-    def exchange(u, fetch, *stage_idx):
-        return _ghosts(_route(u, stage_meta, stage_idx, jnp.float32(0.0)), fetch)
-
-    def interior(u, nbr, valid, coeff, rows):
-        return _rows_update(u, u, u, nbr, valid, coeff, rows, False)
-
-    def boundary(u, ghosts, nbr, valid, coeff, rows):
-        vals_all = jnp.concatenate([u, ghosts])
-        return _rows_update(u, u, vals_all, nbr, valid, coeff, rows, False)
-
-    wrap = lambda f, n: jax.jit(jax.shard_map(
-        f, mesh=mesh, in_specs=(spec,) * n, out_specs=spec, check_vma=False,
-    ))
-    return (
-        wrap(exchange, 2 + len(stage_meta)),
-        wrap(interior, 5),
-        wrap(boundary, 6),
-    )
-
-
-def stencil_phase_times(
-    jax_mesh, plan: HaloPlan, u_dev, args: HaloArgs, *, repeats: int = 2
-) -> dict:
-    """Measured walltime of one sweep's phases, each as its own jitted
-    program (warm: every probe runs ``repeats + 1`` times and the first
-    — the compile — is discarded). Returns seconds per single sweep."""
-    ex, it, bd = _phase_fns(jax_mesh, plan.axes, plan.stage_meta)
-    nbr, valid, coeff, fetch = args.core
-    interior, boundary = args.split
-    out = {}
-    for name, call in (
-        ("exchange", lambda: ex(u_dev, fetch, *args.stages)),
-        ("interior", lambda: it(u_dev, nbr, valid, coeff, interior)),
-    ):
-        best = None
-        jax.block_until_ready(call())  # compile
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            jax.block_until_ready(call())
-            dt = time.perf_counter() - t0
-            best = dt if best is None else min(best, dt)
-        out[name] = best
-    ghosts = jax.block_until_ready(ex(u_dev, fetch, *args.stages))
-    call = lambda: bd(u_dev, ghosts, nbr, valid, coeff, boundary)
-    jax.block_until_ready(call())
-    best = None
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        jax.block_until_ready(call())
-        dt = time.perf_counter() - t0
-        best = dt if best is None else min(best, dt)
-    out["boundary"] = best
-    return out
 
 
 # ---------------------------------------------------------------------------

@@ -6,25 +6,24 @@ candidate interaction sets through CurveIndex bucket lookups within
 radius ``r`` (the 3^d probe-cell walk below) and emits the same padded
 ``(n, K)`` neighbor-table shape `repro.mesh.amr.face_neighbors`
 produces — so :func:`build_interact_plan` is `halo.build_halo_plan`
-wholesale (ghost dedup, interior/boundary split, flat and two-hop node
-routing, `PlanCache` reuse where the topology tier applies), compiled
-ONCE per partition event into fixed-shape interaction/exchange plans.
+wholesale (ghost dedup, flat and two-hop node routing, `PlanCache`
+reuse where the topology tier applies), compiled ONCE per partition
+event into fixed-shape interaction/exchange plans.
 
 Executors mirror `repro.mesh.stencil`: jitted ``shard_map`` closures
-memoized per static shape signature, an overlapped sweep (launch the
-ghost position exchange, compute the plan's *interior* rows while the
-collective is in flight, apply *boundary* rows after the recv lands),
-and a ``fori_loop`` over a traced substep count so ONE compiled program
-serves every sweep length. The row update is the fused
-`kernels.ops.pair_accel` (Pallas + bit-equal jnp fallback).
+memoized per static shape signature, one sweep form (route the owned
+positions, fetch the ghosts, then the accelerations of every owned row
+in one pass over ``concat([x, ghosts])``), and a ``fori_loop`` over a
+traced substep count so ONE compiled program serves every sweep length.
+The row update is the fused `kernels.ops.pair_accel` (Pallas + bit-equal
+jnp fallback).
 
 Bit-equality contract: :func:`reference_leapfrog` (single device,
 global row order) and :func:`leapfrog_steps` (sharded, owned+ghost
 layout) evaluate the SAME per-particle expressions — identical padded
 (n, K) tables, identical fixed-order reductions, identical float32
 integration (:func:`_integrate`) — so a distributed trajectory is
-bitwise equal to the reference trajectory, which is what
-``bench_particles`` gates across repartition events.
+bitwise equal to the reference trajectory across repartition events.
 """
 from __future__ import annotations
 
@@ -41,7 +40,7 @@ from repro.core import curve_index as _ci
 from repro.kernels import ops as _ops
 from repro.mesh import halo as _halo
 from repro.mesh.halo import GID_SENTINEL, HaloPlan, MovePlan, _roundup
-from repro.mesh.stencil import _route
+from repro.mesh.stencil import _ghosts, _route
 
 
 # ---------------------------------------------------------------------------
@@ -141,11 +140,11 @@ def build_interact_plan(
 
     Exactly `halo.build_halo_plan` over the distance-based table (the
     stencil coefficient lanes carry zeros — the pair executors never
-    read them): ghost sets, local index remapping, interior/boundary
-    split and the flat/two-hop routing stages all come from the shared
-    builder, so everything the mesh application proved (bit-identity to
-    the legacy builder, `PlanCache` delta patching keyed on
-    ``topo_token``) holds here unchanged.
+    read them): ghost sets, local index remapping and the flat/two-hop
+    routing stages all come from the shared builder, so everything the
+    mesh application proved (bit-identity to the per-part oracle,
+    `PlanCache` delta patching keyed on ``topo_token``) holds here
+    unchanged.
     """
     coeff = np.zeros(nbr.shape, np.float32)
     return _halo.build_halo_plan(
@@ -192,7 +191,6 @@ class InteractArgs:
     """Device-resident executor arguments for one interaction plan."""
 
     core: tuple     # (nbr, valid, fetch)
-    split: tuple    # (interior, boundary)
     stages: tuple   # one flat lane-index array per hop
 
 
@@ -208,12 +206,8 @@ def interact_args(jax_mesh, plan: HaloPlan) -> InteractArgs:
         put(plan.nbr_valid.reshape(S * plan.cap, plan.K)),
         put(plan.ghost_fetch.reshape(S * plan.gcap)),
     )
-    split = (
-        put(plan.interior_idx.reshape(-1)),
-        put(plan.boundary_idx.reshape(-1)),
-    )
     stages = tuple(put(s.idx.reshape(S * s.lanes * s.cap)) for s in plan.stages)
-    return InteractArgs(core=core, split=split, stages=stages)
+    return InteractArgs(core=core, stages=stages)
 
 
 # ---------------------------------------------------------------------------
@@ -237,17 +231,6 @@ def _integrate(x, v, acc, dt):
     v2 = v + dt * acc
     x2 = x + dt * v2
     return _reflect_walls(x2, v2)
-
-
-def _rows_accel(acc, pos_all, mass_all, x_own, nbr, valid, rows, rc2, use_pallas):
-    """Accelerations for the subset ``rows`` of owned particles (-1 pads
-    drop): gather the row tables, run the fused kernel, scatter back."""
-    r = jnp.maximum(rows, 0)
-    a_rows = _ops.pair_accel(
-        pos_all, mass_all, x_own[r], nbr[r], valid[r], rc2, use_pallas=use_pallas
-    )
-    safe = jnp.where(rows >= 0, r, x_own.shape[0])  # out of range -> dropped
-    return acc.at[safe].set(a_rows, mode="drop")
 
 
 def _route_cols(prev, stage_meta, stage_idx, fill):
@@ -293,7 +276,7 @@ def reference_leapfrog(x, v, m, nbr, steps: int, dt: float, radius: float,
 
 
 # ---------------------------------------------------------------------------
-# distributed leapfrog (overlapped ghost-position exchange)
+# distributed leapfrog (ghost-position exchange, then every owned row)
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=64)
@@ -303,38 +286,25 @@ def _leapfrog_fn(
     stage_meta: tuple,
     use_pallas: bool,
 ):
-    """Jitted overlapped exchange + fused pair-accel + integrate executor,
-    memoized per static (mesh, axes, hop shapes) — ``steps`` is traced,
-    so one compiled program serves any substep count."""
+    """Jitted exchange + fused pair-accel + integrate executor, memoized
+    per static (mesh, axes, hop shapes) — ``steps`` is traced, so one
+    compiled program serves any substep count."""
 
-    def kernel(steps, dt, rc2, x, v, m, m_gh, nbr, valid, fetch,
-               interior, boundary, *stage_idx):
+    def kernel(steps, dt, rc2, x, v, m, m_gh, nbr, valid, fetch, *stage_idx):
         mass_all = jnp.concatenate([m, m_gh])
 
         def body(_, carry):
             x, v = carry
-            # launch the ghost position exchange; nothing below depends
-            # on it until the boundary rows, so XLA can run the interior
-            # accelerations inside the collective's async window
             recv = _route_cols(x, stage_meta, stage_idx, jnp.float32(0.0))
-            acc = jnp.zeros_like(x)
-            # interior rows: every valid neighbor is owned locally
-            acc = _rows_accel(acc, x, m, x, nbr, valid, interior, rc2, use_pallas)
-            ghosts = jnp.where(
-                (fetch >= 0)[:, None],
-                recv[jnp.clip(fetch, 0, recv.shape[0] - 1)],
-                jnp.float32(0.0),
-            )
-            pos_all = jnp.concatenate([x, ghosts], axis=0)
-            acc = _rows_accel(
-                acc, pos_all, mass_all, x, nbr, valid, boundary, rc2, use_pallas
-            )
+            pos_all = jnp.concatenate([x, _ghosts(recv, fetch)], axis=0)
+            acc = _ops.pair_accel(pos_all, mass_all, x, nbr, valid, rc2,
+                                  use_pallas=use_pallas)
             return _integrate(x, v, acc, dt)
 
         return jax.lax.fori_loop(0, steps, body, (x, v))
 
     spec = P(axes)
-    in_specs = (P(), P(), P()) + (spec,) * (9 + len(stage_meta))
+    in_specs = (P(), P(), P()) + (spec,) * (7 + len(stage_meta))
     return jax.jit(jax.shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=(spec, spec),
         check_vma=False,
@@ -363,7 +333,7 @@ def leapfrog_steps(
     fn = _leapfrog_fn(jax_mesh, plan.axes, plan.stage_meta, bool(use_pallas))
     return fn(
         jnp.int32(steps), jnp.float32(dt), jnp.float32(float(radius) ** 2),
-        x_dev, v_dev, m_dev, mgh_dev, *args.core, *args.split, *args.stages,
+        x_dev, v_dev, m_dev, mgh_dev, *args.core, *args.stages,
     )
 
 
@@ -372,8 +342,7 @@ def _exchange_fn(mesh: jax.sharding.Mesh, axes: tuple, stage_meta: tuple):
     """Jitted one-shot ghost fetch of a per-row scalar (the mass vector)."""
 
     def kernel(m, fetch, *stage_idx):
-        recv = _route(m, stage_meta, stage_idx, jnp.float32(0.0))
-        return jnp.where(fetch >= 0, recv[jnp.clip(fetch, 0, recv.shape[0] - 1)], 0.0)
+        return _ghosts(_route(m, stage_meta, stage_idx, jnp.float32(0.0)), fetch)
 
     spec = P(axes)
     in_specs = (spec,) * (2 + len(stage_meta))

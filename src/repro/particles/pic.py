@@ -14,8 +14,9 @@ coefficients), particle rows their `interact.cutoff_neighbors` lanes
 (offset by the cell count). A per-row particle flag splits the lane
 masks on device — cell rows run the fused stencil update on column 0,
 particle rows the fused pair acceleration on the position columns, and
-both phases share the routed ghost matrix, the interior/boundary
-overlap and the traced-substep ``fori_loop``.
+both phases share the routed ghost matrix and the traced-substep
+``fori_loop``. The reference and the distributed executor run the one
+substep expression, :func:`_pic_body`, over every row at once.
 
 Deposit (particle -> containing cell, ``u += kappa * mass``) and
 interpolate (cell -> particle, a drag ``vel *= 1 - gamma * u``) are
@@ -142,34 +143,20 @@ def initial_field(mesh: _amr.AMRMesh) -> np.ndarray:
 # the fused coupled substep (stencil + pair accel share one exchange)
 # ---------------------------------------------------------------------------
 
-def _pic_body(U, isp, nbr, valid, coeff, rc2, dt, d, ghosts, interior, boundary,
-              use_pallas):
-    """One coupled substep given the routed ghost matrix. Shared by the
-    reference twin (``ghosts=None``: every row interior, global order)
-    and the distributed executor — the same expressions, so identical
-    bits per row."""
+def _pic_body(U, A, isp, nbr, valid, coeff, rc2, dt, d, use_pallas):
+    """One coupled substep of the rows ``U`` whose tables index ``A``:
+    ``U`` itself in the reference (global order), ``concat([U,
+    ghosts])`` in the distributed executor — the same expressions, so
+    identical bits per row."""
     u = U[:, 0]
     x = U[:, 1:1 + d]
     v = U[:, 1 + d:1 + 2 * d]
     m = U[:, 1 + 2 * d]
     cval = valid & (~isp)[:, None]
     pval = valid & isp[:, None]
-    if ghosts is None:
-        u_new = _ops.stencil_update(u, u, nbr, cval, coeff, use_pallas=use_pallas)
-        acc = _ops.pair_accel(x, m, x, nbr, pval, rc2, use_pallas=use_pallas)
-    else:
-        # interior rows first (owned-only reads, exchange in flight)
-        u_new = _st._rows_update(u, u, u, nbr, cval, coeff, interior, use_pallas)
-        acc = jnp.zeros_like(x)
-        acc = _ia._rows_accel(acc, x, m, x, nbr, pval, interior, rc2, use_pallas)
-        A = jnp.concatenate([U, ghosts], axis=0)
-        u_new = _st._rows_update(
-            u_new, u, A[:, 0], nbr, cval, coeff, boundary, use_pallas
-        )
-        acc = _ia._rows_accel(
-            acc, A[:, 1:1 + d], A[:, 1 + 2 * d], x, nbr, pval, boundary, rc2,
-            use_pallas,
-        )
+    u_new = _ops.stencil_update(A[:, 0], u, nbr, cval, coeff, use_pallas=use_pallas)
+    acc = _ops.pair_accel(A[:, 1:1 + d], A[:, 1 + 2 * d], x, nbr, pval, rc2,
+                          use_pallas=use_pallas)
     x2, v2 = _ia._integrate(x, v, acc, dt)
     return jnp.concatenate([u_new[:, None], x2, v2, m[:, None]], axis=1)
 
@@ -179,10 +166,7 @@ def _pic_reference_fn(d: int, use_pallas: bool):
     @jax.jit
     def fn(steps, dt, rc2, U, isp, nbr, valid, coeff):
         def body(_, U):
-            return _pic_body(
-                U, isp, nbr, valid, coeff, rc2, dt, d, None, None, None,
-                use_pallas,
-            )
+            return _pic_body(U, U, isp, nbr, valid, coeff, rc2, dt, d, use_pallas)
         return jax.lax.fori_loop(0, steps, body, U)
     return fn
 
@@ -210,23 +194,15 @@ def _pic_fn(
     """Jitted coupled executor: ONE ghost exchange of the full state
     matrix per substep feeds both the stencil and the pair phase."""
 
-    def kernel(steps, dt, rc2, U, isp, nbr, valid, coeff, fetch,
-               interior, boundary, *stage_idx):
+    def kernel(steps, dt, rc2, U, isp, nbr, valid, coeff, fetch, *stage_idx):
         def body(_, U):
             recv = _ia._route_cols(U, stage_meta, stage_idx, jnp.float32(0.0))
-            ghosts = jnp.where(
-                (fetch >= 0)[:, None],
-                recv[jnp.clip(fetch, 0, recv.shape[0] - 1)],
-                jnp.float32(0.0),
-            )
-            return _pic_body(
-                U, isp, nbr, valid, coeff, rc2, dt, d, ghosts,
-                interior, boundary, use_pallas,
-            )
+            A = jnp.concatenate([U, _st._ghosts(recv, fetch)], axis=0)
+            return _pic_body(U, A, isp, nbr, valid, coeff, rc2, dt, d, use_pallas)
         return jax.lax.fori_loop(0, steps, body, U)
 
     spec = P(axes)
-    in_specs = (P(), P(), P()) + (spec,) * (8 + len(stage_meta))
+    in_specs = (P(), P(), P()) + (spec,) * (6 + len(stage_meta))
     return jax.jit(jax.shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False,
     ))
@@ -239,7 +215,7 @@ def pic_steps(jax_mesh, plan, U_dev, isp_dev, hargs: _st.HaloArgs,
     fn = _pic_fn(jax_mesh, plan.axes, plan.stage_meta, d, bool(use_pallas))
     return fn(
         jnp.int32(steps), jnp.float32(dt), jnp.float32(float(radius) ** 2),
-        U_dev, isp_dev, *hargs.core, *hargs.split, *hargs.stages,
+        U_dev, isp_dev, *hargs.core, *hargs.stages,
     )
 
 

@@ -8,8 +8,8 @@ through the engine's insert/delete path, the `HierarchicalRepartitioner`
 answers load drift through the Alg. 3 trigger, a compiled interaction
 plan replaces the halo plan, and a multi-column move plan migrates
 position+velocity+mass under ONE routing (``interact.move_rows``).
-Between events the overlapped leapfrog executor runs ``substeps``
-kick-drift sweeps with the ghost-position exchange in flight.
+Between events the leapfrog executor runs ``substeps`` kick-drift
+sweeps, each a ghost-position exchange and then every owned row.
 
 Bit-equality: :func:`run_reference` and :func:`run_distributed` start
 from the same `state.random_particles` draw and rebuild the interaction

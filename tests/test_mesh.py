@@ -377,40 +377,45 @@ def test_distributed_stencil_bit_equal_and_loop_closes():
 
 
 # ---------------------------------------------------------------------------
-# overlapped stencil executor: plan split, compile caching, bit-equality
+# stencil executor: plan lane tables, compile caching, bit-equality
 # ---------------------------------------------------------------------------
 
 @settings(max_examples=4, deadline=None)
 @given(rounds=st.integers(1, 2), nodes=st.sampled_from([1, 2]), seed=st.integers(0, 3))
 def test_halo_plan_interior_boundary_split(rounds, nodes, seed):
-    """The plan's interior/boundary classification is a disjoint cover of
-    the real rows, and interior rows provably read no ghosts: every
-    valid nbr_local entry of an interior row is an owned slot (< cap)."""
+    """The lane tables split each real row's reads between owned rows and
+    ghosts: a valid lane points below ``cap`` (at the neighbor's own row)
+    exactly when the neighbor has the row's owner, and otherwise into
+    ``[cap, cap + gcap)`` at a ghost slot the exchange fills
+    (``ghost_fetch >= 0``). Interior rows (no ghost lane) and boundary
+    rows count every cell once."""
     rng = np.random.default_rng(seed)
     m = _adapted_mesh(rounds=rounds, cx=0.25 + 0.1 * rng.random())
     plan, part, nbr, hplan, slots = _plan_for(m, num_nodes=nodes, dev=8 // nodes)
     S, cap = plan.owned_idx.shape
+    boundary = 0
     for p in range(S):
-        real = set(np.flatnonzero(plan.owned_idx[p] >= 0).tolist())
-        interior = set(plan.interior_idx[p][plan.interior_idx[p] >= 0].tolist())
-        boundary = set(plan.boundary_idx[p][plan.boundary_idx[p] >= 0].tolist())
-        assert interior | boundary == real
-        assert not (interior & boundary)
-        for r in sorted(interior):
-            nl, nv = plan.nbr_local[p, r], plan.nbr_valid[p, r]
-            assert (nl[nv] < cap).all(), "interior row reads a ghost slot"
-        for r in sorted(boundary):
-            nl, nv = plan.nbr_local[p, r], plan.nbr_valid[p, r]
-            assert (nl[nv] >= cap).any(), "boundary row reads no ghost"
+        rows = np.flatnonzero(plan.owned_idx[p] >= 0)
+        cells = plan.owned_idx[p, rows]
+        nl, nv = plan.nbr_local[p, rows], plan.nbr_valid[p, rows]
+        assert np.array_equal(nv, nbr[cells] >= 0)
+        same = nv & (part[np.maximum(nbr[cells], 0)] == p)
+        assert np.array_equal(nv & (nl < cap), same)
+        assert np.array_equal(plan.owned_idx[p, nl[same]], nbr[cells][same])
+        ghost = nv & ~same
+        g = nl[ghost] - cap
+        assert ((g >= 0) & (g < plan.gcap)).all()
+        assert (plan.ghost_fetch[p, g] >= 0).all()
+        boundary += int(ghost.any(axis=1).sum())
     mets = plan.metrics
+    assert mets["BoundaryCells"] == boundary
     assert mets["InteriorCells"] + mets["BoundaryCells"] == m.n
 
 
 def test_stencil_executor_not_keyed_on_steps():
-    """ONE compiled overlapped executor serves every sweep length (steps
-    is traced through the fori_loop), while the pre-split baseline's
-    cache is keyed on steps — and both stay bit-equal to the reference
-    at every length."""
+    """ONE compiled executor serves every sweep length (steps is traced
+    through the fori_loop), bit-equal to the reference at every
+    length."""
     import jax
     from repro.distributed import sharding as shd
     from repro.mesh import stencil as _st
@@ -425,22 +430,13 @@ def test_stencil_executor_not_keyed_on_steps():
     u_dev = _st.put_state(mesh, plan, u0)
 
     _st._stencil_fn.cache_clear()
-    _st._stencil_fn_presplit.cache_clear()
     for steps in (1, 3, 5):
         ref = np.asarray(_st.reference_stencil(u0, nbr, nbr >= 0, coeff, steps))
-        ov = plan.unpack_cells(
+        got = plan.unpack_cells(
             np.asarray(_st.stencil_steps(mesh, plan, u_dev, args, steps)), m.n
         )
-        ps = plan.unpack_cells(
-            np.asarray(
-                _st.stencil_steps(mesh, plan, u_dev, args, steps, overlap=False)
-            ),
-            m.n,
-        )
-        assert np.array_equal(ref, ov), steps
-        assert np.array_equal(ref, ps), steps
+        assert np.array_equal(ref, got), steps
     assert _st._stencil_fn.cache_info().misses == 1
-    assert _st._stencil_fn_presplit.cache_info().misses == 3
 
 
 @pytest.mark.parametrize("driver", ["incremental", "rebuild"])
@@ -472,9 +468,9 @@ def test_simulation_with_pallas_stencil_bit_equal(driver, monkeypatch):
 
 
 def test_distributed_overlap_variants_bit_equal():
-    """8-device mesh: the overlapped executor (jnp and Pallas row
-    update) and the pre-split baseline all produce the reference bits
-    on a real two-level plan with inter-node ghosts."""
+    """8-device mesh: the executor produces the reference bits on a real
+    two-level plan with inter-node ghosts, for one field (jnp and Pallas
+    row update) and for three fields a cell."""
     out = _run("""
         import numpy as np
         import jax.numpy as jnp
@@ -500,16 +496,18 @@ def test_distributed_overlap_variants_bit_equal():
             hierarchy=hplan, weights=ev.weights)
         assert plan.metrics["BoundaryCells"] > 0
         args = _st.halo_args(mesh, plan)
-        u_dev = _st.put_state(mesh, plan, u0)
         valid = ev.nbr >= 0
+        u3 = np.stack([u0, 1.0 - u0, u0 * u0], axis=1)
+        cases = ((u0, {}), (u0, {"use_pallas": True}), (u3, {}))
         for steps in (1, 3):
-            ref = np.asarray(
-                _st.reference_stencil(u0, ev.nbr, valid, ev.coeff, steps))
-            for kw in ({}, {"use_pallas": True}, {"overlap": False}):
-                got = plan.unpack_cells(np.asarray(
-                    _st.stencil_steps(mesh, plan, u_dev, args, steps, **kw)),
+            for u, kw in cases:
+                ref = np.asarray(
+                    _st.reference_stencil(u, ev.nbr, valid, ev.coeff, steps))
+                got = plan.unpack_cells(np.asarray(_st.stencil_steps(
+                    mesh, plan, _st.put_state(mesh, plan, u), args, steps, **kw)),
                     ev.mesh.n)
-                assert np.array_equal(ref, got), (steps, kw)
+                assert got.shape == u.shape
+                assert np.array_equal(ref, got), (steps, u.shape, kw)
         print("OK")
     """)
     assert "OK" in out

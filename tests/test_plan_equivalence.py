@@ -3,8 +3,9 @@
 The contract that makes the segment-op rewrite of `repro.mesh.halo` a
 pure perf change: every output field of `build_halo_plan` /
 `build_move_plan` is ``np.array_equal`` to the legacy loop builders'
-(the ascending-slot canonical order and stable fills are deterministic,
-so exact equality is the spec, not a tolerance). The matrix covers flat
+in ``tests/_plan_reference.py`` (the ascending-slot canonical order and
+stable fills are deterministic, so exact equality is the spec, not a
+tolerance). The matrix covers flat
 and (N, D) hierarchies, scattered and SFC-compact partitions,
 non-contiguous slot ids, empty-ghost and empty parts, cap-rounding
 boundaries, and every move-plan kind (incremental / full /
@@ -20,6 +21,7 @@ import numpy as np
 import pytest
 
 from tests._hypothesis_compat import given, settings, strategies as st
+from tests._plan_reference import build_halo_plan_legacy, build_move_plan_legacy
 
 from repro.mesh import amr
 from repro.mesh import halo
@@ -88,7 +90,7 @@ def assert_halo_equal(
     )
     for f in (
         "owned_idx", "owned_slot", "nbr_local", "nbr_valid", "coeff",
-        "ghost_fetch", "interior_idx", "boundary_idx",
+        "ghost_fetch",
     ):
         assert np.array_equal(getattr(a, f), getattr(b, f)), f
     assert a.stage_meta == b.stage_meta
@@ -120,7 +122,7 @@ def _build_pair(slot, part, nbr, coeff, hier, S):
     kw = dict(hierarchy=hier) if hier is not None else dict(num_parts=S)
     return (
         halo.build_halo_plan(slot, part, nbr, coeff, **kw),
-        halo.build_halo_plan_legacy(slot, part, nbr, coeff, **kw),
+        build_halo_plan_legacy(slot, part, nbr, coeff, **kw),
     )
 
 
@@ -166,7 +168,7 @@ def test_move_plan_bit_identical(seed, nodes, dev, full, frac):
     kw = dict(hierarchy=hier, full=full)
     assert_move_equal(
         halo.build_move_plan(pv, pv2, **kw),
-        halo.build_move_plan_legacy(pl, pl2, **kw),
+        build_move_plan_legacy(pl, pl2, **kw),
     )
 
 
@@ -175,7 +177,7 @@ def test_move_plan_kind_none():
     slot = _slots(mesh.n, 0, contiguous=True)
     part = _partition(mesh, 4, 0, sfc=True)
     pv, pl = _build_pair(slot, part, nbr, coeff, None, 4)
-    mv, ml = halo.build_move_plan(pv, pv), halo.build_move_plan_legacy(pl, pl)
+    mv, ml = halo.build_move_plan(pv, pv), build_move_plan_legacy(pl, pl)
     assert mv.kind == ml.kind == "none"
     assert_move_equal(mv, ml)
 
@@ -193,7 +195,7 @@ def test_move_plan_node_local_device_certified():
     pv, pl = _build_pair(slot, part, nbr, coeff, hier, 8)
     pv2, pl2 = _build_pair(slot, part2, nbr, coeff, hier, 8)
     mv = halo.build_move_plan(pv, pv2, hierarchy=hier)
-    ml = halo.build_move_plan_legacy(pl, pl2, hierarchy=hier)
+    ml = build_move_plan_legacy(pl, pl2, hierarchy=hier)
     assert mv.kind == ml.kind
     if int(mv.migration.total_moved):
         assert mv.kind == "device"
@@ -241,7 +243,7 @@ def test_with_metrics_false_identical_otherwise():
     assert "MaxEdgeCut" in full.metrics and "MaxEdgeCut" not in lean.metrics
     for f in (
         "owned_idx", "owned_slot", "nbr_local", "nbr_valid", "coeff",
-        "ghost_fetch", "interior_idx", "boundary_idx",
+        "ghost_fetch",
     ):
         assert np.array_equal(getattr(full, f), getattr(lean, f)), f
     assert full.stage_meta == lean.stage_meta
@@ -253,7 +255,7 @@ def test_with_metrics_false_identical_otherwise():
     rec = halo.plan_quality_metrics(part, nbr, 8)
     assert rec["MaxEdgeCut"] == full.metrics["MaxEdgeCut"]
     # the legacy builder honors the same flag
-    lean_l = halo.build_halo_plan_legacy(
+    lean_l = build_halo_plan_legacy(
         slot, part, nbr, coeff, hierarchy=hier, with_metrics=False
     )
     assert_halo_equal(lean, lean_l)
