@@ -129,6 +129,7 @@ def stencil_update(
     coeff: jax.Array,
     *,
     use_pallas: bool = False,
+    finer: tuple | None = None,
 ) -> jax.Array:
     """Fused stencil row update (gather + mask + coeff*(v-u) + K-reduce).
 
@@ -136,11 +137,25 @@ def stencil_update(
     field or (R, V) for V fields per cell. ``use_pallas`` dispatches the
     Pallas kernel; the default jnp path is bit-equal by construction —
     both evaluate `kernels.stencil_update.stencil_update_ref`'s
-    expression.
+    expression. The V-wide kernel runs in two passes: every row with one
+    value a face (`kernels.stencil_update.fused_stencil_update_v`), then
+    the rows of ``finer`` = (rows, nbr, valid, coeff), the halo plan's
+    finer-row list and the table rows it names, with every slot's
+    (`kernels.stencil_update.finer_update_v`). It needs the list where
+    the table has sub-slots a face (``mesh.amr.face_group(K) > 1``); the
+    other forms take none.
     """
+    if use_pallas and u_rows.ndim == 2:
+        out = _su.fused_stencil_update_v(vals_all, u_rows, nbr, valid, coeff,
+                                         interpret=interpret())
+        if finer is None:
+            if _su.face_group(nbr.shape[1]) > 1:
+                raise ValueError("the V-wide kernel needs the finer-row list of a face table")
+            return out
+        return _su.finer_update_v(vals_all, u_rows, out, *finer, interpret=interpret())
     if use_pallas:
-        fused = _su.fused_stencil_update_v if u_rows.ndim == 2 else _su.fused_stencil_update
-        return fused(vals_all, u_rows, nbr, valid, coeff, interpret=interpret())
+        return _su.fused_stencil_update(vals_all, u_rows, nbr, valid, coeff,
+                                        interpret=interpret())
     return _su.stencil_update_ref(vals_all, u_rows, nbr, valid, coeff)
 
 

@@ -35,30 +35,22 @@ rows at once. A cell's fields sit on the lanes, padded to a whole 128
 the rows on sublanes: each neighbour slot's gathered (block, 128) rows
 go to the kernel as they are, with no transpose. Most rows of a mesh's
 table have one valid slot a face; the kernel gathers only that slot's
-values for them and keeps the full K-slot chain (see
-:func:`fused_stencil_update_v`).
+values for them and keeps the full K-slot chain. The few rows with a
+valid slot past the first of a face come listed from the halo plan
+(``mesh.halo.list_finer_rows``, made once per plan) and
+:func:`finer_update_v` runs them again with every slot's values.
 """
 from __future__ import annotations
 
 import functools
-import operator
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.mesh.amr import face_group
+
 BLOCK_R = 512  # rows per grid step (lanes)
-
-
-def face_group(K: int) -> int:
-    """Slots per face of a K-wide face-neighbour table (2d faces of
-    2^(d-1) sub-slots, ``mesh.amr.face_neighbors``): the first slot of a
-    face holds a same-level or coarser neighbour, the others only finer
-    ones. 1 where K is no such width."""
-    for d in (1, 2, 3):
-        if K == 2 * d * (1 << (d - 1)):
-            return 1 << (d - 1)
-    return 1
 
 
 def stencil_update_ref(
@@ -180,13 +172,17 @@ def _update_kernel_v(*refs):
 
 
 def _rows_pass(vals_p, u_rows, nbr, valid, coeff, interpret):
-    """The kernel over every row of the tables: rows in blocks of
+    """The kernel over every row of the tables it is given (all of a
+    device's rows, or its listed finer rows): rows in blocks of
     ``GATHER_ROWS`` (fewer when R is small), each gathering one (block,
     128-lane) array of neighbour rows per column of ``nbr`` (every slot,
     or the first slot of each face: see :func:`_update_kernel_v`).
     The last block ends at row R and overlaps the one before it, whose
     rows it computes again from the same inputs, so no table is copied
     to pad it."""
+    # a barrier keeps XLA from moving the lane pad past the gathers of a
+    # pass it unrolls (one block): it then gathers 40-lane rows
+    vals_p = jax.lax.optimization_barrier(vals_p)
     R, C = nbr.shape
     K = valid.shape[1]
     V = u_rows.shape[1]
@@ -220,28 +216,9 @@ def _rows_pass(vals_p, u_rows, nbr, valid, coeff, interpret):
     return out[:R]
 
 
-def _first_rows(mask: jax.Array, cap: int) -> jax.Array:
-    """The indices of the first ``cap`` True rows of ``mask`` in
-    ascending order, R (out of range) past their end: ``jnp.nonzero``
-    with ``size=cap``. Each row's rank among the True rows is a prefix
-    sum, taken in blocks of 512 by a matmul with a triangle of ones
-    (exact: 0/1 terms, sums <= 512) and then over the block totals:
-    for 5.33M rows XLA:TPU compiles a cumsum in 7 s and a nonzero in
-    13 s, this in under 3."""
-    R = mask.shape[0]
-    B = 512
-    nb = pl.cdiv(R, B)
-    m = jnp.pad(mask, (0, nb * B - R)).reshape(nb, B).astype(jnp.float32)
-    tri = jnp.triu(jnp.ones((B, B), jnp.float32))
-    within = jnp.dot(m, tri, preferred_element_type=jnp.float32).astype(jnp.int32)
-    before = jnp.cumsum(within[:, -1]) - within[:, -1]
-    rank = (within + before[:, None]).reshape(-1)[:R] - 1
-    pos = jnp.where(mask, rank, cap)            # cap: dropped
-    return jnp.full((cap,), R, jnp.int32).at[pos].set(
-        jnp.arange(R, dtype=jnp.int32), mode="drop")
-
-
-FINER_SHARE = 16      # the second pass takes at most R / 16 rows
+def _lanes(vals_all, V):
+    """(M, V) values padded to a whole 128 lanes, for the row gathers."""
+    return jnp.pad(vals_all, ((0, 0), (0, pl.cdiv(V, 128) * 128 - V)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -254,35 +231,42 @@ def fused_stencil_update_v(
     *,
     interpret: bool = True,
 ) -> jax.Array:
-    """V-wide fused update: ``vals_all`` (M, V), ``u_rows`` (R, V),
-    tables (R, K); returns the (R, V) updated rows, bit-equal to
-    :func:`stencil_update_ref`.
+    """V-wide fused update, the face pass: ``vals_all`` (M, V),
+    ``u_rows`` (R, V), tables (R, K); returns the (R, V) updated rows.
 
-    A face-neighbour table has one valid slot a face on most rows: the
-    kernel first runs every row with the values of the first slot of
-    each face only (K / g gathers a row, g = :func:`face_group`), then
-    runs the rows with a valid slot past the first of a face, gathered
-    into a list of at most R / ``FINER_SHARE``, with the values of all K
-    slots, and puts them back. Where more rows than that have such a
-    slot, every row runs with all K."""
-    R, K = nbr.shape
-    V = u_rows.shape[1]
-    lanes = pl.cdiv(V, 128) * 128
-    vals_p = jnp.pad(vals_all, ((0, 0), (0, lanes - V)))
-    full = lambda: _rows_pass(vals_p, u_rows, nbr, valid, coeff, interpret)
-    g = face_group(K)
-    if g == 1:
-        return full()
-    # a slot past the first of a face is valid (an OR of columns: XLA:TPU
-    # compiles a reduction over an (R, K/g, g-1) bool view in ~15 s)
-    finer = functools.reduce(operator.or_, [valid[:, k] for k in range(K) if k % g])
-    cap = pl.cdiv(max(R // FINER_SHARE, 1), BLOCK_RV) * BLOCK_RV
+    A face-neighbour table has one valid slot a face on most rows: every
+    row runs with the values of the first slot of each face only (K / g
+    gathers a row, g = ``mesh.amr.face_group(K)``). A row is then
+    bit-equal to :func:`stencil_update_ref` if no slot past the first of
+    a face is valid, and every row is where g is 1; the other rows are
+    the halo plan's finer-row list, which :func:`finer_update_v` runs
+    again with every slot's values."""
+    g = face_group(nbr.shape[1])
+    return _rows_pass(_lanes(vals_all, u_rows.shape[1]), u_rows, nbr[:, ::g], valid, coeff,
+                      interpret)
 
-    def faces_then_finer():
-        out = _rows_pass(vals_p, u_rows, nbr[:, ::g], valid, coeff, interpret)
-        rows = _first_rows(finer, cap)
-        take = lambda a: jnp.take(a, rows, axis=0, mode="clip")
-        fine = _rows_pass(vals_p, take(u_rows), take(nbr), take(valid), take(coeff), interpret)
-        return out.at[rows].set(fine, mode="drop")
 
-    return jax.lax.cond(jnp.sum(finer) <= cap, faces_then_finer, full)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def finer_update_v(
+    vals_all: jax.Array,
+    u_rows: jax.Array,
+    out: jax.Array,
+    rows: jax.Array,
+    nbr: jax.Array,
+    valid: jax.Array,
+    coeff: jax.Array,
+    *,
+    interpret: bool = True,
+) -> jax.Array:
+    """The listed rows of the V-wide update: ``out`` (R, V) with each row
+    of ``rows`` (fcap,) replaced by :func:`stencil_update_ref`'s value,
+    run with all K slots' values in one block where fcap <=
+    ``GATHER_ROWS``. ``rows`` is the halo plan's finer-row list
+    (``mesh.halo.list_finer_rows``: ascending, -1 pads) and ``nbr`` /
+    ``valid`` / ``coeff`` (fcap, K) the table rows it names."""
+    if rows.shape[0] == 0:
+        return out
+    # a -1 pad reads row 0 and is dropped by the scatter
+    fine = _rows_pass(_lanes(vals_all, u_rows.shape[1]),
+                      jnp.take(u_rows, rows, axis=0, mode="clip"), nbr, valid, coeff, interpret)
+    return out.at[rows].set(fine, mode="drop", wrap_negative_indices=False)

@@ -165,6 +165,16 @@ def neighbor_slots_per_cell(d: int) -> int:
     return 2 * d * (1 << (d - 1))
 
 
+def face_group(K: int) -> int:
+    """Slots per face of a K-wide :func:`face_neighbors` table: the first
+    slot of a face holds a same-level or coarser neighbour, the others
+    only finer ones. 1 where K is no such width."""
+    for d in (1, 2, 3):
+        if K == neighbor_slots_per_cell(d):
+            return 1 << (d - 1)
+    return 1
+
+
 def face_neighbors(mesh: AMRMesh) -> np.ndarray:
     """(n, K) int32 face-neighbor table, K = ``neighbor_slots_per_cell``.
 

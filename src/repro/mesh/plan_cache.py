@@ -279,7 +279,7 @@ def cached_build_halo_plan(
             gcap=old.gcap, K=old.K, owned_idx=old.owned_idx,
             owned_slot=old.owned_slot, nbr_local=old.nbr_local,
             nbr_valid=old.nbr_valid, coeff=old.coeff, stages=old.stages,
-            ghost_fetch=old.ghost_fetch, metrics=mets,
+            ghost_fetch=old.ghost_fetch, finer_rows=old.finer_rows, metrics=mets,
         )
         cache._stash_prev()
         cache._last_plan = plan
@@ -501,7 +501,8 @@ def _patched_build(
         axes=axes, num_parts=S, cap=cap, gcap=gcap, K=K,
         owned_idx=owned_idx, owned_slot=owned_slot, nbr_local=nbr_local,
         nbr_valid=nbr_valid, coeff=coeff_l, stages=stages,
-        ghost_fetch=ghost_fetch, metrics=mets,
+        ghost_fetch=ghost_fetch, finer_rows=_halo.list_finer_rows(nbr_valid),
+        metrics=mets,
     )
     cache._stash_prev()
     cache._install_state(plan, shape_key, dict(

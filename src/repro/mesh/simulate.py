@@ -189,6 +189,9 @@ class SimStats:
     # ghost cells over all chips
     halo_bytes_stage: int = 0
     ghost_cells: int = 0
+    # the current plan's finer-row list: the most rows one chip lists
+    # (against the padded ``fcap`` in ``DistributedSim.floors``)
+    finer_rows: int = 0
     checksums: int = 0              # global checksums taken
     cells_final: int = 0
     halo_metrics: dict = field(default_factory=dict)
@@ -297,7 +300,7 @@ class DistributedSim:
 
     def _pad(self, plan):
         c = plan.caps
-        f = {k: self._floor(k, c[k]) for k in ("cap", "gcap")}
+        f = {k: self._floor(k, c[k]) for k in ("cap", "gcap", "fcap")}
         f["stages"] = self._floor(("stages", len(c["stages"])), c["stages"])
         return plan.padded(f)
 
@@ -462,6 +465,7 @@ class DistributedSim:
         sent = sum((s.idx >= 0).sum(axis=(1, 2)) for s in xplan.stages)
         self.st.halo_bytes_stage = 4 * width * int(np.max(sent)) if xplan.stages else 0
         self.st.ghost_cells = int((xplan.ghost_fetch >= 0).sum())
+        self.st.finer_rows = int((xplan.finer_rows >= 0).sum(axis=1).max())
 
     def fields(self) -> np.ndarray:
         """The current fields in the current mesh's cell order (host)."""

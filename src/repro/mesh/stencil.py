@@ -99,20 +99,32 @@ def _stencil_fn(
     axes: tuple,
     stage_meta: tuple,
     use_pallas: bool,
+    listed: bool = False,
 ):
     """Jitted halo-exchange + fused-update executor, memoized per static
     (mesh, axes, hop shapes) — NOT per step count: ``steps`` is a traced
-    argument, so one compiled program serves any sweep length."""
+    argument, so one compiled program serves any sweep length.
+    ``listed``: the V-wide kernel's form, which also takes the plan's
+    finer-row list (after ``fetch``) and the table rows it names, taken
+    once before the stages."""
 
     def kernel(steps, u, nbr, valid, coeff, fetch, *stage_idx):
+        finer = None
+        if listed:
+            rows, stage_idx = stage_idx[0], stage_idx[1:]
+            # a -1 pad takes row 0; its result is dropped
+            finer = (rows,) + tuple(jnp.take(a, rows, axis=0, mode="clip")
+                                    for a in (nbr, valid, coeff))
+
         def body(_, u):
             recv = _route(u, stage_meta, stage_idx, jnp.float32(0.0))
             vals_all = jnp.concatenate([u, _ghosts(recv, fetch)])
-            return _ops.stencil_update(vals_all, u, nbr, valid, coeff, use_pallas=use_pallas)
+            return _ops.stencil_update(vals_all, u, nbr, valid, coeff, use_pallas=use_pallas,
+                                       finer=finer)
         return jax.lax.fori_loop(0, steps, body, u)
 
     spec = P(axes)
-    in_specs = (P(),) + (spec,) * (5 + len(stage_meta))
+    in_specs = (P(),) + (spec,) * (5 + listed + len(stage_meta))
     return jax.jit(jax.shard_map(
         kernel, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False,
     ))
@@ -120,10 +132,14 @@ def _stencil_fn(
 
 @dataclass(frozen=True)
 class HaloArgs:
-    """Device-resident executor arguments for one halo plan."""
+    """Device-resident executor arguments for one halo plan. ``finer``
+    is the plan's finer-row list (``HaloPlan.finer_rows``, made once per
+    plan by ``halo.list_finer_rows``): the rows the V-wide kernel runs
+    with every slot's values; the scalar sweep does not take it."""
 
     core: tuple     # (nbr, valid, coeff, fetch)
     stages: tuple   # one flat lane-index array per hop
+    finer: jax.Array
 
 
 def halo_args(jax_mesh: jax.sharding.Mesh, plan: HaloPlan) -> HaloArgs:
@@ -141,7 +157,7 @@ def halo_args(jax_mesh: jax.sharding.Mesh, plan: HaloPlan) -> HaloArgs:
     stages = tuple(
         put(s.idx.reshape(S * s.lanes * s.cap)) for s in plan.stages
     )
-    return HaloArgs(core=core, stages=stages)
+    return HaloArgs(core=core, stages=stages, finer=put(plan.finer_rows.reshape(S * plan.fcap)))
 
 
 def stencil_steps(
@@ -158,8 +174,10 @@ def stencil_steps(
     ``u_dev`` is the (S*cap,) owned field or (S*cap, V) owned fields
     (``plan.pack_cells`` layout); ``args`` from :func:`halo_args`. One
     compiled program serves every ``steps``."""
-    fn = _stencil_fn(jax_mesh, plan.axes, plan.stage_meta, bool(use_pallas))
-    return fn(jnp.int32(steps), u_dev, *args.core, *args.stages)
+    listed = bool(use_pallas) and u_dev.ndim == 2
+    fn = _stencil_fn(jax_mesh, plan.axes, plan.stage_meta, bool(use_pallas), listed)
+    finer = (args.finer,) if listed else ()
+    return fn(jnp.int32(steps), u_dev, *args.core, *finer, *args.stages)
 
 
 def put_state(jax_mesh, plan: HaloPlan, u_cells: np.ndarray):

@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.core import metrics as _metrics
 from repro.core import migration as _migration
+from repro.mesh.amr import face_group
 from repro.mesh.halo import (
     HaloPlan,
     MovePlan,
@@ -104,6 +105,16 @@ def build_halo_plan_legacy(
             loc[other] = np.array([cap + gp[int(c)] for c in nb[other]], np.int64)
         nbr_local[p, : cells.size] = np.where(valid, loc, 0)
 
+    # finer-row list: per part, each row with a valid slot that is not
+    # the first of its face, in row order
+    g = face_group(K)
+    listed = [[t for t in range(owned[p].size)
+               if any(nbr_valid[p, t, k] for k in range(K) if k % g)] for p in range(S)]
+    most = max(len(r) for r in listed)
+    finer_rows = np.full((S, _roundup(most) if most else 0), -1, np.int32)
+    for p in range(S):
+        finer_rows[p, : len(listed[p])] = listed[p]
+
     # a real row is a boundary row iff a valid lane reaches a ghost
     reads_ghost = (nbr_valid & (nbr_local >= cap)).any(axis=2)  # (S, cap)
     real = owned_idx >= 0
@@ -136,6 +147,7 @@ def build_halo_plan_legacy(
         coeff=coeff_l,
         stages=stages,
         ghost_fetch=ghost_fetch,
+        finer_rows=finer_rows,
         metrics=mets,
     )
 

@@ -120,14 +120,19 @@ def test_apply_transfer_of_many_fields_is_the_per_column_transfer():
 
 @pytest.mark.parametrize("V,K,R,block,finer", [
     (40, 24, 700, None, 1.0), (3, 8, 1300, None, 1.0), (5, 24, 1300, 512, 1.0),
-    (40, 24, 3000, None, 0.03), (5, 8, 2600, 512, 0.03)])
+    (40, 24, 3000, None, 0.03), (5, 8, 2600, 512, 0.03),
+    (6, 24, 900, None, 0.0), (6, 24, 1500, None, 256), (4, 5, 700, None, 1.0)])
 def test_v_wide_pallas_kernel_bit_equal_in_interpret_mode(V, K, R, block, finer, monkeypatch):
     """Bit-equal to the jnp definition, and to it column by column.
     ``finer``: share of rows whose faces may hold finer neighbours (the
     other rows fill only the first slot of each face, as a mesh's table
-    does on most rows); at 1.0 every row runs with all K slots' values,
-    at 0.03 the face pass and the finer-row pass both run."""
+    does on most rows), or their exact count; at 1.0 every row is on the
+    finer-row list, at 0.03 the face pass and the finer-row pass both
+    run, at 0.0 the list is empty, at 256 it has no pad. K = 5 is no
+    face table: one pass with every slot, and an empty list."""
+    from repro.kernels import ops
     from repro.kernels import stencil_update as su
+    from repro.mesh import halo
 
     if block:   # several row blocks, the last overlapping the one before
         monkeypatch.setattr(su, "GATHER_ROWS", block)
@@ -135,17 +140,132 @@ def test_v_wide_pallas_kernel_bit_equal_in_interpret_mode(V, K, R, block, finer,
     M = R + 300
     nbr = rng.integers(0, M, (R, K)).astype(np.int32)
     valid = rng.random((R, K)) < 0.8
-    first = np.arange(K) % su.face_group(K) == 0
-    valid[rng.random(R) >= finer] &= first
+    g = su.face_group(K)
+    first = np.arange(K) % g == 0
+    if isinstance(finer, int):
+        has = np.zeros(R, bool)
+        has[rng.choice(R, finer, replace=False)] = True
+        valid[has, 1] = True
+    else:
+        has = rng.random(R) < finer
+    valid[~has] &= first
     coeff = (rng.random((R, K)) / K).astype(np.float32)
     vals = rng.random((M, V)).astype(np.float32)
     u = vals[:R] * 0.5
-    got = np.asarray(su.fused_stencil_update_v(vals, u, nbr, valid, coeff, interpret=True))
+    rows = halo.list_finer_rows(valid[None])[0]
+    listed = rows[rows >= 0]
+    np.testing.assert_array_equal(listed, np.flatnonzero(has & (g > 1) & valid[:, ~first].any(1)))
+    if finer == 0.0 or g == 1:
+        assert rows.size == 0
+    if isinstance(finer, int):
+        assert rows.size == finer == listed.size
+    take = lambda a: np.take(a, rows, axis=0, mode="clip")
+    got = np.asarray(ops.stencil_update(vals, u, nbr, valid, coeff, use_pallas=True,
+                                        finer=(rows, take(nbr), take(valid), take(coeff))))
     ref = np.asarray(su.stencil_update_ref(vals, u, nbr, valid, coeff))
     np.testing.assert_array_equal(got, ref)
+    # the face pass alone already gives every row the list leaves out
+    face = np.asarray(su.fused_stencil_update_v(vals, u, nbr, valid, coeff, interpret=True))
+    rest = np.ones(R, bool)
+    rest[listed] = False
+    np.testing.assert_array_equal(face[rest], ref[rest])
     per_col = np.stack([np.asarray(su.stencil_update_ref(vals[:, v], u[:, v], nbr, valid, coeff))
                         for v in range(V)], 1)
     np.testing.assert_array_equal(ref, per_col)
+
+
+def test_finer_row_list_is_a_plan_table():
+    """Each device's rows with a valid slot past the first of a face are
+    listed once, ascending, -1 padded; a padded plan keeps the list, and
+    the rows it names keep their tables, ghost references shifted as
+    ``nbr_local``'s are."""
+    from repro.core import partitioner as pt
+    from repro.mesh import halo
+    from repro.mesh.amr import face_group
+
+    _, _, b, _ = _tiny_events()
+    n, K = b.nbr.shape
+    first = np.arange(K) % face_group(K) == 0
+    part = (np.arange(n) * 4 // n).astype(np.int32)
+    plan = halo.build_halo_plan(np.arange(n), part, b.nbr, b.coeff,
+                                hierarchy=pt.HierarchyPlan(2, 2))
+    caps = dict(plan.caps, cap=plan.cap + 40, gcap=plan.gcap + 16, fcap=plan.fcap + 24)
+    x = plan.padded(caps)
+    assert x.fcap == caps["fcap"] and x.caps == caps
+    counts = []
+    ghost_reads = 0
+    for p in range(4):
+        want = np.flatnonzero(plan.nbr_valid[p][:, ~first].any(1))
+        k = want.size
+        counts.append(k)
+        cells = plan.owned_idx[p, want]
+        assert (cells >= 0).all() and (b.nbr[cells][:, ~first] >= 0).any(1).all()
+        for q in (plan, x):
+            np.testing.assert_array_equal(q.finer_rows[p, :k], want)
+            assert (q.finer_rows[p, k:] == -1).all()
+        valid = plan.nbr_valid[p, want]
+        ghost = valid & (plan.nbr_local[p, want] >= plan.cap)
+        ghost_reads += int(ghost.sum())
+        np.testing.assert_array_equal(x.nbr_valid[p, x.finer_rows[p, :k]], valid)
+        np.testing.assert_array_equal(x.coeff[p, x.finer_rows[p, :k]], plan.coeff[p, want])
+        np.testing.assert_array_equal(x.nbr_local[p, x.finer_rows[p, :k]],
+                                      plan.nbr_local[p, want] + ghost * (x.cap - plan.cap))
+    assert sum(counts) == int((b.nbr[:, ~first] >= 0).any(1).sum()) > 0
+    assert plan.fcap == halo._roundup(max(counts))
+    # listed rows read ghosts, so the padding's shift is exercised
+    assert ghost_reads > 0
+    # a table with no face groups lists nothing
+    assert halo.list_finer_rows(plan.nbr_valid[..., :5]).shape == (4, 0)
+
+
+def test_plan_with_fewer_finer_rows_reuses_the_compiled_sweep():
+    """Two plans of one mesh, padded by one ``DistributedSim``, the
+    second with fewer finer rows on its busiest chip: one compiled
+    sweep serves both, bit-equal to the single-device sweep."""
+    code = textwrap.dedent("""
+        import numpy as np
+        from repro.core import partitioner as pt
+        from repro.distributed import sharding as shd
+        from repro.mesh import amr, halo, simulate
+        from repro.mesh.amr import face_group
+        from repro.mesh import stencil as st
+        S = [amr.Spheroid((-1.10, -1.10, -1.10), (0.03, 0.03, 0.03), (1.5, 1.5, 1.5)),
+             amr.Spheroid((0.5, 0.5, 1.76), (0.0, 0.0, -0.025), (0.75, 0.75, 0.75))]
+        _, _, b, _ = simulate.miniamr_events(S, 20, 25, root_level=2, block_bits=1,
+                                             num_refine=2, block_change=2)
+        n, K = b.nbr.shape
+        listed = (b.nbr[:, np.arange(K) % face_group(K) != 0] >= 0).any(1)
+        # chip 1 owns cells [c, c + n / 2): the finer rows lie near the
+        # start of the cell order, so c moves them between the chips
+        parts = {c: ((np.arange(n) - c) % n < n // 2).astype(np.int32) for c in range(n // 8)}
+        most = {c: max(np.bincount(p[listed], minlength=2)) for c, p in parts.items()}
+        c1, c2 = max(most, key=most.get), min(most, key=most.get)
+        assert most[c2] < most[c1]
+        hplan = pt.HierarchyPlan(1, 2)
+        jm = shd.make_node_device_mesh(1, 2)
+        u = np.random.default_rng(0).random((n, 3)).astype(np.float32)
+        sim = simulate.DistributedSim(b, u, jm, hplan, capacity=4 * n, use_pallas=True,
+                                      cfg=simulate.SimConfig(bucket_size=8, engine_max_depth=10))
+        x1, x2 = (sim._pad(halo.build_halo_plan(np.arange(n), parts[c], b.nbr, b.coeff,
+                                                hierarchy=hplan)) for c in (c1, c2))
+        assert x1.caps == x2.caps
+        assert (x2.finer_rows >= 0).sum(1).max() == most[c2] < most[c1]
+        want = np.asarray(st.reference_stencil(u, b.nbr, b.nbr >= 0, b.coeff, 2))
+        st._stencil_fn.cache_clear()
+        for x in (x1, x2):
+            out = st.stencil_steps(jm, x, st.put_state(jm, x, u), st.halo_args(jm, x), 2,
+                                   use_pallas=True)
+            assert np.array_equal(x.unpack_cells(np.asarray(out), n), want)
+        info = st._stencil_fn.cache_info()
+        assert (info.hits, info.misses) == (1, 1), info
+        assert st._stencil_fn(jm, x2.axes, x2.stage_meta, True, True)._cache_size() == 1
+        print("OK")
+    """)
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         env=env, timeout=600)
+    assert out.returncode == 0 and "OK" in out.stdout, out.stderr[-3000:]
 
 
 def test_distributed_v3_run_bit_equal_to_single_device():
@@ -244,6 +364,10 @@ def test_mesh_spans_and_stats_are_emitted(tmp_path):
         q[0] == "repartition.step" and sp[1] <= q[1] and q[2] <= sp[2] for q in prof.spans)]
     st = sim.finish()
     assert st.ghost_cells == 0 and st.halo_bytes_stage == 0      # one device: no exchange
+    # the plan of mesh b lists every row with a finer neighbour, under fcap
+    K = b.nbr.shape[1]
+    assert st.finer_rows == int((b.nbr[:, np.arange(K) % 4 != 0] >= 0).any(1).sum()) > 0
+    assert st.finer_rows <= sim.xplan.fcap == sim.floors["fcap"]
     assert st.checksums == 4 and st.amr_events == 1 and len(sim.checksums) == 4
     # the span readers of the spheres cell read them
     run = SimpleNamespace(profile=prof, layer={"halo_bytes_stage": 4096})

@@ -90,7 +90,7 @@ def assert_halo_equal(
     )
     for f in (
         "owned_idx", "owned_slot", "nbr_local", "nbr_valid", "coeff",
-        "ghost_fetch",
+        "ghost_fetch", "finer_rows",
     ):
         assert np.array_equal(getattr(a, f), getattr(b, f)), f
     assert a.stage_meta == b.stage_meta
@@ -243,7 +243,7 @@ def test_with_metrics_false_identical_otherwise():
     assert "MaxEdgeCut" in full.metrics and "MaxEdgeCut" not in lean.metrics
     for f in (
         "owned_idx", "owned_slot", "nbr_local", "nbr_valid", "coeff",
-        "ghost_fetch",
+        "ghost_fetch", "finer_rows",
     ):
         assert np.array_equal(getattr(full, f), getattr(lean, f)), f
     assert full.stage_meta == lean.stage_meta

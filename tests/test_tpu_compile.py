@@ -22,6 +22,7 @@ N_POINTS = 1 << 24      # partition phase: keys of every point
 MESH_ROWS = 1_397_674   # mesh phase: cells after the last refinement
 PARTICLES, K_PAIR = 32768, 112   # particle phase: atoms, table width
 AMR_ROWS, AMR_V = 1_400_064, 40   # miniAMR cell: rows a chip updates, fields
+AMR_FINER = 35_840                # ... and its listed finer rows (fcap)
 
 u32, i32, f32, b1 = jnp.uint32, jnp.int32, jnp.float32, jnp.bool_
 
@@ -35,9 +36,12 @@ CASES = {
         [((MESH_ROWS,), f32), ((MESH_ROWS,), f32), ((MESH_ROWS, 8), i32),
          ((MESH_ROWS, 8), b1), ((MESH_ROWS, 8), f32)]),
     "stencil_update_v": (
-        lambda v, u, n, m, c: stencil_update.fused_stencil_update_v(v, u, n, m, c, interpret=False),
+        lambda v, u, n, m, c, *finer: stencil_update.finer_update_v(
+            v, u, stencil_update.fused_stencil_update_v(v, u, n, m, c, interpret=False),
+            *finer, interpret=False),
         [((AMR_ROWS + 200_064, AMR_V), f32), ((AMR_ROWS, AMR_V), f32), ((AMR_ROWS, 24), i32),
-         ((AMR_ROWS, 24), b1), ((AMR_ROWS, 24), f32)]),
+         ((AMR_ROWS, 24), b1), ((AMR_ROWS, 24), f32), ((AMR_FINER,), i32),
+         ((AMR_FINER, 24), i32), ((AMR_FINER, 24), b1), ((AMR_FINER, 24), f32)]),
     "pair_force": (
         lambda p, m, x, n, v, r: pair_force.fused_pair_accel(p, m, x, n, v, r, interpret=False),
         [((PARTICLES, 3), f32), ((PARTICLES,), f32), ((PARTICLES, 3), f32),
@@ -74,3 +78,18 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     arg_bytes = sum(int(np.prod(s)) * np.dtype(d).itemsize for s, d in specs)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= 4 * arg_bytes, (name, temp, arg_bytes)
+
+
+def test_v_wide_passes_gather_full_lane_rows(one_chip):
+    """Both passes of the V-wide update gather neighbour rows padded to
+    128 lanes: XLA would otherwise move the pad of the one-block finer
+    pass past its gathers and gather 40-lane rows. The only 40-lane
+    gather is the listed rows' centres."""
+    import re
+
+    fn, specs = CASES["stencil_update_v"]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    gathers = re.findall(r"= f32\[(\d+),(\d+)\]\S* gather\(", text)
+    assert gathers.count((str(AMR_FINER), "128")) == 24, gathers
+    assert [g for g in gathers if g[1] != "128"] == [(str(AMR_FINER), str(AMR_V))], gathers
